@@ -10,8 +10,6 @@ fault tolerance.  See SURVEY.md for the reference analysis.
 
 __version__ = "0.2.0"
 
-from dryad_tpu.utils import jax_compat as _jax_compat  # noqa: F401,E402
-
 from dryad_tpu.api.dataset import Context, Dataset  # noqa: F401,E402
 from dryad_tpu.parallel.mesh import make_mesh  # noqa: F401,E402
 from dryad_tpu.plan.expr import Decomposable  # noqa: F401,E402

@@ -212,7 +212,7 @@ def _shrink_batch(batch: Batch, new_cap: int) -> Batch:
 def shrink_pdata(pd: PData, new_cap: int) -> PData:
     """Reduce per-partition capacity (device-side) before host transfer —
     collect() uses this so a 1M-capacity / 12-row result doesn't ship 1M
-    padded rows through PCIe/tunnel.  new_cap must cover max(counts)."""
+    padded rows through PCIe.  new_cap must cover max(counts)."""
     return PData(_shrink_batch(pd.batch, new_cap), pd.nparts)
 
 

@@ -255,7 +255,7 @@ _VALOPS_MAX_WORDS = 32
 # ...and until the PROGRAM gets too big: XLA:TPU unrolls sort networks,
 # so executable size scales ~log^2(n) x operands (measured 53 MB for an
 # 8-operand sort at 250k rows) — huge caps with many carried words make
-# remote compiles take minutes and binaries enormous.  Above this
+# compiles take minutes and binaries enormous.  Above this
 # cap x operand budget, reorder via the 3-operand index sort + ONE
 # packed gather instead (slower on-device at huge n, but compilable).
 _VALOPS_MAX_ELEMS = 48 << 20

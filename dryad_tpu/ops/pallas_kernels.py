@@ -20,15 +20,20 @@ pallas is reserved for the primitives XLA lowers badly:
     streamed pass with an SMEM carry between sequential grid steps
     (in-VMEM Hillis-Steele per tile) — 0.12 ms / 512k, 4.5x.  Feeds the
     boundary-carry group aggregation (ops/kernels.group_aggregate).
-  * ``slot_expand`` / ``slot_compact`` — exchange pack/unpack.  The
+  * ``slot_expand`` / ``slot_compact`` — exchange pack/unpack: the
     send-side slot expansion (first min(count, C) rows of each
     destination run -> the [D, C] slot grid) and the receive-side slot
-    compaction (valid prefix of each source block -> dense rows) were
-    XLA random gathers over scatter-shaped index math (~10.7 ns/row x
-    packed words).  Each destination run / source block is CONTIGUOUS
-    in the dest-sorted (resp. received) buffer, so both kernels are D
-    dynamic-offset block DMAs — sequential-bandwidth copies the DMA
-    engine runs at HBM rate, not the gather unit's per-row cost.
+    compaction (valid prefix of each source block -> dense rows).
+    These are XLA gathers, NOT pallas kernels.  A block-DMA design (one
+    dynamic-offset copy per destination run / source block) passed in
+    interpret mode only: the TPU compiler (Mosaic, compiling for a
+    described v5e) refuses it at every packed row width the program
+    produces — "Slice shape along dimension 1 must be aligned to tiling
+    (128), but is W" on the ``[rows, W]`` HBM ref (real rows pack to a
+    handful of u32 words, never a multiple of 128), and at W=128,
+    C=262144 ``RESOURCE_EXHAUSTED`` (a 256 MiB double-buffered VMEM
+    output window against 128 MiB).  The pallas branch was deleted; a
+    block-DMA kernel that compiles is future work (ROADMAP S3).
     Feeds parallel/shuffle._exchange_one_axis (every hash/range
     repartition wave).
 
@@ -46,12 +51,12 @@ per-row-DMA join gather (one async copy per matched right row, probe +
 verify + gather fused per tile) bottomed out at the DMA issue rate
 (descriptor cost >> 20-byte payload, ~3x WORSE than the batched XLA
 gather), so the join probe fusion also ships at the XLA level
-(ops/kernels.hash_join packed single-gather + rank-fused compaction)
-and the exchange keeps its DMAs BLOCK-sized (slot_expand above).
+(ops/kernels.hash_join packed single-gather + rank-fused compaction).
 
-Gating: compiled kernels on TPU backends; ``interpret=True`` under
-``force_interpret()`` (tests exercise the kernel logic on CPU); plain
-XLA fallbacks otherwise, so every caller works on any backend.
+Gating (hist_buckets, prefix_sum, prefix_sum2): compiled kernels on TPU
+backends; ``interpret=True`` under ``force_interpret()`` (tests exercise
+the kernel logic on CPU); plain XLA fallbacks otherwise, so every caller
+works on any backend.
 """
 
 from __future__ import annotations
@@ -86,10 +91,9 @@ def force_interpret():
 
 @functools.lru_cache(maxsize=1)
 def _on_tpu() -> bool:
-    try:
-        return jax.default_backend() == "tpu"
-    except Exception:
-        return False
+    # a backend that fails to initialize raises here: answering "not a
+    # TPU" would silently drop every kernel to its XLA form
+    return jax.default_backend() == "tpu"
 
 
 def pallas_active() -> Optional[str]:
@@ -348,37 +352,9 @@ def prefix_sum2(x: jax.Array) -> Tuple[jax.Array, jax.Array]:
 # Both sides of a repartition move CONTIGUOUS row runs: after the dest
 # sort, destination d's rows occupy [offsets[d], offsets[d]+counts[d]);
 # after the all_to_all, source block s's valid rows are the prefix of
-# slot block [s*C, (s+1)*C).  The XLA lowering expressed both moves as
-# random gathers over scatter-shaped index math (clip(offsets[d]+j) /
-# argsort(~valid)), paying the per-row gather cost for what is really D
-# block copies.  The kernels below issue ONE dynamic-offset DMA per
-# destination/source block — the DMA engine streams each run at copy
-# bandwidth and handles arbitrary (non-tile-aligned) row offsets, which
-# is exactly what VMEM-resident vector code cannot do cheaply on the
-# lane-padded [rows, W] layout.
-
-# block DMAs below this many rows pay more descriptor cost than they
-# move; the XLA gather is better there (and in the degenerate D=1 case)
-_SLOT_MIN_C = 8
-
-
-def _expand_kernel_body(C: int, cap: int):
-    import jax.experimental.pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-
-    def kern(offs_ref, x_ref, o_ref, sem):
-        d = pl.program_id(0)
-        # x_ref is the C-row-padded source, so a run starting anywhere
-        # in [0, cap] always has C readable rows — no down-clamp that
-        # would shift the block off its run (slots past the run's count
-        # read pad garbage the receiver masks via send_counts)
-        start = jnp.clip(offs_ref[d], 0, cap)
-        dma = pltpu.make_async_copy(
-            x_ref.at[pl.ds(start, C), :], o_ref, sem)
-        dma.start()
-        dma.wait()
-
-    return kern
+# slot block [s*C, (s+1)*C).  Both moves are XLA gathers over
+# scatter-shaped index math (clip(offsets[d]+j) / argsort(~valid)); the
+# module docstring records why the block-DMA kernels were removed.
 
 
 def slot_expand(words: jax.Array, offsets: jax.Array, C: int) -> jax.Array:
@@ -386,60 +362,13 @@ def slot_expand(words: jax.Array, offsets: jax.Array, C: int) -> jax.Array:
     [cap, W] u32; destination d's rows start at ``offsets[d]`` (i32 [D]).
     Returns the [D*C, W] send buffer whose block d holds rows
     offsets[d] .. offsets[d]+C (clamped to the array; slots past the
-    run's count are garbage the receiver masks via send_counts).
-
-    One dynamic-offset block DMA per destination vs the XLA fallback's
-    D*C-row random gather."""
+    run's count are garbage the receiver masks via send_counts)."""
     D = offsets.shape[0]
-    cap, W = words.shape
-    mode = pallas_active()
-    if mode is None or C < _SLOT_MIN_C or D < 2 or cap < C:
-        d_idx = jnp.repeat(jnp.arange(D, dtype=jnp.int32), C)
-        j_idx = jnp.tile(jnp.arange(C, dtype=jnp.int32), D)
-        src = jnp.clip(jnp.take(offsets, d_idx) + j_idx, 0, cap - 1)
-        return jnp.take(words, src, axis=0)
-    import jax.experimental.pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-
-    # C pad rows guarantee every run's block DMA [offs, offs+C) stays in
-    # bounds WITHOUT clamping the start (a down-clamp would shift the
-    # block off its run and ship another destination's rows)
-    xp = jnp.concatenate([words, jnp.zeros((C, W), words.dtype)])
-    return pl.pallas_call(
-        _expand_kernel_body(C, cap),
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=1,
-            grid=(D,),
-            in_specs=[pl.BlockSpec(memory_space=pltpu.ANY)],
-            out_specs=pl.BlockSpec((C, W), lambda d, offs: (d, 0),
-                                   memory_space=pltpu.VMEM),
-            scratch_shapes=[pltpu.SemaphoreType.DMA],
-        ),
-        out_shape=jax.ShapeDtypeStruct((D * C, W), words.dtype),
-        interpret=(mode == "interpret"),
-    )(offsets.astype(jnp.int32), xp)
-
-
-def _compact_kernel_body(C: int, out_rows: int):
-    import jax.experimental.pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-
-    def kern(starts_ref, zeros_ref, x_ref, o_ref, sem):
-        del zeros_ref   # aliased into o_ref: the zero seed
-        s = pl.program_id(0)
-        # o_ref is C-row-padded, so any cursor in [0, out_rows] has C
-        # writable rows — no down-clamp (that would land this block's
-        # valid prefix at the wrong offset AND overwrite earlier valid
-        # rows).  Blocks wholly past out_rows write only the pad
-        # (truncation); the caller slices the pad off.
-        dst = jnp.clip(starts_ref[s], 0, out_rows)
-        dma = pltpu.make_async_copy(
-            x_ref.at[pl.ds(s * C, C), :],
-            o_ref.at[pl.ds(dst, C), :], sem)
-        dma.start()
-        dma.wait()
-
-    return kern
+    cap = words.shape[0]
+    d_idx = jnp.repeat(jnp.arange(D, dtype=jnp.int32), C)
+    j_idx = jnp.tile(jnp.arange(C, dtype=jnp.int32), D)
+    src = jnp.clip(jnp.take(offsets, d_idx) + j_idx, 0, cap - 1)
+    return jnp.take(words, src, axis=0)
 
 
 def slot_compact(words: jax.Array, counts: jax.Array, C: int,
@@ -447,54 +376,17 @@ def slot_compact(words: jax.Array, counts: jax.Array, C: int,
     """Receive-slot compaction: ``words`` is the received slot buffer
     [D*C, W] u32 where source block s's valid rows are the prefix
     ``counts[s]`` (i32 [D], <= C) of rows [s*C, (s+1)*C).  Returns
-    [out_rows, W] with the valid rows dense at the front (block s
-    writes its full C rows at the running cursor and block s+1's write
-    overlaps the tail garbage; the sequential grid makes the last
-    writer deterministic.  Rows past the total hold the last block's
-    deterministic tail, then the zero seed — unspecified-padding rows
-    by the Batch contract, like the fallback's dropped-slot rows).
-
-    One dynamic-offset block DMA per source block vs the XLA fallback's
-    stable valid-sort + full gather."""
-    S, W = words.shape
-    D = counts.shape[0]
+    [out_rows, W] with the valid rows dense at the front and zeros past
+    the total (unspecified-padding rows by the Batch contract)."""
+    S = words.shape[0]
     counts = jnp.minimum(counts.astype(jnp.int32), C)
-    starts = jnp.cumsum(counts) - counts   # exclusive prefix
-    mode = pallas_active()
-    if (mode is None or C < _SLOT_MIN_C or D < 2 or S != D * C
-            or out_rows < C):
-        idx = jnp.arange(S, dtype=jnp.int32)
-        rvalid = (idx % C) < jnp.take(counts, idx // C)
-        # fallback mirrors the pre-kernel lowering: stable valid-first
-        # sort of the row ids, then one packed gather
-        perm = jnp.argsort(~rvalid, stable=True)
-        g = jnp.take(words, perm[:out_rows], axis=0) if S >= out_rows \
-            else jnp.pad(jnp.take(words, perm, axis=0),
-                         ((0, out_rows - S), (0, 0)))
-        total = rvalid.sum(dtype=jnp.int32)
-        gmask = jnp.arange(out_rows, dtype=jnp.int32) < total
-        return jnp.where(gmask[:, None], g, 0)
-    import jax.experimental.pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-
-    # C pad rows let every block write its full C rows at the exact
-    # running cursor (no down-clamp); the pad absorbs the last blocks'
-    # tail garbage and truncated rows, and is sliced off below
-    zeros = jnp.zeros((out_rows + C, W), words.dtype)
-    out = pl.pallas_call(
-        _compact_kernel_body(C, out_rows),
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=1,
-            grid=(D,),
-            in_specs=[pl.BlockSpec(memory_space=pltpu.ANY),
-                      pl.BlockSpec(memory_space=pltpu.ANY)],
-            out_specs=pl.BlockSpec(memory_space=pltpu.ANY),
-            scratch_shapes=[pltpu.SemaphoreType.DMA],
-        ),
-        out_shape=jax.ShapeDtypeStruct((out_rows + C, W), words.dtype),
-        # zero-seeded output (aliased operand): padding rows past the
-        # total stay deterministically 0, matching the XLA fallback
-        input_output_aliases={1: 0},
-        interpret=(mode == "interpret"),
-    )(starts, zeros, words)
-    return out[:out_rows]
+    idx = jnp.arange(S, dtype=jnp.int32)
+    rvalid = (idx % C) < jnp.take(counts, idx // C)
+    # stable valid-first sort of the row ids, then one packed gather
+    perm = jnp.argsort(~rvalid, stable=True)
+    g = jnp.take(words, perm[:out_rows], axis=0) if S >= out_rows \
+        else jnp.pad(jnp.take(words, perm, axis=0),
+                     ((0, out_rows - S), (0, 0)))
+    total = rvalid.sum(dtype=jnp.int32)
+    gmask = jnp.arange(out_rows, dtype=jnp.int32) < total
+    return jnp.where(gmask[:, None], g, 0)

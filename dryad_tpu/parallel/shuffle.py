@@ -78,13 +78,13 @@ def _exchange_one_axis(batch: Batch, dest: jax.Array, axis: str,
 
     if os.environ.get("DRYAD_NO_SORT_OPT") or pallas_active() is None:
         # the pack pipeline is shaped for the TPU data plane (tile
-        # histogram + value-carry sort + block-DMA slot expansion); on
-        # backends where the slot kernels don't engage it measured ~3x
-        # SLOWER than the gather lowering (cpu, BENCH_kernels r06:
-        # XLA's stable argsort + composed gather wins there), so
-        # non-TPU backends keep the plain-XLA form — the module
-        # contract's fallback tier.  force_interpret() routes tests
-        # through the pack path on CPU.
+        # histogram + value-carry sort + packed slot gather + ONE
+        # all_to_all); on the CPU backend it measured ~3x SLOWER than
+        # the gather lowering (BENCH_kernels.json: XLA's stable argsort
+        # + composed gather wins there), so non-TPU backends keep the
+        # plain-XLA form — the module contract's fallback tier.  Which
+        # is faster on the chip is unmeasured (ROADMAP S3).
+        # force_interpret() routes tests through the pack path on CPU.
         return _exchange_one_axis_gather(batch, dest, axis, out_capacity,
                                          C, all_axes)
 
@@ -93,10 +93,9 @@ def _exchange_one_axis(batch: Batch, dest: jax.Array, axis: str,
     # slower at 2M), one UNSTABLE value-carry sort by (dest, row index)
     # moving every column's packed u32 words (the index operand makes
     # the unstable network exactly stable — no stable-sort machinery),
-    # then slot expansion as D dynamic-offset block DMAs
-    # (pallas_kernels.slot_expand): each destination's run is CONTIGUOUS
-    # in the sorted buffer, so the send grid is block copies, not the
-    # fallback's D*C-row random gather.
+    # then slot expansion (pallas_kernels.slot_expand): each
+    # destination's run is CONTIGUOUS in the sorted buffer, so the send
+    # grid is one gather of the packed [cap, W] matrix.
     lanes, spec = _pack_columns_u32(dict(batch.columns))
     counts = hist_buckets(dest, D)                      # full counts [D]
     offsets = jnp.cumsum(counts) - counts               # exclusive prefix
@@ -112,10 +111,9 @@ def _exchange_one_axis(batch: Batch, dest: jax.Array, axis: str,
     recv_words = jax.lax.all_to_all(send_words, axis, 0, 0, tiled=True)
     recv_counts = jax.lax.all_to_all(send_counts, axis, 0, 0, tiled=True)
 
-    # UNPACK: the valid rows of each received source block are a prefix,
-    # so compaction is D more block DMAs (pallas_kernels.slot_compact)
-    # instead of a stable valid-first sort + gather
-    # every sender clamped its send_counts to C already
+    # UNPACK: the valid rows of each received source block are a prefix
+    # (pallas_kernels.slot_compact: valid-first sort + one packed
+    # gather); every sender clamped its send_counts to C already
     total = recv_counts.sum(dtype=jnp.int32)
     out_words = slot_compact(recv_words, recv_counts, C, out_capacity)
     W = len(slanes)

@@ -50,8 +50,8 @@ class JobConfig:
     # the reference pays vertex codegen once per job (csc BuildAssembly,
     # DryadLinqCodeGen.cs:2283); this is our once-per-(program, shapes)
     # equivalent across driver restarts AND worker processes.  None
-    # disables (utils/compile_cache.py — the single source of the
-    # default path)
+    # disables; JAX_COMPILATION_CACHE_DIR, where set, overrides this
+    # (utils/compile_cache.py — the single source of the default path)
     compilation_cache_dir: Optional[str] = _DEFAULT_COMPILE_CACHE_DIR
     # device-time profiling: when set, every executor run is wrapped in a
     # jax.profiler trace written under this directory (open with
@@ -165,10 +165,10 @@ class JobConfig:
     # optimistic stage execution (exec/recovery.Run._settle): stages run
     # with ZERO per-stage host syncs; every needs vector is batch-fetched
     # once at job end, and overflows replay synchronously from the first
-    # affected stage.  On a high-latency dispatch link (remote tunnel,
-    # ~0.1 s/round trip) this is the difference between O(stages) and
-    # O(1) round trips per job.  Reference: one DVertexCommandBlock start
-    # per vertex — the GM does not chat mid-vertex (dvertexcommand.h:199).
+    # affected stage: O(1) instead of O(stages) host round trips per
+    # job, which matters in proportion to the dispatch latency.
+    # Reference: one DVertexCommandBlock start per vertex — the GM does
+    # not chat mid-vertex (dvertexcommand.h:199).
     deferred_needs: bool = True
 
     # whole-group streamed operators (group_apply / group_median over
@@ -179,8 +179,8 @@ class JobConfig:
 
     # pick ooc chunk sizes from MEASURED link + dispatch rates instead of
     # the static ooc_chunk_rows (exec/autotune.pick_chunk_rows): on a
-    # high-latency tunnel the tuner grows chunks until the per-dispatch
-    # floor is amortized; on healthy hardware the lower clamp applies.
+    # high-latency link the tuner grows chunks until the per-dispatch
+    # floor is amortized; on a local link the lower clamp applies.
     # Opt-in: explicit chunk_rows arguments always win.
     ooc_chunk_autotune: bool = False
 

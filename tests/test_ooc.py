@@ -239,14 +239,14 @@ def test_terasort_ooc_oracle(tmp_path):
 
 def test_autotune_chunk_rows_model():
     """pick_chunk_rows amortizes a measured dispatch floor against the
-    measured link rate (VERDICT r4 weak 4: chunk_rows was hand-set)."""
+    measured link rate (chunk_rows was hand-set before)."""
     from dryad_tpu.exec.autotune import pick_chunk_rows
 
-    # tunnel-like: 0.1 s floor, 5 MB/s link, 18 B rows -> big chunks:
+    # slow link: 0.1 s floor, 5 MB/s link, 18 B rows -> big chunks:
     # transfer must be >= 0.1 * 0.85/0.15 = 0.57 s -> ~157k rows
     rows = pick_chunk_rows(18, rates=(5e6, 0.1))
     assert 120_000 <= rows <= 200_000
-    # healthy link: microsecond floor -> lower clamp
+    # local link: microsecond floor -> lower clamp
     assert pick_chunk_rows(18, rates=(1e9, 2e-6)) == 4096
     # program-size guard caps wide rows
     rows = pick_chunk_rows(18, rates=(1e9, 10.0), row_lanes=8)
