@@ -21,6 +21,7 @@ import numpy as np
 from dryad_tpu import oracle as _oracle
 from dryad_tpu.exec.data import PData, pdata_from_host, pdata_to_host
 from dryad_tpu.exec.executor import Executor
+from dryad_tpu.obs import trace
 from dryad_tpu.parallel.mesh import make_mesh
 from dryad_tpu.plan import expr as E
 from dryad_tpu.plan.expr import Decomposable  # noqa: F401 (re-export)
@@ -660,28 +661,34 @@ class Context:
         scheme dispatch); the same goes for ``read_store_stream`` and
         ``to_store``."""
         from dryad_tpu.io.store import read_store, store_meta
-        meta = store_meta(path)
-        auto = self.config.ooc_auto_stream_rows
-        if (auto and self.cluster is None
-                and sum(meta.get("counts", [])) >= auto):
-            # size-threshold streaming: a big store never tries to fit in
-            # HBM (VERDICT r2 next-round item 1)
-            return self.read_store_stream(path)
-        pmeta = meta.get("partitioning", {"kind": "none"})
-        part = E.Partitioning(pmeta.get("kind", "none"),
-                              tuple(pmeta.get("keys", ())))
-        # re-blocking across a different mesh size destroys hash placement
-        if meta["npartitions"] != self.nparts:
-            part = E.Partitioning.none()
-        if self.cluster is not None:
-            from dryad_tpu.runtime.sources import DeferredSource, store_spec
-            spec = store_spec(path, self.nparts, meta, capacity=capacity)
-            node = E.Source(parents=(), data=DeferredSource(spec),
-                            _npartitions=self.nparts, _partitioning=part)
-            return Dataset(self, node)
-        pdata = read_store(path, self.mesh, capacity=capacity,
-                           verify=self.config.store_verify_checksums)
-        return self.from_pdata(pdata, partitioning=part)
+        # the read is eager, so it is a query of its own in the trace
+        with trace.span("from_store", "query", source=path):
+            meta = store_meta(path)
+            auto = self.config.ooc_auto_stream_rows
+            if (auto and self.cluster is None
+                    and sum(meta.get("counts", [])) >= auto):
+                # size-threshold streaming: a big store never tries to fit
+                # in HBM (VERDICT r2 next-round item 1)
+                return self.read_store_stream(path)
+            pmeta = meta.get("partitioning", {"kind": "none"})
+            part = E.Partitioning(pmeta.get("kind", "none"),
+                                  tuple(pmeta.get("keys", ())))
+            # re-blocking across a different mesh size destroys hash
+            # placement
+            if meta["npartitions"] != self.nparts:
+                part = E.Partitioning.none()
+            if self.cluster is not None:
+                from dryad_tpu.runtime.sources import (DeferredSource,
+                                                       store_spec)
+                spec = store_spec(path, self.nparts, meta,
+                                  capacity=capacity)
+                node = E.Source(parents=(), data=DeferredSource(spec),
+                                _npartitions=self.nparts,
+                                _partitioning=part)
+                return Dataset(self, node)
+            pdata = read_store(path, self.mesh, capacity=capacity,
+                               verify=self.config.store_verify_checksums)
+            return self.from_pdata(pdata, partitioning=part)
 
     # -- iteration ---------------------------------------------------------
 
@@ -1395,12 +1402,15 @@ class Dataset:
                                 if self.ctx.executor else None)
 
     def _materialize(self) -> PData:
-        graph = plan_query(self.node, self.ctx.nparts,
-                           hosts=self.ctx.hosts,
-                           levels=self.ctx.levels,
-                           config=self.ctx.config)
-        cost_rep = self.ctx._pre_submit_lint(self.node, cluster=False,
-                                             graph=graph)
+        with trace.span("plan", "plan") as sp:
+            graph = plan_query(self.node, self.ctx.nparts,
+                               hosts=self.ctx.hosts,
+                               levels=self.ctx.levels,
+                               config=self.ctx.config)
+            sp.set(stages=len(graph.stages))
+        with trace.span("lint", "plan"):
+            cost_rep = self.ctx._pre_submit_lint(self.node, cluster=False,
+                                                 graph=graph)
         pd = self.ctx.executor.run(graph, spill_dir=self.ctx.spill_dir,
                                    cost_report=cost_rep)
         # runtime hot-key salting — and adaptive broadcast flips
@@ -1424,10 +1434,19 @@ class Dataset:
             from dryad_tpu.exec.stream_exec import chunks_to_table
             out = chunks_to_table(self._stream_run())
         else:
-            from dryad_tpu.exec.data import maybe_shrink_for_collect
-            out = pdata_to_host(
-                maybe_shrink_for_collect(self._materialize(),
-                                         config=self.ctx.config))
+            from dryad_tpu.exec.data import (batch_nbytes,
+                                             maybe_shrink_for_collect)
+            # the terminal call is the root of the query's spans: plan,
+            # lint, run and the fetch share its trace id
+            with trace.span("collect", "query") as sp:
+                pd = self._materialize()
+                with trace.span("collect.fetch", "io") as fsp:
+                    pd = maybe_shrink_for_collect(pd,
+                                                  config=self.ctx.config)
+                    fsp.set(bytes=batch_nbytes(pd.batch))
+                    out = pdata_to_host(pd)
+                sp.set(rows=next((len(v) for v in out.values()), 0),
+                       sink="host")
         if isinstance(self.node, E.Take):
             n = self.node.n
             out = {k: v[:n] for k, v in out.items()}
@@ -1461,12 +1480,14 @@ class Dataset:
                 partitioning={"kind": part.kind, "keys": list(part.keys)},
                 compression=compression)
             return
-        pd = self._materialize()
-        if getattr(self, "_last_salted", False):
-            part = E.Partitioning.none()
-        write_store(path, pd, partitioning={"kind": part.kind,
-                                            "keys": list(part.keys)},
-                    compression=compression)
+        with trace.span("to_store", "query") as sp:
+            pd = self._materialize()
+            if getattr(self, "_last_salted", False):
+                part = E.Partitioning.none()
+            sp.set(sink=path, rows=write_store(
+                path, pd, partitioning={"kind": part.kind,
+                                        "keys": list(part.keys)},
+                compression=compression))
 
     def count(self) -> int:
         if self.ctx.local_debug:

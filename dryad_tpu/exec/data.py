@@ -22,7 +22,13 @@ from dryad_tpu.data.columnar import Batch, StringColumn
 from dryad_tpu.parallel.mesh import batch_sharding
 
 __all__ = ["PData", "pdata_from_host", "pdata_to_host", "put_batch",
-           "replicate_tree", "collect_replicated"]
+           "replicate_tree", "collect_replicated", "batch_nbytes"]
+
+
+def batch_nbytes(tree) -> int:
+    """Bytes a pytree of arrays holds, from shapes alone (no data is
+    touched, on the host or the device)."""
+    return int(sum(x.nbytes for x in jax.tree.leaves(tree)))
 
 
 def mesh_is_multiprocess(mesh) -> bool:

@@ -19,6 +19,7 @@ exec/recovery.py.
 
 from __future__ import annotations
 
+import re
 import time
 from typing import Callable, Dict, List, Optional, Tuple
 
@@ -28,6 +29,7 @@ import numpy as np
 
 from dryad_tpu.data.columnar import Batch, StringColumn
 from dryad_tpu.exec.data import PData
+from dryad_tpu.obs import trace
 from dryad_tpu.ops import kernels
 from dryad_tpu.ops.text import (lower_ascii, split_tokens,
                                 tokenize_group_count)
@@ -36,7 +38,7 @@ from dryad_tpu.parallel.mesh import PARTITION_AXIS
 from dryad_tpu.plan.stages import Exchange, Stage, StageGraph, StageOp
 from jax.sharding import PartitionSpec as P
 
-__all__ = ["Executor", "CapacityError"]
+__all__ = ["Executor", "CapacityError", "stage_program_name"]
 
 _MAX_CAPACITY_RETRIES = 3
 _SAMPLES_PER_PART = 4096
@@ -45,6 +47,8 @@ _SAMPLES_PER_PART = 4096
 # deferred settle can stack infos across stages; stages with more
 # exchange legs simply don't get feedback for the extras)
 _SLOT_FEEDBACK_LEGS = 4
+# a program's name with jit's own "jit_" before it stays within 64
+_PROGRAM_NAME_MAX = 60
 
 
 def _quantize_slot_rows(slot: int) -> int:
@@ -97,6 +101,25 @@ _FIXED_OVERFLOW_KINDS = {"recap", "sliding_window"}
 def _stage_kinds(stage: Stage) -> set:
     return ({op.kind for leg in stage.legs for op in leg.ops}
             | {op.kind for op in stage.body})
+
+
+def stage_program_name(stage: Stage) -> str:
+    """Name of a stage's device program: ``stage_<label>_<op kinds>`` in
+    plan order (each leg's ops, then its exchange, then the body), so a
+    profiler's module line reads ``jit_stage_orderby_range_sort``.  A
+    pure function of the plan — no stage id, fingerprint or counter —
+    so one program has one name in every process and the persistent
+    compile cache keeps hitting."""
+    kinds = []
+    for leg in stage.legs:
+        kinds += [op.kind for op in leg.ops]
+        if leg.exchange is not None:
+            kinds.append(leg.exchange.kind)
+    kinds += [op.kind for op in stage.body]
+    words = [stage.label] + [k for i, k in enumerate(kinds)
+                             if i == 0 or k != kinds[i - 1]]
+    name = re.sub(r"[^a-z0-9]+", "_", "_".join(words).lower()).strip("_")
+    return ("stage_" + name)[:_PROGRAM_NAME_MAX].rstrip("_")
 
 
 def _stage_overflow_scalable(stage: Stage) -> bool:
@@ -587,6 +610,8 @@ class Executor:
                                     slots])
             return _expand(cur), info[None]
 
+        per_shard.__name__ = per_shard.__qualname__ = \
+            stage_program_name(stage)
         in_specs = tuple([P(self.axes)] * n_legs +
                          ([P()] if has_bounds else []))
         fn = jax.shard_map(per_shard, mesh=self.mesh, in_specs=in_specs,
@@ -782,7 +807,7 @@ class Executor:
         if fn is None:
             axes = self.axes
 
-            def per_shard(batch):
+            def probe_slot_rows(batch):
                 b = _squeeze(batch)
                 _, lo = hash_batch_keys(b, list(keys))
                 dest = _canonical_hash_dest(lo, axes)
@@ -792,7 +817,7 @@ class Executor:
                 return jax.lax.pmax(m, axes)[None]
 
             fn = jax.jit(jax.shard_map(
-                per_shard, mesh=self.mesh, in_specs=P(self.axes),
+                probe_slot_rows, mesh=self.mesh, in_specs=P(self.axes),
                 out_specs=P(self.axes[0]), check_vma=False))
             self._compile_cache[key] = fn
         slot = int(np.asarray(fn(b0)).max())
@@ -876,7 +901,7 @@ class Executor:
     def _run_stage(self, stage: Stage, results, bindings,
                    defer: Optional[list] = None, event=None,
                    cost_report=None, stats_box: Optional[list] = None,
-                   job=None) -> PData:
+                   job=None, span=trace.NULL) -> PData:
         # per-job driver state (exec/recovery.Run threads these): the
         # event sink, cost report, and observed-stats box belong to the
         # CALLING run, not this (possibly shared) executor
@@ -924,12 +949,15 @@ class Executor:
                 # from run time (the device-time profiling the reference
                 # surfaces through Artemis; VERDICT r1 weak item 8)
                 t0 = time.time()
-                fn = self._build_stage_fn(stage, scale, slack, len(inputs),
-                                          bounds is not None,
-                                          salted=salted,
-                                          slot_hints=slot_hints
-                                          ).lower(*args).compile()
-                compile_s = time.time() - t0
+                with trace.span("stage.compile", "compile", sink=ev) as csp:
+                    fn = self._build_stage_fn(stage, scale, slack,
+                                              len(inputs),
+                                              bounds is not None,
+                                              salted=salted,
+                                              slot_hints=slot_hints
+                                              ).lower(*args).compile()
+                    compile_s = time.time() - t0
+                    csp.set(compile_s=round(compile_s, 4))
                 _M_COMPILE_S.inc(compile_s)
                 with self._cache_lock:
                     self._compile_cache[key] = fn
@@ -943,6 +971,9 @@ class Executor:
                 # signal (labels ride the same canonical families)
                 _family(_METRICS, "cache_hits" if cache_hit
                         else "cache_misses", job=job).inc()
+            if span is not trace.NULL:
+                span.set(program="jit_" + stage_program_name(stage),
+                         cache_hit=cache_hit)
             t0 = time.time()
             out_batch, info = fn(*args)
             if defer is not None and attempt == 0:
@@ -963,8 +994,9 @@ class Executor:
                 out_bytes = int(sum(
                     x.size * x.dtype.itemsize
                     for x in jax.tree.leaves(out_batch)))
+                # run_seconds is fed the wait for the device, which on
+                # this path is Run._settle's
                 _M_STAGE_RUNS.inc()
-                _M_RUN_S.inc(enqueue_s)
                 _M_SHUFFLE_B.inc(out_bytes)
                 defer.append({"stage": stage, "info": info,
                               "scale": scale, "slack": slack,
@@ -979,7 +1011,8 @@ class Executor:
             if self._multiproc:
                 from dryad_tpu.exec.data import replicate_tree
                 info = replicate_tree(info, self.mesh)
-            info = np.asarray(info)  # [P, 4+legs] (the ONE device sync)
+            with trace.span("settle", "wait", sink=ev, deferred=0):
+                info = np.asarray(info)  # [P, 4+legs] (the ONE device sync)
             wall = time.time() - t0
             # exchange slot feedback rides the fetch — a retry (and every
             # later run of this stage) ships measured exact slots

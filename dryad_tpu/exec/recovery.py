@@ -242,12 +242,24 @@ class Run:
         policy to its sticky knobs, invalidates it plus every dependent
         result, and replays synchronously.  Overflow is the rare case;
         the common case pays zero per-stage round trips."""
+        import time
+
         import numpy as np
 
         import jax.numpy as jnp
 
+        from dryad_tpu.obs import trace
+        from dryad_tpu.obs.metrics import REGISTRY, family_counter
+
         deferred, self._defer = self._defer, None   # replay runs sync
-        infos = np.asarray(jnp.stack([r["info"] for r in deferred]))
+        # the host waits here for the device: every info is an output of
+        # its stage program, so the fetch ends when the last program has
+        t0 = time.perf_counter()
+        with trace.span("settle", "wait", sink=self._event,
+                        deferred=len(deferred)):
+            infos = np.asarray(jnp.stack([r["info"] for r in deferred]))
+        family_counter(REGISTRY, "run_seconds").inc(
+            time.perf_counter() - t0)
         bad: Dict[int, tuple] = {}
         for rec, info in zip(deferred, infos):
             stage = rec["stage"]
@@ -286,8 +298,6 @@ class Run:
                 # the deferred path counts runs/bytes at enqueue
                 # (executor defer branch); the overflow verdict only
                 # exists here, so the retry counter settles here too
-                from dryad_tpu.obs.metrics import (REGISTRY,
-                                                   family_counter)
                 family_counter(REGISTRY, "cap_retries").inc()
                 decision = self.ex._decide_needs(
                     stage, rec["scale"], rec["slack"], rec["salted"],
@@ -367,17 +377,17 @@ class Run:
         stage = self.graph.stage(sid)
         from dryad_tpu.obs import trace
         # one span per stage execution (compile + run attempts; on the
-        # deferred path this covers the enqueue only — the device time
-        # lands in the settle's stage_done events)
+        # deferred path this covers the enqueue only — the wait for the
+        # device is the run's ``settle`` span)
         with trace.span(f"stage {stage.id}:{stage.label}", "stage",
                         sink=self._event, stage=stage.id,
                         label=stage.label,
-                        deferred=self._defer is not None):
+                        deferred=self._defer is not None) as sp:
             out = self.ex._run_stage(stage, self._results, self.bindings,
                                      defer=self._defer, event=self._event,
                                      cost_report=self.cost_report,
                                      stats_box=self._stats_box,
-                                     job=self.job)
+                                     job=self.job, span=sp)
         self._results[sid] = out
         self._save_spill(sid, out)
         if self.checkpoint is not None:

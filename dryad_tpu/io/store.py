@@ -26,7 +26,8 @@ import numpy as np
 
 from dryad_tpu import native
 from dryad_tpu.data.columnar import Batch, StringColumn
-from dryad_tpu.exec.data import PData
+from dryad_tpu.exec.data import PData, batch_nbytes, put_batch
+from dryad_tpu.obs import trace
 
 __all__ = ["write_store", "read_store", "store_meta", "build_meta",
            "schema_row_bytes", "StoreIntegrityError", "is_remote_store",
@@ -202,6 +203,11 @@ def fill_segments(segs: List[np.ndarray], data: bytes, what: str) -> None:
         off += nb
 
 
+def _segments_nbytes(segments) -> int:
+    """Payload bytes of per-partition segment lists, from their shapes."""
+    return sum(s.nbytes for segs in segments for s in segs)
+
+
 def _part_segments_for_write(batch: Batch, schema, p: int, n: int
                              ) -> List[np.ndarray]:
     """Column blobs of partition p, valid rows only, in sorted-column order."""
@@ -218,10 +224,10 @@ def _part_segments_for_write(batch: Batch, schema, p: int, n: int
 
 def write_store(path: str, pd: PData,
                 partitioning: Optional[Dict[str, Any]] = None,
-                compression: Optional[str] = None) -> None:
+                compression: Optional[str] = None) -> Optional[int]:
     """Persist a PData (ToStore, DryadLinqQueryable.cs:3909).  Atomic via
     temp-dir rename (the reference commits temp outputs at job end,
-    DrVertex.h:325-351).
+    DrVertex.h:325-351).  A local write returns the rows it wrote.
 
     ``compression="gzip"`` writes level-1 gzip partition files (the
     per-channel compression transform of the reference,
@@ -239,28 +245,40 @@ def write_store(path: str, pd: PData,
         from dryad_tpu.io.webhdfs import hdfs_write_store
         return hdfs_write_store(path, pd, partitioning=partitioning,
                                 compression=compression)
-    tmp = path + ".tmp"
-    os.makedirs(tmp, exist_ok=True)
-    counts = np.asarray(pd.counts)
-    schema = pdata_schema(pd)
-    paths, segments = [], []
-    for p in range(pd.nparts):
-        paths.append(_part_path(tmp, p))
-        segments.append(_part_segments_for_write(
-            pd.batch, schema, p, int(counts[p])))
-    native.write_files(paths, segments,
-                       compress=(compression == "gzip"))
-    checksums = ["%016x" % native.checksum_segments(segs)
-                 for segs in segments]
-    meta = build_meta(schema, counts.tolist(), checksums,
-                      partitioning=partitioning, compression=compression,
-                      capacity=pd.capacity)
-    with open(os.path.join(tmp, "meta.json"), "w") as f:
-        json.dump(meta, f, indent=1)
-    if os.path.exists(path):
-        import shutil
-        shutil.rmtree(path)
-    os.rename(tmp, path)
+    with trace.span("store.write", "io", partitions=pd.nparts) as sp:
+        tmp = path + ".tmp"
+        os.makedirs(tmp, exist_ok=True)
+        counts = np.asarray(pd.counts)
+        schema = pdata_schema(pd)
+        paths, segments = [], []
+        for p in range(pd.nparts):
+            paths.append(_part_path(tmp, p))
+            # device -> host and the contiguous copy, a partition at a time
+            with trace.span("store.fetch", "io", partition=p) as fsp:
+                segments.append(_part_segments_for_write(
+                    pd.batch, schema, p, int(counts[p])))
+                fsp.set(bytes=_segments_nbytes(segments[-1:]))
+        nbytes = _segments_nbytes(segments)
+        sp.set(bytes=nbytes)
+        with trace.span("store.file_write", "io", bytes=nbytes,
+                        files=len(paths)):
+            native.write_files(paths, segments,
+                               compress=(compression == "gzip"))
+        with trace.span("store.checksum", "io", bytes=nbytes):
+            checksums = ["%016x" % native.checksum_segments(segs)
+                         for segs in segments]
+        with trace.span("store.commit", "io"):
+            meta = build_meta(schema, counts.tolist(), checksums,
+                              partitioning=partitioning,
+                              compression=compression,
+                              capacity=pd.capacity)
+            with open(os.path.join(tmp, "meta.json"), "w") as f:
+                json.dump(meta, f, indent=1)
+            if os.path.exists(path):
+                import shutil
+                shutil.rmtree(path)
+            os.rename(tmp, path)
+        return int(counts.sum())
 
 
 def append_store(path: str, pd: PData) -> int:
@@ -394,59 +412,67 @@ def read_store(path: str, mesh, capacity: Optional[int] = None,
     meta = store_meta(path)
     part_ids = (list(range(meta["npartitions"])) if partitions is None
                 else list(partitions))
-    counts = [meta["counts"][p] for p in part_ids]
-    nparts_store = len(part_ids)
-    schema = meta["schema"]
-    nparts = mesh.devices.size
+    with trace.span("store.read", "io", partitions=len(part_ids),
+                    columns=len(meta["schema"])) as sp:
+        counts = [meta["counts"][p] for p in part_ids]
+        nparts_store = len(part_ids)
+        schema = meta["schema"]
+        nparts = mesh.devices.size
 
-    paths, segments, partviews = [], [], []
-    if is_remote_store(path):
-        for p in part_ids:
-            segs, cols = remote_read_part_views(path, meta, p)
-            segments.append(segs)
-            partviews.append(cols)
-    else:
-        for p in part_ids:
-            segs, cols = _alloc_part_views(schema, meta["counts"][p])
-            paths.append(_part_path(path, p))
-            segments.append(segs)
-            partviews.append(cols)
-        native.read_files(paths, segments,
-                          compress=(meta.get("compression") == "gzip"))
-    if verify:
-        verify_checksums(path, meta, segments, partitions=part_ids)
+        paths, segments, partviews = [], [], []
+        with trace.span("store.file_read", "io", files=len(part_ids)) as fsp:
+            if is_remote_store(path):
+                for p in part_ids:
+                    segs, cols = remote_read_part_views(path, meta, p)
+                    segments.append(segs)
+                    partviews.append(cols)
+            else:
+                for p in part_ids:
+                    segs, cols = _alloc_part_views(schema, meta["counts"][p])
+                    paths.append(_part_path(path, p))
+                    segments.append(segs)
+                    partviews.append(cols)
+                native.read_files(paths, segments,
+                                  compress=(meta.get("compression") == "gzip"))
+            nbytes = _segments_nbytes(segments)
+            fsp.set(bytes=nbytes)
+        sp.set(bytes=nbytes)
+        if verify:
+            with trace.span("store.verify", "io", bytes=nbytes):
+                verify_checksums(path, meta, segments, partitions=part_ids)
 
-    if nparts_store == nparts:
-        # verbatim per-partition load: placement-preserving
-        cap = capacity or max(int(meta.get("capacity", 0)),
-                              max(counts or [0]), 1)
-        part_rows = [{k: (partviews[p][k][1:3]
+        if nparts_store == nparts:
+            # verbatim per-partition load: placement-preserving
+            cap = capacity or max(int(meta.get("capacity", 0)),
+                                  max(counts or [0]), 1)
+            part_rows = [{k: (partviews[p][k][1:3]
+                              if schema[k]["kind"] == "str"
+                              else partviews[p][k][1])
+                          for k in schema} for p in range(nparts)]
+            return _stack_partitions(schema, part_rows, counts, cap, mesh)
+
+        # partition counts differ: concatenate store partitions then
+        # re-block over the mesh (placement-destroying; callers drop
+        # partitioning claims)
+        concat: Dict[str, Any] = {}
+        for k in schema:
+            if schema[k]["kind"] == "str":
+                concat[k] = (np.concatenate([pv[k][1] for pv in partviews]),
+                             np.concatenate([pv[k][2] for pv in partviews]))
+            else:
+                concat[k] = np.concatenate([pv[k][1] for pv in partviews])
+
+        total = sum(counts)
+        base, rem = divmod(total, nparts)
+        sizes = [base + (1 if p < rem else 0) for p in range(nparts)]
+        cap = capacity or max(1, max(sizes))
+        offs = np.cumsum([0] + sizes)
+        part_rows = [{k: ((concat[k][0][offs[p]:offs[p + 1]],
+                           concat[k][1][offs[p]:offs[p + 1]])
                           if schema[k]["kind"] == "str"
-                          else partviews[p][k][1])
+                          else concat[k][offs[p]:offs[p + 1]])
                       for k in schema} for p in range(nparts)]
-        return _stack_partitions(schema, part_rows, counts, cap, mesh)
-
-    # partition counts differ: concatenate store partitions then re-block
-    # over the mesh (placement-destroying; callers drop partitioning claims)
-    concat: Dict[str, Any] = {}
-    for k in schema:
-        if schema[k]["kind"] == "str":
-            concat[k] = (np.concatenate([pv[k][1] for pv in partviews]),
-                         np.concatenate([pv[k][2] for pv in partviews]))
-        else:
-            concat[k] = np.concatenate([pv[k][1] for pv in partviews])
-
-    total = sum(counts)
-    base, rem = divmod(total, nparts)
-    sizes = [base + (1 if p < rem else 0) for p in range(nparts)]
-    cap = capacity or max(1, max(sizes))
-    offs = np.cumsum([0] + sizes)
-    part_rows = [{k: ((concat[k][0][offs[p]:offs[p + 1]],
-                       concat[k][1][offs[p]:offs[p + 1]])
-                      if schema[k]["kind"] == "str"
-                      else concat[k][offs[p]:offs[p + 1]])
-                  for k in schema} for p in range(nparts)]
-    return _stack_partitions(schema, part_rows, sizes, cap, mesh)
+        return _stack_partitions(schema, part_rows, sizes, cap, mesh)
 
 
 def _stack_partitions(schema, part_rows: List[Dict[str, Any]],
@@ -460,22 +486,28 @@ def _stack_partitions(schema, part_rows: List[Dict[str, Any]],
         raise ValueError(f"capacity {cap} < max partition count "
                          f"{max(counts)}")
     cols: Dict[str, Any] = {}
-    for k, spec in schema.items():
-        if spec["kind"] == "str":
-            max_len = spec["max_len"]
-            sd = np.zeros((nparts, cap, max_len), np.uint8)
-            sl = np.zeros((nparts, cap), np.int32)
-            for p in range(nparts):
-                d, l = part_rows[p][k]
-                sd[p, : counts[p]] = d
-                sl[p, : counts[p]] = l
-            cols[k] = StringColumn(sd, sl)
-        else:
-            first = part_rows[0][k]
-            stacked = np.zeros((nparts, cap) + first.shape[1:], first.dtype)
-            for p in range(nparts):
-                stacked[p, : counts[p]] = part_rows[p][k]
-            cols[k] = stacked
-    from dryad_tpu.exec.data import put_batch
-    batch = put_batch(Batch(cols, np.asarray(counts, np.int32)), mesh)
+    with trace.span("store.stack", "io") as sp:
+        for k, spec in schema.items():
+            if spec["kind"] == "str":
+                max_len = spec["max_len"]
+                sd = np.zeros((nparts, cap, max_len), np.uint8)
+                sl = np.zeros((nparts, cap), np.int32)
+                for p in range(nparts):
+                    d, l = part_rows[p][k]
+                    sd[p, : counts[p]] = d
+                    sl[p, : counts[p]] = l
+                cols[k] = StringColumn(sd, sl)
+            else:
+                first = part_rows[0][k]
+                stacked = np.zeros((nparts, cap) + first.shape[1:],
+                                   first.dtype)
+                for p in range(nparts):
+                    stacked[p, : counts[p]] = part_rows[p][k]
+                cols[k] = stacked
+        host = Batch(cols, np.asarray(counts, np.int32))
+        nbytes = batch_nbytes(host)
+        sp.set(bytes=nbytes)
+    # the enqueue only: the copy to the device ends after this span does
+    with trace.span("store.put", "io", bytes=nbytes):
+        batch = put_batch(host, mesh)
     return PData(batch, nparts)

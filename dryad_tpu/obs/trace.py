@@ -22,6 +22,13 @@ Overhead contract (the DRYAD_LOGGING_LEVEL=0 acceptance bar): with no
 sink installed, or level <= 1, ``span()``/``start()`` return a shared
 null object — one env read and one comparison on the hot path, zero
 event construction.
+
+One clock: ``t0`` is the host's wall clock (``time.time()``, kept to
+1 us) and ``dur_s`` a monotonic duration (``perf_counter``).  The wall
+clock is the one the JAX profiler counts from (``profile_start_time``
+of an ``.xplane.pb``), so a span is laid beside the device's operations
+of a trace by ``t0 * 1e9 - profile_start_time`` with no tracer on the
+host (perfbench/trace_reduce.add_host_spans does exactly that).
 """
 
 from __future__ import annotations

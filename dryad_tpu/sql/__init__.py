@@ -30,6 +30,7 @@ from __future__ import annotations
 
 from typing import Tuple
 
+from dryad_tpu.obs import trace
 from dryad_tpu.sql.binder import BoundSelect, bind
 from dryad_tpu.sql.catalog import (Catalog, CatalogTable, SchemaContext,
                                    SchemaOnlyTableError)
@@ -58,8 +59,10 @@ def compile_query(catalog: Catalog, text: str,
     """Parse + bind (no Context needed): returns (mode, BoundSelect)
     where mode reflects a leading ``EXPLAIN [COST]``.  Raises
     :class:`SqlError` with all DTA3xx findings."""
-    mode, stmt = parse_statement(text, origin=origin)
-    return mode, bind(catalog, stmt)
+    with trace.span("sql.parse", "front"):
+        mode, stmt = parse_statement(text, origin=origin)
+    with trace.span("sql.bind", "front"):
+        return mode, bind(catalog, stmt)
 
 
 def query(ctx, catalog: Catalog, text: str, origin: str = "<sql>",
@@ -73,12 +76,15 @@ def query(ctx, catalog: Catalog, text: str, origin: str = "<sql>",
 
 def _lowered(ctx, catalog: Catalog, text: str, origin: str = "<sql>",
              event=None):
-    mode, bound = compile_query(catalog, text, origin=origin)
-    if mode != "run":
-        raise ValueError(
-            "EXPLAIN statements build no dataset — use sql.explain()")
-    ds, handles = lower(ctx, catalog, bound)
-    _emit(ctx, event, text, catalog, bound)
+    with trace.span("sql.query", "query"):
+        mode, bound = compile_query(catalog, text, origin=origin)
+        if mode != "run":
+            raise ValueError(
+                "EXPLAIN statements build no dataset — use sql.explain()")
+        # the catalog's from_store (an eager store.read) nests in here
+        with trace.span("sql.lower", "front"):
+            ds, handles = lower(ctx, catalog, bound)
+        _emit(ctx, event, text, catalog, bound)
     return ds, handles
 
 

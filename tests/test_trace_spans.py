@@ -1,0 +1,302 @@
+"""The program's own spans along a query (obs/trace.py call sites in
+io/store.py, exec/data.py, api/dataset.py, exec/recovery.py,
+exec/executor.py and sql/), and the stable names of stage programs.
+
+Small sizes on the suite's virtual CPU devices; every case is its own
+parametrised test so that each counts."""
+
+import os
+import re
+
+import jax
+import numpy as np
+import pytest
+
+from dryad_tpu import Context, make_mesh, sql
+from dryad_tpu.exec.executor import stage_program_name
+from dryad_tpu.obs import trace
+from dryad_tpu.plan import expr as E
+from dryad_tpu.plan.planner import plan_query
+
+N = 4096
+
+
+@pytest.fixture(autouse=True)
+def _detach_sink():
+    yield
+    trace.install(None)
+
+
+def _columns():
+    return {"k": np.arange(N, dtype=np.int32)[::-1].copy(),
+            "v": np.arange(N, dtype=np.float32),
+            "g": (np.arange(N) % 3).astype(np.int32)}
+
+
+def _ctx(ndev, events):
+    return Context(mesh=make_mesh(jax.devices()[:ndev]),
+                   event_log=events.append if events is not None else None)
+
+
+def _input_store(tmp_path, ndev):
+    path = str(tmp_path / "in")
+    _ctx(ndev, None).from_columns(_columns()).to_store(path)
+    return path
+
+
+def _sort_query(ctx, src, dst):
+    ctx.from_store(src).order_by([("k", False)]).to_store(dst)
+
+
+def _sql_query(ctx, src):
+    cat = sql.Catalog().register_store("t", src)
+    return sql.query(ctx, cat,
+                     "SELECT g, SUM(v) AS s FROM t GROUP BY g").collect()
+
+
+def _spans(events):
+    return [e for e in events if e.get("event") == "span"]
+
+
+@pytest.fixture(scope="module")
+def sort_spans(tmp_path_factory):
+    tmp = tmp_path_factory.mktemp("sort")
+    src = _input_store(tmp, 1)
+    events = []
+    _sort_query(_ctx(1, events), src, str(tmp / "out"))
+    trace.install(None)
+    return _spans(events), str(tmp / "out")
+
+
+@pytest.fixture(scope="module")
+def sql_spans(tmp_path_factory):
+    tmp = tmp_path_factory.mktemp("sql")
+    src = _input_store(tmp, 1)
+    events = []
+    out = _sql_query(_ctx(1, events), src)
+    trace.install(None)
+    assert sorted(out["g"].tolist()) == [0, 1, 2]
+    return _spans(events)
+
+
+def _one(spans, name):
+    hit = [s for s in spans if s["name"] == name]
+    assert len(hit) == 1, (name, [s["name"] for s in spans])
+    return hit[0]
+
+
+def _parent_name(spans, span):
+    by_id = {s["span"]: s for s in spans}
+    return by_id[span["parent"]]["name"] if span.get("parent") else None
+
+
+# name, kind, the name of its parent (stage spans are matched by kind)
+SORT_TREE = [
+    ("from_store", "query", None),
+    ("store.read", "io", "from_store"),
+    ("store.file_read", "io", "store.read"),
+    ("store.verify", "io", "store.read"),
+    ("store.stack", "io", "store.read"),
+    ("store.put", "io", "store.read"),
+    ("to_store", "query", None),
+    ("plan", "plan", "to_store"),
+    ("lint", "plan", "to_store"),
+    ("run", "job", "to_store"),
+    ("settle", "wait", "run"),
+    ("store.write", "io", "to_store"),
+    ("store.fetch", "io", "store.write"),
+    ("store.file_write", "io", "store.write"),
+    ("store.checksum", "io", "store.write"),
+    ("store.commit", "io", "store.write"),
+]
+
+SQL_TREE = [
+    ("sql.query", "query", None),
+    ("sql.parse", "front", "sql.query"),
+    ("sql.bind", "front", "sql.query"),
+    ("sql.lower", "front", "sql.query"),
+    ("from_store", "query", "sql.lower"),
+    ("store.read", "io", "from_store"),
+    ("collect", "query", None),
+    ("plan", "plan", "collect"),
+    ("lint", "plan", "collect"),
+    ("run", "job", "collect"),
+    ("settle", "wait", "run"),
+    ("collect.fetch", "io", "collect"),
+]
+
+
+@pytest.mark.parametrize("name,kind,parent", SORT_TREE,
+                         ids=[t[0] for t in SORT_TREE])
+def test_sort_query_span(sort_spans, name, kind, parent):
+    spans, _out = sort_spans
+    s = _one(spans, name)
+    assert s["kind"] == kind
+    assert _parent_name(spans, s) == parent
+
+
+@pytest.mark.parametrize("name,kind,parent", SQL_TREE,
+                         ids=[t[0] for t in SQL_TREE])
+def test_sql_query_span(sql_spans, name, kind, parent):
+    s = _one(sql_spans, name)
+    assert s["kind"] == kind
+    assert _parent_name(sql_spans, s) == parent
+
+
+@pytest.mark.parametrize("which", ["sort", "sql"])
+def test_stage_span_names_its_program(sort_spans, sql_spans, which):
+    spans = sort_spans[0] if which == "sort" else sql_spans
+    stages = [s for s in spans if s["kind"] == "stage"]
+    assert stages
+    for s in stages:
+        assert _parent_name(spans, s) == "run"
+        assert re.fullmatch(r"jit_stage_[a-z0-9_]+", s["attrs"]["program"])
+        assert s["attrs"]["cache_hit"] is False
+        (c,) = [x for x in spans if x.get("parent") == s["span"]]
+        assert (c["name"], c["kind"]) == ("stage.compile", "compile")
+        assert c["attrs"]["compile_s"] > 0
+
+
+@pytest.mark.parametrize("which,roots", [
+    ("sort", ["from_store", "to_store"]),
+    ("sql", ["sql.query", "collect"])])
+def test_one_trace_id_per_terminal_call(sort_spans, sql_spans, which, roots):
+    spans = sort_spans[0] if which == "sort" else sql_spans
+    assert [s["name"] for s in spans if not s.get("parent")] == roots
+    by_id = {s["span"]: s for s in spans}
+    traces = {}
+    for s in spans:
+        root = s
+        while root.get("parent"):
+            root = by_id[root["parent"]]
+        traces.setdefault(root["name"], set()).add(s["trace"])
+    assert all(len(ids) == 1 for ids in traces.values()), traces
+    assert len({next(iter(ids)) for ids in traces.values()}) == len(roots)
+
+
+def test_bytes_are_the_bytes_on_disk(sort_spans):
+    spans, out = sort_spans
+    on_disk = sum(os.path.getsize(os.path.join(out, f))
+                  for f in os.listdir(out) if f.startswith("part-"))
+    assert _one(spans, "store.file_write")["attrs"]["bytes"] == on_disk
+    assert _one(spans, "store.write")["attrs"]["bytes"] == on_disk
+    assert _one(spans, "store.checksum")["attrs"]["bytes"] == on_disk
+    assert _one(spans, "store.read")["attrs"]["bytes"] == on_disk
+    assert _one(spans, "to_store")["attrs"]["rows"] == N
+    assert _one(spans, "to_store")["attrs"]["sink"] == out
+
+
+def test_settle_is_inside_run_and_span_times_nest(sort_spans):
+    spans, _out = sort_spans
+    by_id = {s["span"]: s for s in spans}
+    for s in spans:
+        assert s["t0"] == round(s["t0"], 6)
+        if s.get("parent"):
+            p = by_id[s["parent"]]
+            assert p["t0"] - 1e-3 <= s["t0"]
+            assert s["t0"] + s["dur_s"] <= p["t0"] + p["dur_s"] + 1e-3
+
+
+class _Counting(trace.Span):
+    built = 0
+
+    def __init__(self, *a, **kw):
+        _Counting.built += 1
+        super().__init__(*a, **kw)
+
+
+@pytest.mark.parametrize("how", ["level_1", "no_event_log"])
+def test_spans_off_build_nothing(tmp_path, monkeypatch, how):
+    src = _input_store(tmp_path, 1)
+    monkeypatch.setattr(trace, "Span", _Counting)
+    monkeypatch.setattr(_Counting, "built", 0)
+    events = []
+    if how == "level_1":
+        monkeypatch.setenv("DRYAD_LOGGING_LEVEL", "1")
+        ctx = _ctx(1, events)
+    else:
+        ctx = _ctx(1, None)
+    _sort_query(ctx, src, str(tmp_path / "out"))
+    _sql_query(ctx, src)
+    assert _spans(events) == []
+    assert _Counting.built == 0
+    if how == "level_1":        # the other events still flow
+        assert any(e.get("event") == "stage_done" for e in events)
+
+
+def _program_names(ndev, build):
+    ctx = _ctx(ndev, None)
+    ds = build(ctx.from_columns(_columns()))
+    graph = plan_query(ds.node, ctx.nparts, hosts=ctx.hosts,
+                       levels=ctx.levels, config=ctx.config)
+    return [stage_program_name(st) for st in graph.stages]
+
+
+def _sorted(ds):
+    return ds.order_by([("k", False)])
+
+
+def _grouped(ds):
+    return ds.group_by(["g"], {"s": ("sum", "v")})
+
+
+@pytest.mark.parametrize("ndev", [1, 4])
+@pytest.mark.parametrize("build", [_sorted, _grouped],
+                         ids=["sort", "group_by"])
+def test_program_name_is_a_function_of_the_plan(ndev, build):
+    a, b = _program_names(ndev, build), _program_names(ndev, build)
+    assert a == b
+    for name in a:
+        assert re.fullmatch(r"[a-z0-9_]{1,64}", "jit_" + name)
+        assert name.startswith("stage_")
+
+
+@pytest.mark.parametrize("ndev", [1, 4])
+def test_program_name_tells_a_sort_from_a_group_by(ndev):
+    assert set(_program_names(ndev, _sorted)).isdisjoint(
+        _program_names(ndev, _grouped))
+
+
+def test_long_labels_are_cut_to_64():
+    class _Op:
+        kind = "Some-Very Long.Kind"
+
+    class _Stage:
+        label = "x" * 100
+        legs = ()
+        body = (_Op(), _Op())
+    name = "jit_" + stage_program_name(_Stage())
+    assert re.fullmatch(r"[a-z0-9_]{1,64}", name)
+
+
+def test_compiled_module_carries_the_name():
+    """What the profiler's ``XLA Modules`` line will say."""
+    ctx = _ctx(1, None)
+    ds = _sorted(ctx.from_columns(_columns()))
+    graph = plan_query(ds.node, ctx.nparts, hosts=ctx.hosts,
+                       levels=ctx.levels, config=ctx.config)
+    (stage,) = graph.stages
+    (source,) = [n for n in E.walk(ds.node) if isinstance(n, E.Source)]
+    fn = ctx.executor._build_stage_fn(stage, 1, 1, 1, False)
+    text = fn.lower(source.data.batch).as_text()
+    assert "jit_" + stage_program_name(stage) in text
+    assert "jit_per_shard" not in text
+
+
+def test_one_store_fetch_a_partition_on_four_devices(tmp_path):
+    src = _input_store(tmp_path, 4)
+    events = []
+    _sort_query(_ctx(4, events), src, str(tmp_path / "out"))
+    spans = _spans(events)
+    fetches = [s for s in spans if s["name"] == "store.fetch"]
+    assert sorted(s["attrs"]["partition"] for s in fetches) == [0, 1, 2, 3]
+    write = _one(spans, "store.write")
+    assert {s["parent"] for s in fetches} == {write["span"]}
+    assert write["attrs"]["partitions"] == 4
+    assert sum(s["attrs"]["bytes"] for s in fetches) \
+        == write["attrs"]["bytes"]
+    assert _one(spans, "store.file_write")["attrs"]["files"] == 4
+    # two planned stages, and a span a stage attempt, each with its name
+    programs = {s["attrs"]["program"] for s in spans
+                if s["kind"] == "stage"}
+    assert "jit_stage_orderby_range_sort" in programs
