@@ -11,18 +11,22 @@ anchor for fault tolerance), where the reference materializes temp files
 from __future__ import annotations
 
 import dataclasses
-from functools import partial
-from typing import Any, Dict, Mapping, Optional
+from functools import lru_cache, partial
+from typing import (Any, Dict, Iterator, List, Mapping, Optional, Sequence,
+                    Tuple)
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import lax
+from jax.sharding import NamedSharding, PartitionSpec
 
 from dryad_tpu.data.columnar import Batch, StringColumn
 from dryad_tpu.parallel.mesh import batch_sharding
 
 __all__ = ["PData", "pdata_from_host", "pdata_to_host", "put_batch",
-           "replicate_tree", "collect_replicated", "batch_nbytes"]
+           "fetch_partitions", "replicate_tree", "collect_replicated",
+           "batch_nbytes"]
 
 
 def batch_nbytes(tree) -> int:
@@ -54,6 +58,147 @@ def put_batch(tree, mesh):
                                             lambda idx: x[idx])
 
     return jax.tree.map(put, tree)
+
+
+# -- device -> host: a result that is about to be stored ----------------------
+
+# Bytes of one chunk of one partition (its rows: the largest power of two
+# that stays under this, and never more than the capacity).  Large enough
+# that a copy runs at the link's rate, small enough that what is moved past
+# a partition's count stays a few per cent of it.
+_FETCH_CHUNK_BYTES = 8 << 20
+
+# A chunk whose rows are narrower than this many elements leaves the device
+# as rows of this many.  The TPU holds [rows, 10] or [rows, 90] bytes
+# column-major (the long dimension minor), and the host array of such a
+# copy keeps that order: making it contiguous is a transpose on one host
+# thread, 3.7-3.9 s for 755 MB, where the same bytes as wide rows arrive
+# contiguous at the link's rate (PERF.md section 6, PR 29).
+_FETCH_LINK_WIDTH = 512
+
+
+def _fetch_chunk_rows(cap: int, row_bytes: int) -> int:
+    rows = max(1, _FETCH_CHUNK_BYTES // max(row_bytes, 1))
+    return min(1 << (rows.bit_length() - 1), max(cap, 1))
+
+
+def _row_elems(x) -> int:
+    return int(np.prod(x.shape[2:], dtype=np.int64))
+
+
+def _leaves_wide(x) -> bool:
+    """Whether a chunk of the stacked ``[P, cap, ...]`` array ``x`` is
+    reshaped to wide rows before it is copied: read from the column's
+    shape alone (it has trailing dimensions, and they are narrow)."""
+    return x.ndim > 2 and 0 < _row_elems(x) < _FETCH_LINK_WIDTH
+
+
+@lru_cache(maxsize=None)
+def _fetch_chunk_program(rows: int, wide: bool, out_sharding):
+    """``x[:, start:start + rows]`` of a stacked ``[P, cap, ...]`` array,
+    every shard on its own device; ``start`` is traced, so one program
+    serves every chunk of every count.  ``wide``: each partition's chunk
+    flattened, padded to whole rows of ``_FETCH_LINK_WIDTH`` elements."""
+    def fetch_chunk(x, start):
+        c = lax.dynamic_slice_in_dim(x, start, rows, axis=1)
+        if wide:
+            c = c.reshape(c.shape[0], -1)
+            c = jnp.pad(c, ((0, 0), (0, -c.shape[1] % _FETCH_LINK_WIDTH)))
+            c = c.reshape(c.shape[0], -1, _FETCH_LINK_WIDTH)
+        return c
+    return jax.jit(fetch_chunk, out_shardings=out_sharding)
+
+
+def _partition_shards(x) -> Dict[Tuple[int, int], jax.Array]:
+    """{(first partition, one past the last): the addressable shard's data}
+    of an array whose leading dimension is the partition."""
+    out: Dict[Tuple[int, int], jax.Array] = {}
+    for s in x.addressable_shards:
+        lo, hi, _ = s.index[0].indices(x.shape[0])
+        out.setdefault((lo, hi), s.data)
+    return out
+
+
+def _partition_sharding(x):
+    """The sharding of ``x``'s partition dimension alone (what a chunk of
+    it keeps), or None to leave a single device's output where it is."""
+    sh = x.sharding
+    if isinstance(sh, NamedSharding):
+        return NamedSharding(sh.mesh, PartitionSpec(*sh.spec[:1]))
+    return None
+
+
+def fetch_partitions(leaves: Sequence[Any], counts: np.ndarray
+                     ) -> Iterator[Tuple[List[np.ndarray], int, int]]:
+    """Bring the valid rows of stacked ``[P, cap, ...]`` arrays to the
+    host: for partition 0, 1, ... in turn ``(pieces, moved_bytes,
+    chunks)``, where ``pieces`` are contiguous host arrays that, end to
+    end, are ``leaves[0][p, :counts[p]]``, then ``leaves[1][p, :counts[p]]``
+    and so on — each leaf as the chunks it arrived in, not copied again.
+
+    How a result leaves the device.  Partition p is the shard on device
+    p, and a shard is cut into chunks of a fixed number of rows by ONE
+    program a column shape (a ``dynamic_slice`` with a traced start —
+    nothing that is compiled depends on a count).  Only the chunks that
+    hold valid rows are moved, so at most one chunk a leaf a partition
+    crosses the link in vain.  A chunk of narrow rows (a string column's
+    bytes) is reshaped on the device to wide rows first
+    (``_FETCH_LINK_WIDTH``), so that it arrives contiguous.  Every chunk
+    of every leaf of every partition is cut and its copy started
+    (``copy_to_host_async``) before the first is awaited: the links of
+    all devices work at once, and partition p is handed on while p + 1
+    ... are still on their way.  Until its host copy has been taken a
+    chunk stays in HBM, at most one more copy of the result.  Leaves
+    already on the host are sliced in place; a partition no shard of this
+    process holds is an error."""
+    counts = np.asarray(counts)
+    plans = []          # per leaf: None (host) or (rows, {(lo, hi): [chunk]})
+    for x in leaves:
+        if not isinstance(x, jax.Array):
+            plans.append(None)
+            continue
+        cap = x.shape[1]
+        rows = _fetch_chunk_rows(cap, x.dtype.itemsize * _row_elems(x))
+        program = _fetch_chunk_program(rows, _leaves_wide(x),
+                                       _partition_sharding(x))
+        spans = list(_partition_shards(x))
+        need = {sp: -(-int(counts[sp[0]:sp[1]].max(initial=0)) // rows)
+                for sp in spans}
+        chunks: Dict[Tuple[int, int], list] = {sp: [] for sp in spans}
+        for i in range(max(need.values(), default=0)):
+            out = _partition_shards(program(x, min(i * rows, cap - rows)))
+            for sp in spans:
+                if i < need[sp]:
+                    out[sp].copy_to_host_async()
+                    chunks[sp].append(out[sp])
+        plans.append((rows, chunks))
+
+    for p, n in enumerate(counts.tolist()):
+        pieces: List[np.ndarray] = []
+        moved = nchunks = 0
+        for x, plan in zip(leaves, plans):
+            if plan is None:
+                pieces.append(np.ascontiguousarray(np.asarray(x)[p, :n]))
+                continue
+            rows, chunks = plan
+            sp = next((sp for sp in chunks if sp[0] <= p < sp[1]), None)
+            if sp is None:
+                raise ValueError(f"partition {p} is on no device of this "
+                                 "process: it cannot be fetched here")
+            if p == sp[0]:              # a shard's chunks count once
+                moved += sum(c.nbytes for c in chunks[sp])
+                nchunks += len(chunks[sp])
+            cap, row = x.shape[1], x.shape[2:]
+            for i in range(-(-n // rows)):
+                start = min(i * rows, cap - rows)
+                # the host's copy stays, the device's goes
+                host = chunks[sp][i] = np.asarray(chunks[sp][i])
+                host = host[p - sp[0]].reshape(-1)[:rows * _row_elems(x)]
+                pieces.append(host.reshape((rows,) + row)
+                              [i * rows - start:n - start])
+            if n == 0:                  # a leaf is never no piece at all
+                pieces.append(np.empty((0,) + row, x.dtype))
+        yield pieces, moved, nchunks
 
 
 def replicate_tree(tree, mesh):

@@ -68,7 +68,7 @@ def s3_write_store(url: str, pd, partitioning=None, compression=None,
                    client: Optional[S3Client] = None) -> None:
     """write_store for s3:// paths (same segments, checksums, meta)."""
     from dryad_tpu import native
-    from dryad_tpu.io.store import (_part_segments_for_write, build_meta,
+    from dryad_tpu.io.store import (build_meta, fetch_part_segments,
                                     pdata_schema, segments_blob)
 
     if compression not in (None, "gzip"):
@@ -80,9 +80,8 @@ def s3_write_store(url: str, pd, partitioning=None, compression=None,
     import uuid
     gen = uuid.uuid4().hex[:12]
     checksums: List[str] = []
-    for p in range(pd.nparts):
-        segs = _part_segments_for_write(pd.batch, schema, p,
-                                        int(counts[p]))
+    for p, (segs, _, _) in enumerate(
+            fetch_part_segments(pd, schema, counts)):
         checksums.append("%016x" % native.checksum_segments(segs))
         c.put_object(bucket, _part_key(prefix, p, gen),
                      segments_blob(segs, compression))

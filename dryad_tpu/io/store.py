@@ -14,6 +14,14 @@ Partition files are written/read by the native parallel scatter-gather IO
 engine (native/dryad_io.cpp via dryad_tpu.native) — partitions move in
 parallel on a worker pool, the role of the reference's per-channel async
 buffer queues (channelbufferqueue.cpp) — with a pure-Python fallback.
+
+A PData that is about to be stored leaves the device through
+``exec.data.fetch_partitions`` (whole shards, fixed chunks of the valid
+rows only, every copy started before any is awaited, narrow rows reshaped
+to wide ones on the device so that they arrive contiguous).  A partition's
+segments are then each leaf's chunks as they arrived, in file order: a
+column is several consecutive segments, never one assembled copy — file
+bytes and the chained fnv64 are the same either way.
 """
 
 from __future__ import annotations
@@ -26,7 +34,8 @@ import numpy as np
 
 from dryad_tpu import native
 from dryad_tpu.data.columnar import Batch, StringColumn
-from dryad_tpu.exec.data import PData, batch_nbytes, put_batch
+from dryad_tpu.exec.data import (PData, batch_nbytes, fetch_partitions,
+                                 put_batch)
 from dryad_tpu.obs import trace
 
 __all__ = ["write_store", "read_store", "store_meta", "build_meta",
@@ -153,8 +162,7 @@ def pdata_schema(pd: "PData") -> Dict[str, Any]:
         if isinstance(v, StringColumn):
             schema[k] = {"kind": "str", "max_len": int(v.data.shape[2])}
         else:
-            arr_dtype = np.dtype(str(np.asarray(v[0, :1]).dtype))
-            schema[k] = {"kind": "dense", "dtype": arr_dtype.name,
+            schema[k] = {"kind": "dense", "dtype": np.dtype(v.dtype).name,
                          "shape": list(v.shape[2:])}
     return schema
 
@@ -208,18 +216,19 @@ def _segments_nbytes(segments) -> int:
     return sum(s.nbytes for segs in segments for s in segs)
 
 
-def _part_segments_for_write(batch: Batch, schema, p: int, n: int
-                             ) -> List[np.ndarray]:
-    """Column blobs of partition p, valid rows only, in sorted-column order."""
-    segs: List[np.ndarray] = []
+def fetch_part_segments(pd: PData, schema, counts: np.ndarray):
+    """For partition 0, 1, ... in turn ``(segments, moved_bytes, chunks)``:
+    the blobs of the partition's ``counts[p]`` valid rows in sorted-column
+    order (strings: data then lengths; a column is one segment a chunk it
+    arrived in), brought off the device by ``exec.data.fetch_partitions``
+    — the ONE fetch of every store writer (local, append, s3://, hdfs://,
+    the stage spill)."""
+    leaves: List[Any] = []
     for k in _col_order(schema):
-        v = batch.columns[k]
-        if isinstance(v, StringColumn):
-            segs.append(np.ascontiguousarray(np.asarray(v.data[p])[:n]))
-            segs.append(np.ascontiguousarray(np.asarray(v.lengths[p])[:n]))
-        else:
-            segs.append(np.ascontiguousarray(np.asarray(v[p])[:n]))
-    return segs
+        v = pd.batch.columns[k]
+        leaves.extend([v.data, v.lengths] if isinstance(v, StringColumn)
+                      else [v])
+    return fetch_partitions(leaves, counts)
 
 
 def write_store(path: str, pd: PData,
@@ -251,13 +260,17 @@ def write_store(path: str, pd: PData,
         counts = np.asarray(pd.counts)
         schema = pdata_schema(pd)
         paths, segments = [], []
+        fetched = fetch_part_segments(pd, schema, counts)
         for p in range(pd.nparts):
             paths.append(_part_path(tmp, p))
-            # device -> host and the contiguous copy, a partition at a time
+            # device -> host: partition 0's span starts the copy of every
+            # chunk of every partition, then each span waits for what its
+            # partition still misses (exec.data.fetch_partitions)
             with trace.span("store.fetch", "io", partition=p) as fsp:
-                segments.append(_part_segments_for_write(
-                    pd.batch, schema, p, int(counts[p])))
-                fsp.set(bytes=_segments_nbytes(segments[-1:]))
+                segs, moved, chunks = next(fetched)
+                segments.append(segs)
+                fsp.set(bytes=_segments_nbytes(segments[-1:]),
+                        moved_bytes=moved, chunks=chunks)
         nbytes = _segments_nbytes(segments)
         sp.set(bytes=nbytes)
         with trace.span("store.file_write", "io", bytes=nbytes,
@@ -312,12 +325,12 @@ def append_store(path: str, pd: PData) -> int:
     counts = np.asarray(pd.counts)
     base = int(meta["npartitions"])
     paths, segments, new_counts = [], [], []
-    for p in range(pd.nparts):
-        n = int(counts[p])
+    for n, (segs, _, _) in zip(counts.tolist(),
+                               fetch_part_segments(pd, schema, counts)):
         if n == 0:  # empty shards would bloat the manifest forever
             continue
         paths.append(_part_path(path, base + len(new_counts)))
-        segments.append(_part_segments_for_write(pd.batch, schema, p, n))
+        segments.append(segs)
         new_counts.append(n)
     if not new_counts:
         return store_generation(meta)

@@ -333,14 +333,13 @@ def hdfs_store_meta(url: str, client: Optional[WebHdfsClient] = None
     return json.loads(c.read_all(path.rstrip("/") + "/meta.json"))
 
 
-def part_blob(pd_batch, schema, p: int, n: int,
+def part_blob(segs: List[np.ndarray],
               compression: Optional[str]) -> Tuple[bytes, int]:
     """(serialized partition blob, fnv64 checksum of the UNCOMPRESSED
     segments) — the store read contract (io/store.verify_checksums)."""
     from dryad_tpu import native
-    from dryad_tpu.io.store import _part_segments_for_write, segments_blob
+    from dryad_tpu.io.store import segments_blob
 
-    segs = _part_segments_for_write(pd_batch, schema, p, n)
     return segments_blob(segs, compression), native.checksum_segments(segs)
 
 
@@ -352,7 +351,8 @@ def hdfs_write_store(url: str, pd, partitioning=None, compression=None,
     a reader never observes a half-written store."""
     import uuid
 
-    from dryad_tpu.io.store import build_meta, pdata_schema
+    from dryad_tpu.io.store import (build_meta, fetch_part_segments,
+                                    pdata_schema)
 
     if compression not in (None, "gzip"):
         raise ValueError(f"unknown compression {compression!r}")
@@ -363,9 +363,9 @@ def hdfs_write_store(url: str, pd, partitioning=None, compression=None,
     tmp = path + ".tmp-" + uuid.uuid4().hex[:12]
     c.mkdirs(tmp)
     checksums: List[str] = []
-    for p in range(pd.nparts):
-        blob, checksum = part_blob(pd.batch, schema, p, int(counts[p]),
-                                   compression)
+    for p, (segs, _, _) in enumerate(
+            fetch_part_segments(pd, schema, counts)):
+        blob, checksum = part_blob(segs, compression)
         checksums.append("%016x" % checksum)
         c.create(hdfs_part_path(tmp, p), blob)
     meta = build_meta(schema, counts.tolist(), checksums,
@@ -501,7 +501,7 @@ def hdfs_part_chunks(url: str, meta: Dict[str, Any], p: int,
     cnt = int(meta["counts"][p])
     part = hdfs_part_path(path, p)
     # segment layout in file order: sorted columns, strings as
-    # (data, lengths) — must match io/store._part_segments_for_write
+    # (data, lengths) — must match io/store.fetch_part_segments
     layout: List[Tuple[str, Optional[int], Any, Tuple[int, ...], int, int]] \
         = []   # (col, str_part, dtype, row_shape, row_bytes, base_off)
     base = 0
