@@ -145,18 +145,6 @@ def conjuncts_of(prog: Optional[List]) -> List[List]:
 # -- bound SQL plan canonicalization ------------------------------------
 
 
-def _rename_cols(prog: List, phys_map: Dict[str, str]) -> List:
-    head = prog[0]
-    if head == "col":
-        return ["col", phys_map.get(prog[1], prog[1])]
-    if head in ("lit", "const"):
-        return list(prog)
-    if head in ("not", "neg"):
-        return [head, _rename_cols(prog[1], phys_map)]
-    return ["bin", prog[1], _rename_cols(prog[2], phys_map),
-            _rename_cols(prog[3], phys_map)]
-
-
 def canonical_select(catalog, bound) -> Dict[str, Any]:
     """Canonical JSON-able form of a ``BoundSelect``; see module
     docstring for the rewrite set.  ``catalog`` supplies per-table
@@ -164,7 +152,7 @@ def canonical_select(catalog, bound) -> Dict[str, Any]:
     form identifies the *data* too — equal canonical forms compute
     the same result, not just the same function."""
     from dryad_tpu.sql.catalog import table_fingerprint
-    from dryad_tpu.sql.rowexpr import prog_columns
+    from dryad_tpu.sql.rowexpr import prog_columns, rename_prog
 
     # alias-insensitive renaming: positional canonical aliases in FROM
     # order (join order is semantically significant — it is preserved)
@@ -184,7 +172,7 @@ def canonical_select(catalog, bound) -> Dict[str, Any]:
 
     def cp(prog: Optional[List]) -> Optional[List]:
         return None if prog is None \
-            else canon_prog(_rename_cols(prog, phys_map))
+            else canon_prog(rename_prog(prog, phys_map))
 
     # referenced physical columns — dead-column pruning of scan renames
     referenced: set = set()
@@ -277,7 +265,7 @@ def scan_prefix(catalog, bound) -> Optional[Dict[str, Any]]:
     (source column names the query reads), ``filter`` (canonical
     conjunct list over SOURCE column names; empty = always-true)."""
     from dryad_tpu.sql.catalog import table_fingerprint
-    from dryad_tpu.sql.rowexpr import prog_columns
+    from dryad_tpu.sql.rowexpr import prog_columns, rename_prog
     if bound.joins:
         return None
     src_map = {phys: col for phys, col in bound.base_renames.items()}
@@ -293,7 +281,7 @@ def scan_prefix(catalog, bound) -> Optional[Dict[str, Any]]:
             referenced |= prog_columns(prog)
     t = catalog.get(bound.base_table)
     filt = [] if bound.where is None else conjuncts_of(
-        _rename_cols(bound.where, src_map))
+        rename_prog(bound.where, src_map))
     return {"table": bound.base_table,
             "content": table_fingerprint(t) if t is not None else "?",
             "columns": sorted(src_map[p] for p in referenced

@@ -482,6 +482,9 @@ class Executor:
         from collections import OrderedDict
         self._compile_cache: "OrderedDict[Any, Callable]" = OrderedDict()
         self._compile_cache_max = self.config.compile_cache_size
+        # what tracing a cached join stage's program told of its shapes
+        # (_build_stage_fn's ``facts``), under the program's cache key
+        self._stage_facts: Dict[Any, Dict[str, int]] = {}
         # measured slot-probe RESULTS keyed by (keys, slack, schema, the
         # input's device buffer identities): an iterative job re-running
         # the same stage over the SAME buffers (do_while loop state that
@@ -521,14 +524,23 @@ class Executor:
         enable_persistent_cache(self.config.compilation_cache_dir)
         self._compile_cache_max = self.config.compile_cache_size
         while len(self._compile_cache) > self._compile_cache_max:
-            self._compile_cache.popitem(last=False)
+            self._evict_oldest_program()
+
+    def _evict_oldest_program(self) -> None:
+        key, _fn = self._compile_cache.popitem(last=False)
+        self._stage_facts.pop(key, None)
 
     # -- stage program construction ---------------------------------------
 
     def _build_stage_fn(self, stage: Stage, scale: int, slack: int,
                         n_legs: int, has_bounds: bool,
                         salted: bool = False,
-                        slot_hints: tuple = ()):
+                        slot_hints: tuple = (),
+                        facts: Optional[Dict[str, int]] = None):
+        """``facts`` (optional) is filled while the program is traced
+        with what only its shapes tell: ``join_in_bytes``, the bytes of
+        a join's two inputs as the program holds them (every leg's ops
+        and exchange applied), capacity x row bytes over all shards."""
         def per_shard(*args):
             leg_batches = [
                 _squeeze(b) for b in args[:n_legs]]
@@ -591,6 +603,11 @@ class Executor:
             for op in _fuse_stage_ops(stage.body):
                 if op.kind in ("join", "semi_anti", "concat", "apply2",
                                "zip"):
+                    if op.kind == "join" and facts is not None:
+                        facts["join_in_bytes"] = self.nparts * sum(
+                            x.size * x.dtype.itemsize
+                            for b in (cur, rest[0])
+                            for x in jax.tree.leaves(b.columns))
                     cur, nd = _apply_op(cur, op, scale, rest,
                                         self.axes, slack)
                     rest = []
@@ -924,6 +941,7 @@ class Executor:
         slack = stage._send_slack or self.config.initial_send_slack
         salted = stage._salted
         max_retries = self.config.max_capacity_retries
+        join = next((op for op in stage.body if op.kind == "join"), None)
         for attempt in range(max_retries + 1):
             # salt knobs are baked into compiled salted programs — they
             # must key the cache or a re-configured job reuses stale code
@@ -943,6 +961,7 @@ class Executor:
                     self._compile_cache.move_to_end(key)
             compile_s = 0.0
             cache_hit = fn is not None
+            facts = self._stage_facts.get(key, {})
             if fn is None:
                 _M_CACHE_MISSES.inc()
                 # AOT compile so the event stream separates compile time
@@ -954,15 +973,18 @@ class Executor:
                                               len(inputs),
                                               bounds is not None,
                                               salted=salted,
-                                              slot_hints=slot_hints
+                                              slot_hints=slot_hints,
+                                              facts=facts
                                               ).lower(*args).compile()
                     compile_s = time.time() - t0
                     csp.set(compile_s=round(compile_s, 4))
                 _M_COMPILE_S.inc(compile_s)
                 with self._cache_lock:
                     self._compile_cache[key] = fn
+                    if facts:
+                        self._stage_facts[key] = facts
                     if len(self._compile_cache) > self._compile_cache_max:
-                        self._compile_cache.popitem(last=False)
+                        self._evict_oldest_program()
             else:
                 _M_CACHE_HITS.inc()
             if job is not None:
@@ -971,9 +993,16 @@ class Executor:
                 # signal (labels ride the same canonical families)
                 _family(_METRICS, "cache_hits" if cache_hit
                         else "cache_misses", job=job).inc()
+            # a join stage says what it was given and what it may return
+            # (static: shapes and plan, no device sync)
+            join_attrs = {} if join is None else {
+                "out_capacity": join.params["out_capacity"] * scale,
+                "right_unique": bool(join.params.get("right_unique",
+                                                     False)),
+                **facts}
             if span is not trace.NULL:
                 span.set(program="jit_" + stage_program_name(stage),
-                         cache_hit=cache_hit)
+                         cache_hit=cache_hit, **join_attrs)
             t0 = time.time()
             out_batch, info = fn(*args)
             if defer is not None and attempt == 0:
@@ -1003,7 +1032,8 @@ class Executor:
                               "salted": salted, "cache_hit": cache_hit,
                               "compile_s": round(compile_s, 4),
                               "out_bytes": out_bytes,
-                              "enqueue_s": enqueue_s})
+                              "enqueue_s": enqueue_s,
+                              "join": join_attrs})
                 stage._capacity_scale = scale
                 stage._send_slack = slack
                 stage._salted = salted
@@ -1040,7 +1070,7 @@ class Executor:
                 "compile_s": round(compile_s, 4),
                 "cache_hit": cache_hit,
                 "dispatches": 2,   # program launch + info fetch
-                "wall_s": round(wall, 4)})
+                "wall_s": round(wall, 4), **join_attrs})
             decision = self._decide_needs(stage, scale, slack, salted,
                                           need_scale, need_slack,
                                           need_exch)

@@ -283,7 +283,7 @@ class Run:
                 "out_bytes": rec.get("out_bytes", 0),
                 "deferred": True,
                 "dispatches": 1,   # program launch only; fetch amortized
-                "wall_s": rec["enqueue_s"]})
+                "wall_s": rec["enqueue_s"], **rec.get("join", {})})
             if not of:
                 # settled clean at the planned shapes: cross-check the
                 # measured rows/bytes against the static cost prediction

@@ -31,7 +31,7 @@ from __future__ import annotations
 from typing import Tuple
 
 from dryad_tpu.obs import trace
-from dryad_tpu.sql.binder import BoundSelect, bind
+from dryad_tpu.sql.binder import BoundSelect, bind, conjuncts
 from dryad_tpu.sql.catalog import (Catalog, CatalogTable, SchemaContext,
                                    SchemaOnlyTableError)
 from dryad_tpu.sql.errors import SqlError
@@ -61,8 +61,13 @@ def compile_query(catalog: Catalog, text: str,
     :class:`SqlError` with all DTA3xx findings."""
     with trace.span("sql.parse", "front"):
         mode, stmt = parse_statement(text, origin=origin)
-    with trace.span("sql.bind", "front"):
-        return mode, bind(catalog, stmt)
+    with trace.span("sql.bind", "front") as sp:
+        bound = bind(catalog, stmt)
+        sp.set(joins=len(bound.joins),
+               pushed_conjuncts=sum(len(conjuncts(p)) for p in
+                                    bound.scan_filters.values()),
+               residual_conjuncts=len(conjuncts(bound.residual)))
+        return mode, bound
 
 
 def query(ctx, catalog: Catalog, text: str, origin: str = "<sql>",
@@ -82,8 +87,8 @@ def _lowered(ctx, catalog: Catalog, text: str, origin: str = "<sql>",
             raise ValueError(
                 "EXPLAIN statements build no dataset — use sql.explain()")
         # the catalog's from_store (an eager store.read) nests in here
-        with trace.span("sql.lower", "front"):
-            ds, handles = lower(ctx, catalog, bound)
+        with trace.span("sql.lower", "front") as sp:
+            ds, handles = lower(ctx, catalog, bound, span=sp)
         _emit(ctx, event, text, catalog, bound)
     return ds, handles
 

@@ -10,7 +10,14 @@ query text):
   column / duplicate alias / duplicate output name,
 * DTA305 type mismatches (including aggregate-shape errors: a
   non-grouped column in an aggregated SELECT),
-* DTA306 recognized-but-unsupported constructs.
+* DTA306 recognized-but-unsupported constructs (a FROM-list table that
+  no equality joins among them: never a cross product).
+
+WHERE is split into its top-level ``AND`` conjuncts, and each is placed
+where it costs least (:meth:`_Binder._place_conjuncts`): a cross-table
+column equality becomes a join key (that is what joins a FROM list), a
+conjunct over one table becomes that table's scan filter, the rest stay
+the residual above the joins.
 
 Internally every column gets a unique physical name ``alias.col`` the
 moment its table enters scope, so downstream joins can never collide
@@ -27,8 +34,9 @@ from dryad_tpu.analysis.diagnostics import DiagnosticReport, Span
 from dryad_tpu.sql import nodes as N
 from dryad_tpu.sql.catalog import Catalog, sql_type_of
 from dryad_tpu.sql.errors import SqlError
+from dryad_tpu.sql.rowexpr import prog_columns
 
-__all__ = ["BoundSelect", "BoundJoin", "bind"]
+__all__ = ["BoundSelect", "BoundJoin", "bind", "conjuncts"]
 
 Prog = list  # rowexpr program node
 
@@ -42,6 +50,11 @@ class BoundJoin:
     right_keys: List[str]            # physical names in the new table
     renames: Dict[str, str]          # phys -> source column
     span: Optional[Span] = None
+    # the stage's sides, from the catalog's row counts: ``swap`` puts
+    # this table on the probe (left) side and what is joined so far on
+    # the build side — the larger input probes, so a key/foreign-key
+    # join fits the stage's first out_capacity
+    swap: bool = False
 
 
 @dataclasses.dataclass
@@ -52,6 +65,9 @@ class BoundSelect:
     base_alias: str
     base_renames: Dict[str, str]          # phys -> source column
     joins: List[BoundJoin]
+    # WHERE less the equalities that became join keys: what the
+    # statement filters by (canon, subsume, inc read it).  lower() reads
+    # its split instead: ``scan_filters`` and ``residual`` below
     where: Optional[Prog]
     # aggregation (empty group_keys + aggs means a GLOBAL aggregate)
     grouped: bool
@@ -77,6 +93,31 @@ class BoundSelect:
     # service's standing-query scheduler and the inc/ refresh planner
     emit_every: Optional[float] = None
     emit_span: Optional[Span] = None
+    # ``where`` split: alias -> the conjuncts over that table alone
+    # (lowered on its scan, below its join), and what is left above
+    # the joins.  AND of all of them == ``where``
+    scan_filters: Dict[str, Prog] = dataclasses.field(default_factory=dict)
+    residual: Optional[Prog] = None
+
+
+def conjuncts(prog: Optional[Prog]) -> List[Prog]:
+    """The top-level AND conjuncts of a bound predicate (none of None)."""
+    if prog is None:
+        return []
+    if prog[0] == "bin" and prog[1] == "and":
+        return conjuncts(prog[2]) + conjuncts(prog[3])
+    return [prog]
+
+
+def _and(progs: List[Prog]) -> Optional[Prog]:
+    out = None
+    for p in progs:
+        out = p if out is None else ["bin", "and", out, p]
+    return out
+
+
+def _alias(phys: str) -> str:
+    return phys.split(".", 1)[0]
 
 
 class _Scope:
@@ -207,6 +248,109 @@ class _Binder:
             rks.append(sides[r_i][1])
         return lks, rks
 
+    # -- WHERE's conjuncts ---------------------------------------------------
+
+    def _place_conjuncts(self, where: Optional[Prog],
+                         joins: List[BoundJoin], scope: _Scope):
+        """Split ``where`` into its top-level AND conjuncts and place
+        each: (a) ``x = y`` over columns of two tables becomes a key of
+        the later table's join (inner joins only; a FROM list's joins
+        are ordered here, each step taking the next listed table an
+        equality connects to what is joined so far); (b) a conjunct
+        over one table becomes that table's scan filter unless an outer
+        join supplies the table's nulls; (c) the rest is the residual.
+        Returns (where less the join keys, {alias: filter}, residual);
+        ``joins`` is reordered and given its keys in place."""
+        stmt = self.stmt
+        base = stmt.table.alias
+        conj = conjuncts(where)
+        types = {phys: typ for _, _, phys, typ in scope.all_columns()}
+        all_inner = all(j.how == "inner" for j in joins)
+
+        def cross_equality(c):
+            if not (all_inner and c[0] == "bin" and c[1] == "="
+                    and c[2][0] == "col" and c[3][0] == "col"):
+                return None
+            a, b = c[2][1], c[3][1]
+            if _alias(a) == _alias(b) or types[a] != types[b]:
+                return None
+            return a, b
+
+        eq_of = [cross_equality(c) for c in conj]
+        equalities = [e for e in eq_of if e]
+        if stmt.from_list:
+            self.fail_if_dirty()    # a WHERE that did not bind joins nothing
+            joined, pending, ordered = {base}, list(joins), []
+            while pending:
+                nxt = next((j for j in pending if any(
+                    {_alias(a), _alias(b)} - joined == {j.alias}
+                    for a, b in equalities)), None)
+                if nxt is None:
+                    for j in pending:
+                        self.diag(
+                            "DTA306",
+                            f"table {j.alias!r} of the FROM list is "
+                            f"joined to the tables before it by no "
+                            f"column equality in WHERE (a cross product "
+                            f"is not supported)", j.span)
+                    self.fail_if_dirty()
+                pending.remove(nxt)
+                joined.add(nxt.alias)
+                ordered.append(nxt)
+            joins[:] = ordered
+        pos = {base: 0}
+        pos.update({j.alias: i + 1 for i, j in enumerate(joins)})
+
+        kept: List[Prog] = []
+        for c, eq in zip(conj, eq_of):
+            if eq is not None:
+                a, b = sorted(eq, key=lambda p: pos[_alias(p)])
+                j = joins[pos[_alias(b)] - 1]
+                if a not in j.left_keys and b not in j.right_keys:
+                    j.left_keys.append(a)
+                    j.right_keys.append(b)
+                    continue
+            kept.append(c)
+
+        # a table's own filter goes below its join unless an outer join
+        # fills the table's side with nulls (zeros) after the filter ran
+        hows = [j.how for j in joins]
+        pushable = set()
+        for alias, i in pos.items():
+            own = hows[i - 1] if i else "inner"
+            later = hows[i:]
+            if own in ("inner", "right") and not any(
+                    h in ("right", "full") for h in later):
+                pushable.add(alias)
+        per_table: Dict[str, List[Prog]] = {}
+        rest: List[Prog] = []
+        for c in kept:
+            aliases = {_alias(p) for p in prog_columns(c)}
+            if len(aliases) == 1 and aliases <= pushable:
+                per_table.setdefault(aliases.pop(), []).append(c)
+            else:
+                rest.append(c)
+        return (_and(kept), {a: _and(cs) for a, cs in per_table.items()},
+                _and(rest))
+
+    def _choose_sides(self, joins: List[BoundJoin]) -> None:
+        """Which input of each join stage probes and which is built,
+        from the catalog's row counts: the stage's out_capacity is its
+        left capacity, and a key/foreign-key join returns at most the
+        rows of its larger (foreign-key) side — so the larger side goes
+        left.  Outer joins keep the written sides.  Nothing here says
+        that a side's keys are unique (the catalog holds no constraint),
+        so no join asks for the merge join (``right_unique``): asked for
+        blindly it compiles both join kernels into every join stage."""
+        rows = self.catalog.get(self.stmt.table.name).rows
+        for j in joins:
+            t_rows = self.catalog.get(j.table).rows
+            if j.how == "inner":
+                j.swap = t_rows > rows
+                rows = max(rows, t_rows)
+            elif j.how != "left":
+                rows += t_rows
+
     # -- expressions -------------------------------------------------------
 
     def _bind_col(self, col: N.Col, scope: _Scope):
@@ -315,6 +459,12 @@ class _Binder:
         seen: set = set()
         base_renames = self._table_scope(stmt.table, scope, seen)
         joins: List[BoundJoin] = []
+        for ref in stmt.from_list:
+            # joined by WHERE's equalities (_place_conjuncts)
+            renames = self._table_scope(ref, scope, seen)
+            if renames is not None:
+                joins.append(BoundJoin(ref.name, ref.alias, "inner", [],
+                                       [], renames, span=ref.span))
         left_aliases = {stmt.table.alias}
         for jc in stmt.joins:
             renames = self._table_scope(jc.table, scope, seen)
@@ -340,6 +490,10 @@ class _Binder:
                 self.diag("DTA305",
                           f"WHERE must be boolean, got {wt}",
                           getattr(stmt.where, "span", stmt.span))
+                where = None
+        where, scan_filters, residual = self._place_conjuncts(
+            where, joins, scope)
+        self._choose_sides(joins)
 
         has_agg = any(isinstance(it.expr, N.Agg) for it in stmt.items)
         grouped = bool(stmt.group_by) or has_agg
@@ -528,7 +682,8 @@ class _Binder:
             span=stmt.span,
             where_span=getattr(stmt.where, "span", None),
             having_span=getattr(stmt.having, "span", None),
-            emit_every=stmt.emit_every, emit_span=stmt.emit_span)
+            emit_every=stmt.emit_every, emit_span=stmt.emit_span,
+            scan_filters=scan_filters, residual=residual)
 
 
 def bind(catalog: Catalog, stmt: N.Select) -> BoundSelect:

@@ -9,9 +9,13 @@ sources, and per-tenant admission when submitted through the service.
 
 Shape of the lowered chain::
 
-    FROM t [JOIN ...]      catalog.dataset() roots + rename Projector
-                           (every column becomes ``alias.col``)
-    WHERE                  .where(Predicate)
+    FROM t [, u | JOIN u]  catalog.dataset() roots + rename Projector
+                           (a column the statement names becomes
+                           ``alias.col``; the others are dropped)
+    WHERE, one table's     .where(Predicate) on that table's scan, below
+                           its join; a column only it reads goes after
+    JOIN / FROM-list keys  .join(...), the larger input on the left
+    WHERE, the residual    .where(Predicate) above the joins
     GROUP BY + aggregates  pre-Projector (keys + agg-input exprs)
                            -> .group_by(keys, aggs) [-> .where(HAVING)]
     SELECT list            final Projector (output names)
@@ -30,7 +34,8 @@ from typing import Any, Dict, Optional, Tuple
 
 from dryad_tpu.sql.binder import BoundSelect
 from dryad_tpu.sql.catalog import Catalog
-from dryad_tpu.sql.rowexpr import Predicate, Projector
+from dryad_tpu.sql.rowexpr import (Predicate, Projector, prog_columns,
+                                   rename_prog)
 
 __all__ = ["lower", "GLOBAL_AGG_KEY"]
 
@@ -54,36 +59,97 @@ def _stamp(ds, span):
     return ds
 
 
-def lower(ctx, catalog: Catalog, bound: BoundSelect, loader=None
-          ) -> Tuple[Any, Dict[int, str]]:
+def lower(ctx, catalog: Catalog, bound: BoundSelect, loader=None,
+          span=None) -> Tuple[Any, Dict[int, str]]:
     """(dataset, source-handle map) for a bound statement under ``ctx``
     (api.Context or sql.catalog.SchemaContext).  The handle map
     (``id(Source.data) -> table name``) lets the service re-bind plan
     source slots on a warm plan-cache hit.  ``loader`` (optional,
     ``name -> PData``) is forwarded to :meth:`Catalog.dataset` — the
     service's scan-share hook (one cold scan for concurrent jobs over
-    the same table)."""
+    the same table).  ``span`` (the caller's ``sql.lower`` span) is
+    given ``columns_kept`` / ``columns_stored``, a table."""
     handles: Dict[int, str] = {}
+
+    # an inner join's output drops its right input's key columns; what
+    # the statement reads of them above the join reads the left input's
+    # (equal) key instead: dropped name -> the name that lives on
+    subst: Dict[str, str] = {}
+
+    def live(name: str) -> str:
+        while name in subst:
+            name = subst[name]
+        return name
+
+    def above(prog):
+        return rename_prog(prog, {k: live(k) for k in subst})
+
+    # the columns each scan keeps: what the statement names above the
+    # scans (keys, residual, group, aggregates, select list) ...
+    named = set(bound.group_keys)
+    for j in bound.joins:
+        named |= set(j.left_keys) | set(j.right_keys)
+    progs = list((bound.pre_projection if bound.grouped
+                  else bound.outputs).values())
+    if bound.residual is not None:
+        progs.append(bound.residual)
+    for prog in progs:
+        named |= prog_columns(prog)
+    kept: Dict[str, int] = {}
+    stored: Dict[str, int] = {}
 
     def root(table: str, alias: str, renames: Dict[str, str], span):
         ds, data = catalog.dataset(ctx, table, loader=loader)
         handles[id(data)] = table
         _stamp(ds, span)
-        return _stamp(ds.select(_rename_projector(renames),
-                                label=f"sql-scan {alias}"), span)
+        pred = bound.scan_filters.get(alias)
+        # ... and, until it has run, what the table's own filter reads
+        pred_reads = prog_columns(pred) if pred is not None else set()
+        keep = [p for p in renames if p in named]
+        only_pred = [p for p in renames
+                     if p in pred_reads and p not in named]
+        if not keep:
+            # COUNT(*) names no column; a batch needs one to have rows:
+            # one the filter reads anyway, else a numeric one
+            keep = only_pred[:1] or [min(renames, key=lambda p: (
+                catalog.get(table).schema[renames[p]]["kind"] == "str"))]
+            only_pred = only_pred[1:]
+        reads = keep + only_pred
+        stored[table] = stored.get(table, 0) + len(renames)
+        kept[table] = kept.get(table, 0) + len(keep)
+        ds = _stamp(ds.select(_rename_projector(
+            {p: renames[p] for p in reads}), label=f"sql-scan {alias}"),
+            span)
+        if pred is not None:
+            ds = _stamp(ds.where(Predicate(pred),
+                                 label=f"sql-where {alias}"),
+                        bound.where_span or span)
+            if only_pred:
+                ds = _stamp(ds.select(_rename_projector(
+                    {p: p for p in keep}), label=f"sql-prune {alias}"),
+                    span)
+        return ds
 
     cur = root(bound.base_table, bound.base_alias, bound.base_renames,
                bound.span)
     for j in bound.joins:
-        right = root(j.table, j.alias, j.renames, j.span)
-        cur = _stamp(cur.join(right, j.left_keys, j.right_keys,
-                              how=j.how), j.span)
-    if bound.where is not None:
-        cur = _stamp(cur.where(Predicate(bound.where),
+        other = root(j.table, j.alias, j.renames, j.span)
+        lks = [live(k) for k in j.left_keys]
+        cur = _stamp(other.join(cur, j.right_keys, lks, how=j.how)
+                     if j.swap else
+                     cur.join(other, lks, j.right_keys, how=j.how), j.span)
+        if j.how == "inner":
+            subst.update(zip(lks, j.right_keys) if j.swap
+                         else zip(j.right_keys, lks))
+    if span is not None:
+        span.set(columns_kept=kept, columns_stored=stored)
+    if bound.residual is not None:
+        cur = _stamp(cur.where(Predicate(above(bound.residual)),
                                label="sql-where"),
                      bound.where_span or bound.span)
     if bound.grouped:
-        pre = dict(bound.pre_projection or {})
+        pre = {name: above(prog)
+               for name, prog in (bound.pre_projection or {}).items()}
         keys = list(bound.group_keys)
         if not keys:
             # global aggregate: one constant key, dropped again by the
@@ -97,8 +163,12 @@ def lower(ctx, catalog: Catalog, bound: BoundSelect, loader=None
             cur = _stamp(cur.where(Predicate(bound.having),
                                    label="sql-having"),
                          bound.having_span or bound.span)
-    cur = _stamp(cur.select(Projector(bound.outputs),
-                            label="sql-select"), bound.span)
+        outputs = bound.outputs
+    else:
+        outputs = {name: above(prog)
+                   for name, prog in bound.outputs.items()}
+    cur = _stamp(cur.select(Projector(outputs), label="sql-select"),
+                 bound.span)
     if bound.distinct:
         cur = _stamp(cur.distinct(), bound.span)
     if bound.order_by:

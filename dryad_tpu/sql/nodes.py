@@ -105,3 +105,6 @@ class Select:
     # [SECONDS]); None for plain batch queries
     emit_every: Optional[float] = None
     emit_span: Optional[Span] = None
+    # FROM a, b, c: the tables after the first; WHERE's cross-table
+    # equalities join them (binder), and ``joins`` is then empty
+    from_list: Tuple[TableRef, ...] = ()

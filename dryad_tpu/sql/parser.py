@@ -3,18 +3,23 @@
 Grammar (the DryadLINQ-parity declarative surface over the plan DAG —
 SELECT / WHERE / GROUP BY + aggregates / JOIN / ORDER BY / LIMIT)::
 
-    query     := SELECT [DISTINCT] items FROM table_ref join* [WHERE expr]
+    query     := SELECT [DISTINCT] items FROM from [WHERE expr]
                  [GROUP BY col ("," col)*] [HAVING expr]
                  [ORDER BY ord ("," ord)*] [LIMIT int]
                  [EMIT EVERY num [SECONDS]] [";"]
     items     := "*" | item ("," item)*
     item      := expr [[AS] ident]
+    from      := table_ref ("," table_ref)+ | table_ref join*
     table_ref := ident [[AS] ident]
     join      := [INNER | LEFT|RIGHT|FULL [OUTER]] JOIN table_ref ON expr
     ord       := ident [ASC | DESC]
     expr      := or-tree over NOT / comparisons / + - / * / / unary- /
                  "(" expr ")" / literal / [ident "."] ident /
                  SUM|COUNT|MIN|MAX|AVG "(" expr | "*" ")"
+
+A FROM list (``FROM a, b, c``) is joined by the cross-table equalities
+of WHERE (the binder finds them); it is never a cross product, and it
+does not mix with ``JOIN ... ON`` in one statement (DTA306).
 
 A syntax error raises :class:`SqlError` with DTA301 and the offending
 token's line:column; recognized-but-unsupported constructs (subqueries,
@@ -105,9 +110,16 @@ class _Parser:
         items = self.select_items()
         self.expect_kw("FROM")
         table = self.table_ref()
+        from_list = []
+        while self.at_punct(","):
+            self.take()
+            from_list.append(self.table_ref())
         joins = []
         while self.at_kw("JOIN", "INNER", "LEFT", "RIGHT", "FULL",
                          "CROSS", "NATURAL"):
+            if from_list:
+                raise self.err("a FROM list does not mix with JOIN ... "
+                               "ON in one statement", code="DTA306")
             joins.append(self.join_clause())
         where = None
         if self.at_kw("WHERE"):
@@ -159,6 +171,7 @@ class _Parser:
         if self.cur.kind != "eof":
             raise self.err("unexpected trailing input")
         return N.Select(items=items, distinct=distinct, table=table,
+                        from_list=tuple(from_list),
                         joins=tuple(joins), where=where,
                         group_by=tuple(group_by), having=having,
                         order_by=tuple(order_by), limit=limit,

@@ -31,7 +31,7 @@ from __future__ import annotations
 from typing import Any, Dict, List
 
 __all__ = ["Predicate", "Projector", "render_prog", "prog_columns",
-           "fold_prog"]
+           "rename_prog", "fold_prog"]
 
 
 def _is_strcol(v: Any) -> bool:
@@ -183,6 +183,20 @@ def prog_columns(prog: List) -> set:
     if head == "bin":
         return prog_columns(prog[2]) | prog_columns(prog[3])
     raise ValueError(f"bad row-expression program node {prog!r}")
+
+
+def rename_prog(prog: List, names: Dict[str, str]) -> List:
+    """``prog`` with its column references renamed through ``names``
+    (a name it lacks stays)."""
+    head = prog[0]
+    if head == "col":
+        return ["col", names.get(prog[1], prog[1])]
+    if head in ("lit", "const"):
+        return list(prog)
+    if head in ("not", "neg"):
+        return [head, rename_prog(prog[1], names)]
+    return ["bin", prog[1], rename_prog(prog[2], names),
+            rename_prog(prog[3], names)]
 
 
 def fold_prog(prog: List) -> List:
