@@ -1368,25 +1368,29 @@ def write_chunks_to_store(path: str, chunks: Iterable[HChunk],
             "streamed writes to s3:// are not supported (no atomic "
             "multi-object commit for an unbounded chunk stream); "
             "to_store to a local or hdfs:// path instead")
-    from dryad_tpu.io.store import chunk_segments
+    from dryad_tpu.io.store import chunk_segments, part_checksums
 
     tmp = path + ".tmp"
     os.makedirs(tmp, exist_ok=True)
     counts: List[int] = []
     checksums: List[str] = []
+    leaf_checksums: List[List[str]] = []
     p = 0
     for chunk in chunks:
         segs = chunk_segments(store_schema, chunk.cols)
         native.write_files([os.path.join(tmp, f"part-{p:05d}.bin")], [segs],
                            compress=(compression == "gzip"))
-        checksums.append("%016x" % native.checksum_segments(segs))
+        sums, leaves, _ = part_checksums(store_schema, [chunk.n], [segs])
+        checksums += sums
+        leaf_checksums += leaves
         counts.append(chunk.n)
         p += 1
     import json
 
     from dryad_tpu.io.store import build_meta
     meta = build_meta(store_schema, counts, checksums,
-                      partitioning=partitioning, compression=compression)
+                      partitioning=partitioning, compression=compression,
+                      leaf_checksums=leaf_checksums)
     with open(os.path.join(tmp, "meta.json"), "w") as f:
         json.dump(meta, f, indent=1)
     if os.path.exists(path):

@@ -67,9 +67,9 @@ def s3_store_meta(url: str, client: Optional[S3Client] = None
 def s3_write_store(url: str, pd, partitioning=None, compression=None,
                    client: Optional[S3Client] = None) -> None:
     """write_store for s3:// paths (same segments, checksums, meta)."""
-    from dryad_tpu import native
     from dryad_tpu.io.store import (build_meta, fetch_part_segments,
-                                    pdata_schema, segments_blob)
+                                    part_checksums, pdata_schema,
+                                    segments_blob)
 
     if compression not in (None, "gzip"):
         raise ValueError(f"unknown compression {compression!r}")
@@ -79,15 +79,16 @@ def s3_write_store(url: str, pd, partitioning=None, compression=None,
     schema = pdata_schema(pd)
     import uuid
     gen = uuid.uuid4().hex[:12]
-    checksums: List[str] = []
+    segments = []
     for p, (segs, _, _) in enumerate(
             fetch_part_segments(pd, schema, counts)):
-        checksums.append("%016x" % native.checksum_segments(segs))
+        segments.append(segs)
         c.put_object(bucket, _part_key(prefix, p, gen),
                      segments_blob(segs, compression))
+    checksums, leaf_checksums, _ = part_checksums(schema, counts, segments)
     meta = build_meta(schema, counts.tolist(), checksums,
                       partitioning=partitioning, compression=compression,
-                      capacity=pd.capacity)
+                      capacity=pd.capacity, leaf_checksums=leaf_checksums)
     meta["generation"] = gen
     # the PREVIOUS meta (if any) names the generation readers may still
     # be holding — it survives this overwrite; anything older is garbage

@@ -21,7 +21,20 @@ rows only, every copy started before any is awaited, narrow rows reshaped
 to wide ones on the device so that they arrive contiguous).  A partition's
 segments are then each leaf's chunks as they arrived, in file order: a
 column is several consecutive segments, never one assembled copy — file
-bytes and the chained fnv64 are the same either way.
+bytes and the digest are the same either way.
+
+Integrity (``part_checksums``, the ONE digest of every writer and
+verifier): every byte written is digested before the write returns and
+every byte read is verified before the read returns.  The manifest names
+the digest's form.  ``fnv64-blocks`` (format v4): a partition's leaves in
+file order (a column's array; a string column is data then lengths), each
+cut into blocks of ``checksum_block`` bytes, each block 64-bit FNV-1a
+from the basis, a leaf's digest FNV-1a over its block digests, a
+partition's (``checksums[p]``) over its leaf digests
+(``leaf_checksums[p]``) — independent chains that native/dryad_io.cpp runs
+several in lockstep a worker on every core.  ``fnv64`` (format v3, still
+read and appended to as written): one chain over all of a partition's
+bytes.
 """
 
 from __future__ import annotations
@@ -41,9 +54,15 @@ from dryad_tpu.obs import trace
 __all__ = ["write_store", "read_store", "store_meta", "build_meta",
            "schema_row_bytes", "StoreIntegrityError", "is_remote_store",
            "remote_read_part_views", "append_store", "store_generation",
-           "parts_since"]
+           "parts_since", "part_checksums", "leaf_nbytes"]
 
-_FORMAT_VERSION = 3
+# the digest's form a store is written in (docs/store_format.md); a block
+# is sized so that a column of a few MB already fills a worker's lanes and
+# the per-block bookkeeping stays under a thousandth of its bytes
+CHECKSUM_ALGO = "fnv64-blocks"
+CHECKSUM_BLOCK = 1 << 20
+# a manifest's format_version follows the form its digests are in
+_FORMAT_VERSION = {"fnv64": 3, CHECKSUM_ALGO: 4}
 
 _REMOTE_SCHEMES = ("s3://", "hdfs://")
 
@@ -67,7 +86,7 @@ def remote_read_part_views(path: str, meta: Dict[str, Any], p: int):
 
 class StoreIntegrityError(RuntimeError):
     """A partition file's content does not match its recorded checksum
-    (fnv64 over the partition's segments, chained — the role of the
+    (``part_checksums`` in the form the manifest names — the role of the
     reference's channel fingerprints, classlib fingerprint.cpp /
     ms_fprint.cpp)."""
 
@@ -87,13 +106,32 @@ def schema_row_bytes(schema: Dict[str, Any]) -> int:
     return _srb(schema_from_store_schema(schema))
 
 
+def checksum_form(meta: Optional[Dict[str, Any]] = None) -> Dict[str, Any]:
+    """The digest's form as a manifest names it (a manifest has ONE form:
+    ``append_store`` digests new partitions in the form the store was
+    written in); the current form for a store not yet written."""
+    if meta is None:
+        return {"checksum_algo": CHECKSUM_ALGO,
+                "checksum_block": CHECKSUM_BLOCK}
+    algo = meta.get("checksum_algo", "fnv64")
+    if algo == "fnv64":
+        return {"checksum_algo": algo}
+    if algo != CHECKSUM_ALGO:
+        raise ValueError(f"unknown checksum_algo {algo!r} in a store's "
+                         "manifest")
+    return {"checksum_algo": algo,
+            "checksum_block": int(meta["checksum_block"])}
+
+
 def build_meta(schema: Dict[str, Any], counts: List[int],
                checksums: List[str],
                partitioning: Optional[Dict[str, Any]] = None,
                compression: Optional[str] = None,
                capacity: Optional[int] = None,
                generation: int = 0,
-               part_generations: Optional[List[int]] = None
+               part_generations: Optional[List[int]] = None,
+               leaf_checksums: Optional[List[List[str]]] = None,
+               form: Optional[Dict[str, Any]] = None
                ) -> Dict[str, Any]:
     """The ONE meta.json constructor — every writer (in-memory write_store,
     streamed write_chunks_to_store, cluster parallel partition writers)
@@ -112,10 +150,18 @@ def build_meta(schema: Dict[str, Any], counts: List[int],
     generation 0, every :func:`append_store` commit bumps it, and
     ``part_generations[p]`` records the generation that added partition
     p — so a standing-query refresh holding watermark W scopes its scan
-    to ``parts_since(meta, W)`` without touching old partition files."""
+    to ``parts_since(meta, W)`` without touching old partition files.
+
+    ``checksums`` / ``leaf_checksums`` are ``part_checksums``' in the
+    digest's ``form`` (``checksum_form``; the current one by default).
+    ``leaf_checksums[p]`` are partition p's leaf digests in file order —
+    what a read of some columns only verifies those columns by; a writer
+    that cannot carry them (the cluster writer's allgather of one digest a
+    partition) leaves them out and readers verify ``checksums`` alone."""
     rb = schema_row_bytes(schema)
+    form = form or checksum_form()
     return {
-        "format_version": _FORMAT_VERSION,
+        "format_version": _FORMAT_VERSION[form["checksum_algo"]],
         "npartitions": len(counts),
         "counts": list(counts),
         "bytes": [int(c) * rb for c in counts],
@@ -124,8 +170,10 @@ def build_meta(schema: Dict[str, Any], counts: List[int],
         "schema": schema,
         "partitioning": partitioning or {"kind": "none"},
         "compression": compression,
-        "checksum_algo": "fnv64",
+        **form,
         "checksums": checksums,
+        **({"leaf_checksums": leaf_checksums}
+           if form["checksum_algo"] == CHECKSUM_ALGO else {}),
         "native_io": native.available(),
         "generation": int(generation),
         "part_generations": (list(part_generations)
@@ -211,6 +259,46 @@ def fill_segments(segs: List[np.ndarray], data: bytes, what: str) -> None:
         off += nb
 
 
+def leaf_nbytes(schema: Dict[str, Any], n: int) -> List[int]:
+    """Byte length of each leaf of a partition of ``n`` rows, in file
+    order (sorted columns; a string column is data then lengths)."""
+    sizes: List[int] = []
+    for k in _col_order(schema):
+        spec = schema[k]
+        if spec["kind"] == "str":
+            sizes.extend([n * int(spec["max_len"]), n * 4])
+        else:
+            sizes.append(n * np.dtype(spec["dtype"]).itemsize
+                         * int(np.prod(spec.get("shape", ()), dtype=np.int64)))
+    return sizes
+
+
+def part_checksums(schema: Dict[str, Any], counts, segments,
+                   meta: Optional[Dict[str, Any]] = None
+                   ) -> Tuple[List[str], Optional[List[List[str]]],
+                              Dict[str, Any]]:
+    """The ONE digest of every store writer and verifier:
+    ``(checksums, leaf_checksums, info)`` of partitions holding
+    ``counts[i]`` rows whose bytes are ``segments[i]``, contiguous arrays
+    cut anywhere (the chunks a column came off the device in, an array a
+    column, one blob), in the form ``meta`` names — the current one for a
+    store not yet written.  ``leaf_checksums`` is None in the chained form,
+    which has none; ``info`` says what ran (``algo``, ``blocks``,
+    ``threads``) and rides on the ``store.verify`` / ``store.checksum``
+    spans."""
+    form = checksum_form(meta)
+    if form["checksum_algo"] == "fnv64":
+        return (["%016x" % native.checksum_segments(segs)
+                 for segs in segments], None,
+                {"algo": "fnv64", "blocks": len(segments), "threads": 1})
+    sums, leaves, ran = native.digest_parts(
+        segments, [leaf_nbytes(schema, int(n)) for n in counts],
+        form["checksum_block"])
+    return (["%016x" % h for h in sums],
+            [["%016x" % h for h in part] for part in leaves],
+            {"algo": form["checksum_algo"], **ran})
+
+
 def _segments_nbytes(segments) -> int:
     """Payload bytes of per-partition segment lists, from their shapes."""
     return sum(s.nbytes for segs in segments for s in segs)
@@ -277,14 +365,16 @@ def write_store(path: str, pd: PData,
                         files=len(paths)):
             native.write_files(paths, segments,
                                compress=(compression == "gzip"))
-        with trace.span("store.checksum", "io", bytes=nbytes):
-            checksums = ["%016x" % native.checksum_segments(segs)
-                         for segs in segments]
+        with trace.span("store.checksum", "io", bytes=nbytes) as csp:
+            checksums, leaf_checksums, ran = part_checksums(
+                schema, counts, segments)
+            csp.set(**ran)
         with trace.span("store.commit", "io"):
             meta = build_meta(schema, counts.tolist(), checksums,
                               partitioning=partitioning,
                               compression=compression,
-                              capacity=pd.capacity)
+                              capacity=pd.capacity,
+                              leaf_checksums=leaf_checksums)
             with open(os.path.join(tmp, "meta.json"), "w") as f:
                 json.dump(meta, f, indent=1)
             if os.path.exists(path):
@@ -336,8 +426,9 @@ def append_store(path: str, pd: PData) -> int:
         return store_generation(meta)
     native.write_files(paths, segments,
                        compress=(compression == "gzip"))
-    checksums = ["%016x" % native.checksum_segments(segs)
-                 for segs in segments]
+    checksums, leaf_checksums, _ = part_checksums(schema, new_counts,
+                                                  segments, meta)
+    old_leaves = meta.get("leaf_checksums")
     gen = store_generation(meta) + 1
     gens = list(meta.get("part_generations") or [0] * base)
     part = meta.get("partitioning") or {"kind": "none"}
@@ -349,7 +440,12 @@ def append_store(path: str, pd: PData) -> int:
         compression=compression,
         capacity=max(int(meta.get("capacity", 1)), max(new_counts)),
         generation=gen,
-        part_generations=gens + [gen] * len(new_counts))
+        part_generations=gens + [gen] * len(new_counts),
+        # a manifest carries leaf digests for every partition or for none
+        leaf_checksums=(old_leaves + leaf_checksums
+                        if None not in (old_leaves, leaf_checksums)
+                        else None),
+        form=checksum_form(meta))
     from dryad_tpu.utils.atomic import atomic_write_json
     atomic_write_json(os.path.join(path, "meta.json"), new_meta,
                       indent=1)
@@ -369,20 +465,41 @@ def store_meta(path: str) -> Dict[str, Any]:
 
 def verify_checksums(path: str, meta: Dict[str, Any],
                      segments: List[List[np.ndarray]],
-                     partitions: Optional[List[int]] = None) -> None:
-    """Compare freshly-read partition segments against the recorded fnv64
-    checksums; raise StoreIntegrityError on mismatch.  Stores written
-    before format v3 carry no checksums and are accepted as-is."""
+                     partitions: Optional[List[int]] = None
+                     ) -> Optional[Dict[str, Any]]:
+    """Compare freshly-read partition segments against the recorded
+    checksums, in the form the manifest names and in ONE digest call for
+    all of them; raise StoreIntegrityError naming the partition (and, where
+    the manifest carries leaf digests, the column) on a mismatch.  Returns
+    ``part_checksums``' info.  Stores written before format v3 carry no
+    checksums and are accepted as-is (None)."""
     recorded = meta.get("checksums")
     if not recorded:
-        return
-    parts = partitions if partitions is not None else range(len(segments))
-    for segs, p in zip(segments, parts):
-        got = "%016x" % native.checksum_segments(segs)
-        if got != recorded[p]:
+        return None
+    parts = list(partitions if partitions is not None
+                 else range(len(segments)))
+    schema = meta["schema"]
+    counts = [int(meta["counts"][p]) for p in parts]
+    for segs, p, n in zip(segments, parts, counts):
+        have, want = _segments_nbytes([segs]), sum(leaf_nbytes(schema, n))
+        if have != want:
             raise StoreIntegrityError(
-                f"partition {p} of {path}: checksum {got} != recorded "
-                f"{recorded[p]} — file corrupted or tampered")
+                f"partition {p} of {path}: {have} bytes read, the manifest's "
+                f"{n} rows are {want} — file truncated or tampered")
+    sums, leaves, ran = part_checksums(schema, counts, segments, meta)
+    rec_leaves = meta.get("leaf_checksums") if leaves is not None else None
+    leaf_column = [k for k in _col_order(schema)
+                   for _ in range(2 if schema[k]["kind"] == "str" else 1)]
+    for i, p in enumerate(parts):
+        bad = [j for j, (got, rec) in enumerate(zip(leaves[i], rec_leaves[p]))
+               if got != rec] if rec_leaves else []
+        if sums[i] != recorded[p] or bad:
+            where = (f" (column {leaf_column[bad[0]]!r}, leaf {bad[0]})"
+                     if bad else "")
+            raise StoreIntegrityError(
+                f"partition {p} of {path}{where}: checksum {sums[i]} != "
+                f"recorded {recorded[p]} — file corrupted or tampered")
+    return ran
 
 
 def _alloc_part_views(schema, n: int) -> Tuple[List[np.ndarray],
@@ -445,14 +562,26 @@ def read_store(path: str, mesh, capacity: Optional[int] = None,
                     paths.append(_part_path(path, p))
                     segments.append(segs)
                     partviews.append(cols)
+                    # a file cut short or grown is named as what it is, not
+                    # as a failed read (a gzip file's size says nothing)
+                    if verify and meta.get("compression") is None:
+                        have = os.path.getsize(paths[-1])
+                        want = _segments_nbytes([segs])
+                        if have != want:
+                            raise StoreIntegrityError(
+                                f"partition {p} of {path}: the file holds "
+                                f"{have} bytes, the manifest's "
+                                f"{meta['counts'][p]} rows are {want} — "
+                                "file truncated or tampered")
                 native.read_files(paths, segments,
                                   compress=(meta.get("compression") == "gzip"))
             nbytes = _segments_nbytes(segments)
             fsp.set(bytes=nbytes)
         sp.set(bytes=nbytes)
         if verify:
-            with trace.span("store.verify", "io", bytes=nbytes):
-                verify_checksums(path, meta, segments, partitions=part_ids)
+            with trace.span("store.verify", "io", bytes=nbytes) as vsp:
+                vsp.set(**(verify_checksums(path, meta, segments,
+                                            partitions=part_ids) or {}))
 
         if nparts_store == nparts:
             # verbatim per-partition load: placement-preserving

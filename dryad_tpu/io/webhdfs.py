@@ -333,16 +333,6 @@ def hdfs_store_meta(url: str, client: Optional[WebHdfsClient] = None
     return json.loads(c.read_all(path.rstrip("/") + "/meta.json"))
 
 
-def part_blob(segs: List[np.ndarray],
-              compression: Optional[str]) -> Tuple[bytes, int]:
-    """(serialized partition blob, fnv64 checksum of the UNCOMPRESSED
-    segments) — the store read contract (io/store.verify_checksums)."""
-    from dryad_tpu import native
-    from dryad_tpu.io.store import segments_blob
-
-    return segments_blob(segs, compression), native.checksum_segments(segs)
-
-
 def hdfs_write_store(url: str, pd, partitioning=None, compression=None,
                      client: Optional[WebHdfsClient] = None) -> None:
     """write_store for hdfs:// paths.  HDFS has an atomic rename, so the
@@ -352,7 +342,8 @@ def hdfs_write_store(url: str, pd, partitioning=None, compression=None,
     import uuid
 
     from dryad_tpu.io.store import (build_meta, fetch_part_segments,
-                                    pdata_schema)
+                                    part_checksums, pdata_schema,
+                                    segments_blob)
 
     if compression not in (None, "gzip"):
         raise ValueError(f"unknown compression {compression!r}")
@@ -362,15 +353,16 @@ def hdfs_write_store(url: str, pd, partitioning=None, compression=None,
     schema = pdata_schema(pd)
     tmp = path + ".tmp-" + uuid.uuid4().hex[:12]
     c.mkdirs(tmp)
-    checksums: List[str] = []
+    segments = []
     for p, (segs, _, _) in enumerate(
             fetch_part_segments(pd, schema, counts)):
-        blob, checksum = part_blob(segs, compression)
-        checksums.append("%016x" % checksum)
-        c.create(hdfs_part_path(tmp, p), blob)
+        segments.append(segs)
+        c.create(hdfs_part_path(tmp, p), segments_blob(segs, compression))
+    # digests of the UNCOMPRESSED segments, every partition in one call
+    checksums, leaf_checksums, _ = part_checksums(schema, counts, segments)
     meta = build_meta(schema, counts.tolist(), checksums,
                       partitioning=partitioning, compression=compression,
-                      capacity=pd.capacity)
+                      capacity=pd.capacity, leaf_checksums=leaf_checksums)
     c.create(tmp + "/meta.json", json.dumps(meta, indent=1).encode())
     c.delete(path, recursive=True)   # False = nothing to remove
     c.rename(tmp, path)
@@ -436,9 +428,8 @@ def _write_chunks_hdfs(url: str, chunks, schema: Dict[str, Any],
     written last, temp-dir rename commit."""
     import uuid
 
-    from dryad_tpu import native
     from dryad_tpu.io.store import (build_meta, chunk_segments,
-                                    segments_blob)
+                                    part_checksums, segments_blob)
 
     if compression not in (None, "gzip"):
         raise ValueError(f"unknown compression {compression!r}")
@@ -448,15 +439,19 @@ def _write_chunks_hdfs(url: str, chunks, schema: Dict[str, Any],
     c.mkdirs(tmp)
     counts: List[int] = []
     checksums: List[str] = []
+    leaf_checksums: List[List[str]] = []
     p = 0
     for chunk in chunks:
         segs = chunk_segments(schema, chunk.cols)
-        checksums.append("%016x" % native.checksum_segments(segs))
+        sums, leaves, _ = part_checksums(schema, [chunk.n], [segs])
+        checksums += sums
+        leaf_checksums += leaves
         c.create(hdfs_part_path(tmp, p), segments_blob(segs, compression))
         counts.append(chunk.n)
         p += 1
     meta = build_meta(schema, counts, checksums,
-                      partitioning=partitioning, compression=compression)
+                      partitioning=partitioning, compression=compression,
+                      leaf_checksums=leaf_checksums)
     c.create(tmp + "/meta.json", json.dumps(meta, indent=1).encode())
     c.delete(path, recursive=True)
     c.rename(tmp, path)

@@ -1,8 +1,9 @@
 """ctypes bindings for the native IO engine (native/dryad_io.cpp).
 
-Builds on first use (g++ via make) and degrades gracefully to pure-Python
-fallbacks when no toolchain is available — `available()` reports which path
-is active.  pybind11 is not in this environment, so the binding layer is
+Builds on first use, and again whenever ``dryad_io.cpp`` is newer than the
+library (g++ via make), and degrades to pure-Python forms of the same
+functions only when no library can be built at all — `available()` reports
+which path is active.  pybind11 is not in this environment, so the binding layer is
 ctypes over a plain C ABI.
 """
 
@@ -12,60 +13,74 @@ import ctypes
 import os
 import subprocess
 import threading
-from typing import List, Optional, Sequence, Tuple
+from typing import Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
 _REPO = os.path.dirname(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))
 _NATIVE_DIR = os.path.join(_REPO, "native")
-_SO = os.path.join(_NATIVE_DIR, "libdryad_io.so")
 
 _lock = threading.Lock()
 _lib: Optional[ctypes.CDLL] = None
 _tried = False
+_error: Optional[Exception] = None
+
+_P, _I64, _I32, _U64 = (ctypes.c_void_p, ctypes.c_int64, ctypes.c_int32,
+                        ctypes.c_uint64)
+# every entry point of native/dryad_io.cpp this module calls
+_SIGNATURES = {
+    "dryad_pack_lines": (_I64, [_P, _I64, _I64, _P, _P, _I64]),
+    "dryad_pack_bytes": (_I64, [ctypes.POINTER(_P), _P, _I64, _I64, _P, _P,
+                                _I64]),
+    "dryad_file_jobs": (_I64, [ctypes.POINTER(ctypes.c_char_p), _I64,
+                               ctypes.POINTER(_P), _P, _P, _I32, _I32]),
+    "dryad_compact_rows": (_I64, [_P, _P, _I64, _I64, _P, _P]),
+    "dryad_fingerprint_seed": (_U64, [_P, _I64, _U64]),
+    "dryad_digest_parts": (_I64, [ctypes.POINTER(_P), _P, _P, _P, _P, _I64,
+                                  _I64, _P, _P, _P]),
+}
+
+
+def _open_library(native_dir: str) -> Optional[ctypes.CDLL]:
+    """Build (when ``dryad_io.cpp`` is newer than the library, or the
+    library is absent: a ``make`` with nothing to do costs milliseconds)
+    and open ``libdryad_io.so`` of ``native_dir``.  None only when there
+    is no library and none can be built (no toolchain): the numpy forms
+    then compute the same functions.  A library that is there but lacks an
+    entry point is an error, never a silent fallback — the byte-at-a-time
+    Python chain takes hours where the native one takes a second."""
+    so = os.path.join(native_dir, "libdryad_io.so")
+    try:
+        subprocess.run(["make", "-C", native_dir, "-s"],
+                       check=True, capture_output=True, timeout=120)
+    except (OSError, subprocess.SubprocessError):
+        pass            # no make, no compiler: whatever library is there
+    if not os.path.exists(so):
+        return None
+    lib = ctypes.CDLL(so)
+    missing = [n for n in _SIGNATURES if not hasattr(lib, n)]
+    if missing:
+        raise RuntimeError(
+            f"{so} lacks {', '.join(missing)}: it is older than "
+            f"dryad_io.cpp and could not be rebuilt (make -C {native_dir})")
+    for name, (restype, argtypes) in _SIGNATURES.items():
+        fn = getattr(lib, name)
+        fn.restype, fn.argtypes = restype, argtypes
+    return lib
 
 
 def _load() -> Optional[ctypes.CDLL]:
-    global _lib, _tried
+    global _lib, _tried, _error
     with _lock:
-        if _lib is not None or _tried:
-            return _lib
-        _tried = True
-        if not os.path.exists(_SO):
+        if not _tried:
+            _tried = True
             try:
-                subprocess.run(["make", "-C", _NATIVE_DIR, "-s"],
-                               check=True, capture_output=True, timeout=120)
-            except Exception:
-                return None
-        try:
-            lib = ctypes.CDLL(_SO)
-        except OSError:
-            return None
-        lib.dryad_pack_lines.restype = ctypes.c_int64
-        lib.dryad_pack_lines.argtypes = [
-            ctypes.c_void_p, ctypes.c_int64, ctypes.c_int64,
-            ctypes.c_void_p, ctypes.c_void_p, ctypes.c_int64]
-        lib.dryad_pack_bytes.restype = ctypes.c_int64
-        lib.dryad_pack_bytes.argtypes = [
-            ctypes.POINTER(ctypes.c_void_p), ctypes.c_void_p,
-            ctypes.c_int64, ctypes.c_int64, ctypes.c_void_p,
-            ctypes.c_void_p, ctypes.c_int64]
-        lib.dryad_file_jobs.restype = ctypes.c_int64
-        lib.dryad_file_jobs.argtypes = [
-            ctypes.POINTER(ctypes.c_char_p), ctypes.c_int64,
-            ctypes.POINTER(ctypes.c_void_p), ctypes.c_void_p,
-            ctypes.c_void_p, ctypes.c_int32, ctypes.c_int32]
-        lib.dryad_fingerprint.restype = ctypes.c_uint64
-        lib.dryad_fingerprint.argtypes = [ctypes.c_void_p, ctypes.c_int64]
-        lib.dryad_compact_rows.restype = ctypes.c_int64
-        lib.dryad_compact_rows.argtypes = [
-            ctypes.c_void_p, ctypes.c_void_p, ctypes.c_int64,
-            ctypes.c_int64, ctypes.c_void_p, ctypes.c_void_p]
-        lib.dryad_fingerprint_seed.restype = ctypes.c_uint64
-        lib.dryad_fingerprint_seed.argtypes = [
-            ctypes.c_void_p, ctypes.c_int64, ctypes.c_uint64]
-        _lib = lib
+                _lib = _open_library(_NATIVE_DIR)
+            except Exception as e:      # raised again on every later call
+                _error = e
+        if _error is not None:
+            raise _error
         return _lib
 
 
@@ -253,32 +268,116 @@ def _fnv_py(data: bytes, seed: int = _FNV_BASIS) -> int:
     return h
 
 
-def fingerprint(buf) -> int:
-    """64-bit FNV-1a.  The Python fallback computes the SAME function as
-    the native path (a fallback must never change the digest — the store
-    records fnv64 checksums that any environment must be able to verify)."""
-    lib = _load()
-    arr = np.ascontiguousarray(np.frombuffer(buf, np.uint8) if
-                               isinstance(buf, (bytes, bytearray)) else buf)
-    if lib is None:
-        return _fnv_py(arr.tobytes())
-    return int(lib.dryad_fingerprint(
-        arr.ctypes.data_as(ctypes.c_void_p), arr.nbytes))
-
-
 def checksum_segments(segments: Sequence[np.ndarray]) -> int:
-    """Chained fnv64 over a partition's segment list (no concatenation):
-    store integrity checksums (the role of the reference's channel
-    fingerprints, classlib fingerprint.cpp)."""
+    """The store's digest in its first form (manifest ``fnv64``): ONE
+    64-bit FNV-1a chained over a partition's segment list, byte i waiting
+    for byte i - 1.  Kept for the stores that were written with it; the
+    Python fallback computes the SAME function (a fallback must never
+    change a digest that any environment has to be able to verify)."""
     lib = _load()
     h = _FNV_BASIS
     for s in segments:
         s = np.ascontiguousarray(s)
-        view = s.view(np.uint8).reshape(-1)
         if lib is None:
-            h = _fnv_py(view.tobytes(), h)
+            h = _fnv_py(s.tobytes(), h)
         else:
-            h = int(lib.dryad_fingerprint_seed(
-                view.ctypes.data_as(ctypes.c_void_p), view.nbytes,
-                ctypes.c_uint64(h)))
+            h = int(lib.dryad_fingerprint_seed(s.ctypes.data, s.nbytes, h))
     return h
+
+
+def _fnv_words(words: Sequence[int]) -> int:
+    """FNV-1a over 64-bit digests as 8-byte little-endian words."""
+    return _fnv_py(np.asarray(words, "<u8").tobytes())
+
+
+def _digest_parts_np(parts: Sequence[Sequence[np.ndarray]],
+                     leaf_nbytes: Sequence[Sequence[int]], block: int
+                     ) -> Tuple[List[int], List[List[int]], Dict[str, int]]:
+    """``digest_parts`` without the library: every block of every leaf of
+    every partition side by side in one padded [blocks, block] matrix, one
+    numpy step a byte position (the blocks are independent, so the step is
+    a vector one)."""
+    rows: List[np.ndarray] = []         # one block each
+    shape: List[List[int]] = []         # blocks a leaf, a partition
+    for segs, sizes in zip(parts, leaf_nbytes):
+        flat = np.concatenate(
+            [np.ascontiguousarray(s).reshape(-1).view(np.uint8)
+             for s in segs] + [np.empty(0, np.uint8)])
+        off, counts = 0, []
+        for n in sizes:
+            leaf = flat[off:off + n]
+            rows.extend(leaf[i:i + block] for i in range(0, n, block))
+            counts.append(-(-n // block))
+            off += n
+        shape.append(counts)
+    lens = np.asarray([r.size for r in rows], np.int64)
+    padded = np.zeros((len(rows), int(lens.max(initial=0))), np.uint8)
+    for i, r in enumerate(rows):
+        padded[i, :r.size] = r
+    h = np.full(len(rows), _FNV_BASIS, np.uint64)
+    prime = np.uint64(_FNV_PRIME)
+    for i in range(padded.shape[1]):
+        live = lens > i
+        h[live] = (h[live] ^ padded[live, i]) * prime
+    digests, at = h.tolist(), 0
+    leaf_out: List[List[int]] = []
+    for counts in shape:
+        leaves = []
+        for c in counts:
+            leaves.append(_fnv_words(digests[at:at + c]))
+            at += c
+        leaf_out.append(leaves)
+    return ([_fnv_words(l) for l in leaf_out], leaf_out,
+            {"blocks": len(rows), "threads": 1})
+
+
+def digest_parts(parts: Sequence[Sequence[np.ndarray]],
+                 leaf_nbytes: Sequence[Sequence[int]], block: int
+                 ) -> Tuple[List[int], List[List[int]], Dict[str, int]]:
+    """The store's digest as independent blocks (manifest
+    ``fnv64-blocks``), for every partition of a read or a write in ONE
+    native call: ``(partition digests, leaf digests a partition,
+    {"blocks", "threads"})``.
+
+    ``parts[p]`` are partition p's bytes as contiguous arrays cut
+    anywhere — the chunks a column came off the device in, an array a
+    column, one blob — and ``leaf_nbytes[p]`` the byte length of each of
+    its leaves in file order.  A leaf is cut into blocks of ``block``
+    bytes (the last one short), each digested byte-wise by FNV-1a from
+    the basis; a leaf's digest is FNV-1a over its block digests as
+    8-byte little-endian words, a partition's the same over its leaf
+    digests.  The blocks run several in lockstep a worker on a pool sized
+    from the cores the process may use (native/dryad_io.cpp); a few
+    blocks start no thread.  The numpy form computes the same function."""
+    if block < 1:
+        raise ValueError(f"digest block of {block} bytes")
+    keep = [[np.ascontiguousarray(s) for s in segs] for segs in parts]
+    for p, (segs, sizes) in enumerate(zip(keep, leaf_nbytes)):
+        have, want = sum(s.nbytes for s in segs), sum(sizes)
+        if have != want:
+            raise ValueError(f"partition {p}: {have} bytes handed over, "
+                             f"its leaves hold {want}")
+    lib = _load()
+    if lib is None:
+        return _digest_parts_np(keep, leaf_nbytes, block)
+    flat = [s for segs in keep for s in segs]
+    seg_ptrs = (ctypes.c_void_p * len(flat))(*[s.ctypes.data for s in flat])
+    seg_lens = np.asarray([s.nbytes for s in flat], np.int64)
+    seg_offs = np.cumsum([0] + [len(segs) for segs in keep], dtype=np.int64)
+    leaf_lens = np.asarray([n for sizes in leaf_nbytes for n in sizes],
+                           np.int64)
+    leaf_offs = np.cumsum([0] + [len(sizes) for sizes in leaf_nbytes],
+                          dtype=np.int64)
+    leaf_out = np.empty(leaf_lens.size, np.uint64)
+    part_out = np.empty(len(keep), np.uint64)
+    stats = np.zeros(2, np.int64)
+    rc = lib.dryad_digest_parts(
+        seg_ptrs, seg_lens.ctypes.data, seg_offs.ctypes.data,
+        leaf_lens.ctypes.data, leaf_offs.ctypes.data, len(keep), block,
+        leaf_out.ctypes.data, part_out.ctypes.data, stats.ctypes.data)
+    if rc != 0:
+        raise ValueError(f"native digest refused its input (rc {rc})")
+    leaves = leaf_out.tolist()
+    return (part_out.tolist(),
+            [leaves[a:b] for a, b in zip(leaf_offs[:-1], leaf_offs[1:])],
+            {"blocks": int(stats[0]), "threads": int(stats[1])})

@@ -211,7 +211,8 @@ def _write_partitions(out_path: str, schema, part_chunks, part_ids,
             "cluster parallel output to s3:// is not supported (no "
             "atomic multi-object commit across writers); use a shared "
             "filesystem or hdfs:// target")
-    from dryad_tpu.io.store import chunk_segments, segments_blob
+    from dryad_tpu.io.store import (chunk_segments, part_checksums,
+                                    segments_blob)
     if hdfs:
         from dryad_tpu.io.webhdfs import hdfs_client, hdfs_part_path
         hc, hpath = hdfs_client(out_path)
@@ -246,11 +247,14 @@ def _write_partitions(out_path: str, schema, part_chunks, part_ids,
             native.write_files([os.path.join(tmp, f"part-{g:05d}.bin")],
                                [segs], compress=(compression == "gzip"))
         my_counts.append(merged.n)
-        my_sums.append(native.checksum_segments(segs))
+        my_sums.append(int(part_checksums(schema, [merged.n], [segs])[0][0],
+                           16))
 
     # allgather (counts, checksums) — doubles as the write barrier.
     # uint32 lanes only: jax without x64 silently truncates 64-bit arrays,
-    # so the fnv64 checksum rides as (hi, lo) words
+    # so a partition's 64-bit digest rides as (hi, lo) words; its leaf
+    # digests do not ride, and the manifest records none (readers verify
+    # by the partition digest alone)
     sums = np.asarray(my_sums, np.uint64)
     arr = np.stack([np.asarray(my_counts, np.uint32),
                     (sums >> np.uint64(32)).astype(np.uint32),

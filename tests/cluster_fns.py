@@ -7,6 +7,14 @@ def double_v(cols):
     return dict(cols, v=cols["v"] * 2)
 
 
+def v_as_x(cols):
+    return {"x": cols["v"]}
+
+
+def v_doubled_as_y(cols):
+    return {"y": cols["v"] * 2}
+
+
 def poison_wide_lines(cols):
     """Deterministically raises for the partition whose packed string
     column is wider than 64 bytes (StringColumn.max_len is static, so

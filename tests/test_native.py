@@ -92,9 +92,19 @@ def test_read_missing_file_raises(tmp_path):
 
 
 def test_fingerprint_stable():
-    a = native.fingerprint(b"hello")
-    assert a == native.fingerprint(b"hello")
-    assert a != native.fingerprint(b"hellp")
+    def digest(data, block=4):
+        return native.digest_parts(
+            [[np.frombuffer(data, np.uint8)]], [[len(data)]], block)[0][0]
+    a = digest(b"hello")
+    assert a == digest(b"hello")
+    assert a != digest(b"hellp")
+    assert a != digest(b"hello", block=3)
+    # one block of one leaf: FNV-1a of the bytes, of that digest's eight
+    # bytes, and of that one's again
+    h = native._fnv_py(b"hello")
+    assert h == 0xa430d84680aabd0b            # the published test vector
+    assert digest(b"hello", block=8) == native._fnv_words(
+        [native._fnv_words([h])])
 
 
 def test_read_text_native(tmp_path):

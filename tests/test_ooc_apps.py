@@ -84,7 +84,31 @@ def test_kmeans_ooc_10x_budget(tmp_path):
         ctx, ctx.read_store_stream(pstore, chunk_rows=1 << 14), k,
         init, n_iters=ITERS)
     exp = kmeans.kmeans_numpy(pts, k, n_iters=ITERS, init_centers=init)
-    np.testing.assert_allclose(got, exp, rtol=2e-4, atol=2e-4)
+    # The tolerance, from the oracle's own float32 spread.  A mean of
+    # float32 rows agrees to 2e-4 whatever the order of summation; the
+    # ASSIGNMENT does not: the program takes the argmin of ||c||^2 - 2 p.c
+    # in float32 on the device, the oracle that of (p - c)^2 in float64,
+    # and a point whose two nearest centroids are nearer each other than
+    # float32 resolves those sums (a few eps x (||p||^2 + ||c||^2)) goes
+    # to either.  Each such point moves a coordinate of a centroid of n
+    # points by |p - c| / (n - 1): this data has one (margin 2.5e-6 in
+    # 1188, 0.02 eps), 1e-3 on two centroids, and every other coordinate
+    # agrees to 5e-7.  So: 2e-4, plus what the oracle's near-ties allow.
+    x = np.asarray(pts["x"], np.float64)
+    d = ((x[:, None, :] - exp[None, :, :]) ** 2).sum(-1)
+    two = np.argsort(d, axis=1)[:, :2]
+    near, far = np.take_along_axis(d, two, axis=1).T
+    eps = float(np.finfo(np.float32).eps)
+    ties = np.flatnonzero(far - near < 8 * eps * (
+        (x ** 2).sum(-1) + (exp ** 2).sum(-1).max()))
+    assert len(ties) <= 8           # else the data tests nothing
+    sizes = np.bincount(two[:, 0], minlength=k)
+    slack = np.zeros_like(exp)
+    for p in ties:
+        for j in two[p]:
+            slack[j] += np.abs(x[p] - exp[j]) / (sizes[j] - 1)
+    assert (np.abs(got - exp) <= 2e-4 + 2e-4 * np.abs(exp) + slack).all(), \
+        np.abs(got - exp).max()
 
 
 def test_streamed_do_while_cond_stops_early(tmp_path):

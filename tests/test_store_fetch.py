@@ -85,10 +85,10 @@ def _plain_write(path, pd, compression=None):
     segments = [_plain_segments(pd.batch, p, n) for p, n in enumerate(counts)]
     native.write_files([store._part_path(path, p) for p in range(pd.nparts)],
                        segments, compress=(compression == "gzip"))
-    meta = store.build_meta(
-        store.pdata_schema(pd), counts,
-        ["%016x" % native.checksum_segments(s) for s in segments],
-        compression=compression, capacity=pd.capacity)
+    schema = store.pdata_schema(pd)
+    sums, leaves, _ = store.part_checksums(schema, counts, segments)
+    meta = store.build_meta(schema, counts, sums, compression=compression,
+                            capacity=pd.capacity, leaf_checksums=leaves)
     with open(os.path.join(path, "meta.json"), "w") as f:
         json.dump(meta, f, indent=1)
     return segments
@@ -160,8 +160,9 @@ def test_append_store_equals_the_plain_path(tmp_path, compression):
     assert meta["counts"] == [5, 6, 7, 8] + [n for _, n in kept]
     for i, (p, n) in enumerate(kept):
         segs = _plain_segments(more.batch, p, n)
-        assert meta["checksums"][4 + i] \
-            == "%016x" % native.checksum_segments(segs)
+        sums, leaves, _ = store.part_checksums(meta["schema"], [n], [segs])
+        assert meta["checksums"][4 + i] == sums[0]
+        assert meta["leaf_checksums"][4 + i] == leaves[0]
         ref = str(tmp_path / f"ref{i}.bin")
         native.write_files([ref], [segs], compress=(compression == "gzip"))
         with open(ref, "rb") as fa, \

@@ -68,6 +68,10 @@ def test_cluster_stream_sort(cluster, store, data, tmp_path):
     meta = store_meta(out)
     assert meta["npartitions"] == 4  # one per device across the gang
     assert meta["partitioning"] == {"kind": "range", "keys": ["v"]}
+    # the allgather carries one digest a partition: the block form, no
+    # leaf digests, and the read below verifies by the partition's alone
+    assert meta["checksum_algo"] == "fnv64-blocks"
+    assert len(meta["checksums"]) == 4 and meta["leaf_checksums"] is None
     back = Context().from_store(out).collect()
     np.testing.assert_array_equal(np.asarray(back["v"]),
                                   np.sort(data["v"]))

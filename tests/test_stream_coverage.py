@@ -154,8 +154,10 @@ def test_cluster_stream_zip(cluster, store, data):
     pairing equals global row pairing — every x pairs its own 2x."""
     ctx = _cctx(cluster)
     sds = ctx.read_store_stream(store, chunk_rows=CHUNK)
-    a = sds.select(lambda c: {"x": c["v"]})
-    b = sds.select(lambda c: {"y": c["v"] * 2})
+    # module-level functions: a plan that ships to workers may hold no
+    # lambda (DTA014)
+    a = sds.select(cluster_fns.v_as_x)
+    b = sds.select(cluster_fns.v_doubled_as_y)
     z = a.zip_with(b).collect()
     assert len(z["x"]) == N
     np.testing.assert_array_equal(np.asarray(z["y"]),
