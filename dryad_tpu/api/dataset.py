@@ -611,24 +611,15 @@ class Context:
             return None
         try:
             from dryad_tpu.exec.autotune import pick_chunk_rows
-            from dryad_tpu.io.store import store_meta
-            meta = store_meta(store_path)
-            row_bytes = 0
-            lanes = 0
-            for spec in meta["schema"].values():
-                if spec["kind"] == "str":
-                    row_bytes += int(spec["max_len"]) + 4
-                    lanes += -(-int(spec["max_len"]) // 4) + 1
-                else:
-                    import numpy as np
-                    w = int(np.dtype(spec["dtype"]).itemsize)
-                    n_el = 1
-                    for d in spec.get("shape", ()):
-                        n_el *= int(d)
-                    row_bytes += w * n_el
-                    lanes += max(1, w // 4) * n_el
-            return pick_chunk_rows(row_bytes, self.config,
-                                   row_lanes=lanes)
+            from dryad_tpu.io.store import part_layout, store_meta
+            layout = part_layout(store_meta(store_path)["schema"])
+            # 32-bit lanes a row: a string's bytes pack four to a lane
+            lanes = sum(-(-leaf.row_bytes // 4) if leaf.str_part == 0
+                        else max(1, leaf.dtype.itemsize // 4)
+                        * (leaf.row_bytes // leaf.dtype.itemsize)
+                        for leaf in layout)
+            return pick_chunk_rows(sum(leaf.row_bytes for leaf in layout),
+                                   self.config, row_lanes=lanes)
         except Exception:
             return None   # sizing is a heuristic; never fail the query
 

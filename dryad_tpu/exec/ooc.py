@@ -259,33 +259,24 @@ class ChunkSource:
         read like every other store.  ``partitions`` restricts to the
         listed store partitions (the per-worker subset of a cluster
         streamed job)."""
-        from dryad_tpu.io.store import (_alloc_part_views, _part_path,
-                                        is_remote_store,
-                                        remote_read_part_views,
-                                        store_meta, verify_checksums)
-        from dryad_tpu import native
+        from dryad_tpu.io import store
 
-        meta = store_meta(path)
+        meta = store.store_meta(path)
         schema = meta["schema"]
         part_ids = (list(range(meta["npartitions"]))
                     if partitions is None else list(partitions))
 
         ranged_parts: set = set()
-        if (path.startswith("hdfs://")
-                and meta.get("compression") != "gzip"):
-            row_bytes = 0
-            for spec in schema.values():
-                if spec["kind"] == "str":
-                    row_bytes += int(spec["max_len"]) + 4
-                else:
-                    n_el = 1
-                    for d in spec.get("shape", ()):
-                        n_el *= int(d)
-                    row_bytes += np.dtype(spec["dtype"]).itemsize * n_el
+        if store.has_ranged_read(path, meta):
+            row_bytes = store.schema_row_bytes(schema)
             ranged_parts = {
                 p for p in part_ids
                 if meta["counts"][p] * row_bytes
                 >= ChunkSource.RANGED_STREAM_MIN_BYTES}
+
+        def read(p):
+            cols = store.read_parts(path, meta, [p])[1][0]
+            return {k: cols[k] for k in schema}
 
         def it():
             for p in part_ids:
@@ -293,12 +284,11 @@ class ChunkSource:
                 if p in ranged_parts:
                     # integrity trade documented above: too big to hold,
                     # so stream unverified ranged chunks
-                    from dryad_tpu.io.webhdfs import hdfs_part_chunks
-                    for cols, n in hdfs_part_chunks(path, meta, p,
-                                                    chunk_rows):
+                    for cols, n in store.iter_part_chunks(path, meta, p,
+                                                          chunk_rows):
                         yield HChunk(cols, n)
                     continue
-                if is_remote_store(path):
+                if store.is_remote_store(path):
                     # multi-request remote read: transient provider
                     # failures re-issue the whole partition with
                     # backoff (io/providers.retry_transient) instead of
@@ -309,20 +299,12 @@ class ChunkSource:
                     # past them (truncated streams, empty 200 bodies),
                     # so keep the stacked worst case bounded
                     from dryad_tpu.io.providers import retry_transient
-                    segs, cols = retry_transient(
-                        lambda p=p: remote_read_part_views(path, meta,
-                                                           p),
+                    cols = retry_transient(
+                        lambda p=p: read(p),
                         what=f"remote part {p} of {path}", retries=2)
                 else:
-                    segs, cols = _alloc_part_views(schema, cnt)
-                    native.read_files(
-                        [_part_path(path, p)], [segs],
-                        compress=(meta.get("compression") == "gzip"))
-                verify_checksums(path, meta, [segs], partitions=[p])
-                hc = {k: ((cols[k][1], cols[k][2])
-                          if cols[k][0] == "str" else cols[k][1])
-                      for k in schema}
-                whole = HChunk(hc, cnt)
+                    cols = read(p)
+                whole = HChunk(cols, cnt)
                 for s in range(0, cnt, chunk_rows):
                     yield _slice_hchunk(whole, s, min(s + chunk_rows, cnt))
 
@@ -873,9 +855,8 @@ def _sorted_bucket_chunks(schema, frags: List[HChunk],
 
 
 def _schema_row_bytes(schema) -> int:
-    # one row-width arithmetic repo-wide (io/store.schema_row_bytes ->
-    # analysis/domain); floored at 1 so an empty schema cannot zero the
-    # in-core byte estimate
+    # the store's row width (io/store.part_layout); floored at 1 so an
+    # empty schema cannot zero the in-core byte estimate
     from dryad_tpu.io.store import schema_row_bytes
     return max(schema_row_bytes(schema), 1)
 
@@ -1349,55 +1330,18 @@ def write_chunks_to_store(path: str, chunks: Iterable[HChunk],
     (``hdfs://`` targets commit the same way through the WebHDFS
     adapter's rename; each chunk uploads as soon as it is drained, so
     host memory stays O(chunk_rows) on the write side too)."""
-    from dryad_tpu import native
-
-    store_schema: Dict[str, Any] = {}
-    for k, spec in schema.items():
-        if spec["kind"] == "str":
-            store_schema[k] = {"kind": "str", "max_len": spec["max_len"]}
-        else:
-            store_schema[k] = {"kind": "dense", "dtype": spec["dtype"],
-                               "shape": list(spec.get("shape", ()))}
-    if path.startswith("hdfs://"):
-        from dryad_tpu.io.webhdfs import _write_chunks_hdfs
-        return _write_chunks_hdfs(path, chunks, store_schema,
-                                  partitioning=partitioning,
-                                  compression=compression)
     if path.startswith("s3://"):
         raise OOCError(
             "streamed writes to s3:// are not supported (no atomic "
             "multi-object commit for an unbounded chunk stream); "
             "to_store to a local or hdfs:// path instead")
-    from dryad_tpu.io.store import chunk_segments, part_checksums
+    from dryad_tpu.io.store import StoreWriter, store_schema
 
-    tmp = path + ".tmp"
-    os.makedirs(tmp, exist_ok=True)
-    counts: List[int] = []
-    checksums: List[str] = []
-    leaf_checksums: List[List[str]] = []
-    p = 0
+    writer = StoreWriter(path, store_schema(schema), partitioning,
+                         compression)
     for chunk in chunks:
-        segs = chunk_segments(store_schema, chunk.cols)
-        native.write_files([os.path.join(tmp, f"part-{p:05d}.bin")], [segs],
-                           compress=(compression == "gzip"))
-        sums, leaves, _ = part_checksums(store_schema, [chunk.n], [segs])
-        checksums += sums
-        leaf_checksums += leaves
-        counts.append(chunk.n)
-        p += 1
-    import json
-
-    from dryad_tpu.io.store import build_meta
-    meta = build_meta(store_schema, counts, checksums,
-                      partitioning=partitioning, compression=compression,
-                      leaf_checksums=leaf_checksums)
-    with open(os.path.join(tmp, "meta.json"), "w") as f:
-        json.dump(meta, f, indent=1)
-    if os.path.exists(path):
-        import shutil
-        shutil.rmtree(path)
-    os.rename(tmp, path)
-    return meta
+        writer.add_chunk(chunk.n, chunk.cols)
+    return writer.commit()
 
 
 # ---------------------------------------------------------------------------

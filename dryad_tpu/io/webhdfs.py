@@ -19,9 +19,10 @@ by HttpFS proxies):
   locality hints for the task farm (runtime/farm.py dispatches a task
   to a worker on a host that holds its input blocks);
 * bounded exponential-backoff retries on 5xx / connection errors;
-* the partitioned-store layout of io/store.py (part-NNNNN.bin +
-  meta.json) committed atomically via HDFS's rename (the same temp-dir
-  rename commit the local store uses, DrVertex.h:325-351).
+* the ``hdfs://`` byte target of io/store.py (``HdfsDir``: names and
+  bytes of part-NNNNN.bin + meta.json, committed atomically via HDFS's
+  rename — the same temp-dir rename commit the local store uses,
+  DrVertex.h:325-351); the format itself is io/store.py's alone.
 
 ``hdfs://namenode:port/path`` URIs address the WebHDFS endpoint
 ``http://namenode:port/webhdfs/v1/path``; io/store.py routes any
@@ -31,7 +32,6 @@ by HttpFS proxies):
 
 from __future__ import annotations
 
-import gzip
 import json
 import os
 import socket
@@ -39,13 +39,13 @@ import time
 import urllib.error
 import urllib.parse
 import urllib.request
+import uuid
 from typing import Any, Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
 __all__ = ["WebHdfsClient", "WebHdfsError", "parse_hdfs_url",
-           "hdfs_client", "hdfs_store_meta", "hdfs_write_store",
-           "hdfs_read_part_views", "hdfs_part_path",
+           "hdfs_client", "HdfsDir", "hdfs_part_path",
            "hdfs_preferred_hosts", "hdfs_provider"]
 
 # ranged-read piece size: the reference FileServer's 2 MB block
@@ -311,61 +311,11 @@ def hdfs_client(url: str) -> Tuple[WebHdfsClient, str]:
     return c, path
 
 
-def _resolve(url: str, client: Optional[WebHdfsClient]
-             ) -> Tuple[WebHdfsClient, str]:
-    """(client, path) — an explicitly-passed client wins over the
-    per-namenode cache."""
-    if client is not None:
-        return client, parse_hdfs_url(url)[1]
-    return hdfs_client(url)
-
-
-# -- partitioned-store layout (io/store.py format on HDFS) -------------------
+# -- the hdfs:// byte target of the partitioned store (io/store.py) ----------
 
 
 def hdfs_part_path(path: str, p: int) -> str:
     return path.rstrip("/") + f"/part-{p:05d}.bin"
-
-
-def hdfs_store_meta(url: str, client: Optional[WebHdfsClient] = None
-                    ) -> Dict[str, Any]:
-    c, path = _resolve(url, client)
-    return json.loads(c.read_all(path.rstrip("/") + "/meta.json"))
-
-
-def hdfs_write_store(url: str, pd, partitioning=None, compression=None,
-                     client: Optional[WebHdfsClient] = None) -> None:
-    """write_store for hdfs:// paths.  HDFS has an atomic rename, so the
-    commit is the same temp-dir rename the local store uses (parts +
-    meta under ``<path>.tmp-<nonce>``, then RENAME onto ``<path>``) —
-    a reader never observes a half-written store."""
-    import uuid
-
-    from dryad_tpu.io.store import (build_meta, fetch_part_segments,
-                                    part_checksums, pdata_schema,
-                                    segments_blob)
-
-    if compression not in (None, "gzip"):
-        raise ValueError(f"unknown compression {compression!r}")
-    c, path = _resolve(url, client)
-    path = path.rstrip("/")
-    counts = np.asarray(pd.counts)
-    schema = pdata_schema(pd)
-    tmp = path + ".tmp-" + uuid.uuid4().hex[:12]
-    c.mkdirs(tmp)
-    segments = []
-    for p, (segs, _, _) in enumerate(
-            fetch_part_segments(pd, schema, counts)):
-        segments.append(segs)
-        c.create(hdfs_part_path(tmp, p), segments_blob(segs, compression))
-    # digests of the UNCOMPRESSED segments, every partition in one call
-    checksums, leaf_checksums, _ = part_checksums(schema, counts, segments)
-    meta = build_meta(schema, counts.tolist(), checksums,
-                      partitioning=partitioning, compression=compression,
-                      capacity=pd.capacity, leaf_checksums=leaf_checksums)
-    c.create(tmp + "/meta.json", json.dumps(meta, indent=1).encode())
-    c.delete(path, recursive=True)   # False = nothing to remove
-    c.rename(tmp, path)
 
 
 def _fill_ranged(c: WebHdfsClient, path: str, segs: List[np.ndarray],
@@ -398,66 +348,6 @@ def _fill_ranged(c: WebHdfsClient, path: str, segs: List[np.ndarray],
         off += len(piece)
 
 
-def hdfs_read_part_views(url: str, meta: Dict[str, Any], p: int,
-                         client: Optional[WebHdfsClient] = None):
-    """(segments, column views) for one partition — the read_store /
-    ChunkSource building block (io/s3_store.s3_read_part_views shape).
-    Uncompressed parts fill their segments directly from ranged reads;
-    gzip parts are fetched whole (ranges of a gzip stream don't
-    decompress independently)."""
-    from dryad_tpu.io.store import _alloc_part_views
-
-    c, path = _resolve(url, client)
-    segs, cols = _alloc_part_views(meta["schema"], meta["counts"][p])
-    part = hdfs_part_path(path, p)
-    if meta.get("compression") == "gzip":
-        from dryad_tpu.io.store import fill_segments
-        fill_segments(segs, gzip.decompress(c.read_all(part)),
-                      f"hdfs part {part!r}")
-    else:
-        _fill_ranged(c, part, segs)
-    return segs, cols
-
-
-def _write_chunks_hdfs(url: str, chunks, schema: Dict[str, Any],
-                       partitioning=None, compression=None,
-                       client: Optional[WebHdfsClient] = None
-                       ) -> Dict[str, Any]:
-    """ooc.write_chunks_to_store for hdfs:// targets: one part file per
-    chunk uploaded as it is drained (O(chunk_rows) host memory), meta
-    written last, temp-dir rename commit."""
-    import uuid
-
-    from dryad_tpu.io.store import (build_meta, chunk_segments,
-                                    part_checksums, segments_blob)
-
-    if compression not in (None, "gzip"):
-        raise ValueError(f"unknown compression {compression!r}")
-    c, path = _resolve(url, client)
-    path = path.rstrip("/")
-    tmp = path + ".tmp-" + uuid.uuid4().hex[:12]
-    c.mkdirs(tmp)
-    counts: List[int] = []
-    checksums: List[str] = []
-    leaf_checksums: List[List[str]] = []
-    p = 0
-    for chunk in chunks:
-        segs = chunk_segments(schema, chunk.cols)
-        sums, leaves, _ = part_checksums(schema, [chunk.n], [segs])
-        checksums += sums
-        leaf_checksums += leaves
-        c.create(hdfs_part_path(tmp, p), segments_blob(segs, compression))
-        counts.append(chunk.n)
-        p += 1
-    meta = build_meta(schema, counts, checksums,
-                      partitioning=partitioning, compression=compression,
-                      leaf_checksums=leaf_checksums)
-    c.create(tmp + "/meta.json", json.dumps(meta, indent=1).encode())
-    c.delete(path, recursive=True)
-    c.rename(tmp, path)
-    return meta
-
-
 def _read_exact(c: WebHdfsClient, path: str, off: int, ln: int,
                 block: int = _RANGE_BLOCK) -> bytes:
     """Exactly ``ln`` bytes at ``off`` via bounded ranged reads (servers
@@ -474,96 +364,56 @@ def _read_exact(c: WebHdfsClient, path: str, off: int, ln: int,
     return b"".join(out)
 
 
-def hdfs_part_chunks(url: str, meta: Dict[str, Any], p: int,
-                     chunk_rows: int,
-                     client: Optional[WebHdfsClient] = None):
-    """Yield one partition's rows as (column dict, n) chunks of at most
-    ``chunk_rows`` rows, each fetched by PER-SEGMENT ranged reads — host
-    memory stays O(chunk_rows) even when the partition itself exceeds
-    RAM (the channelbufferhdfs.cpp:69-97 block-read pattern applied to
-    the columnar part layout: rows [s, e) of column segment j live at
-    one contiguous byte range, so a chunk is k ranges, k = segments).
+class HdfsDir:
+    """Names and bytes of one store in an ``hdfs://`` directory (io/store.py
+    ``_target``).  HDFS has an atomic rename, so the commit is the same
+    temp-dir rename the local store uses: parts + meta under a temp
+    directory (``<path>.tmp-<nonce>``; ``<path>.tmp`` where several
+    processes share it), then RENAME onto ``<path>`` — a reader never
+    observes a half-written store."""
 
-    Uncompressed parts only (a gzip stream has no independently
-    decompressible ranges — callers fall back to whole-part reads); the
-    store's per-partition checksums cover whole segments and are NOT
-    verifiable on this path."""
-    if meta.get("compression"):
-        raise WebHdfsError(
-            "hdfs_part_chunks streams uncompressed parts only")
-    c, path = _resolve(url, client)
-    schema = meta["schema"]
-    cnt = int(meta["counts"][p])
-    part = hdfs_part_path(path, p)
-    # segment layout in file order: sorted columns, strings as
-    # (data, lengths) — must match io/store.fetch_part_segments
-    layout: List[Tuple[str, Optional[int], Any, Tuple[int, ...], int, int]] \
-        = []   # (col, str_part, dtype, row_shape, row_bytes, base_off)
-    base = 0
-    for k in sorted(schema):
-        spec = schema[k]
-        if spec["kind"] == "str":
-            for part_i, (dt, tail) in enumerate(
-                    ((np.dtype(np.uint8), (int(spec["max_len"]),)),
-                     (np.dtype(np.int32), ()))):
-                rb = dt.itemsize
-                for d in tail:
-                    rb *= d
-                layout.append((k, part_i, dt, tail, rb, base))
-                base += cnt * rb
-        else:
-            dt = np.dtype(spec["dtype"])
-            tail = tuple(int(d) for d in spec.get("shape", ()))
-            rb = dt.itemsize
-            for d in tail:
-                rb *= d
-            layout.append((k, None, dt, tail, rb, base))
-            base += cnt * rb
-    import concurrent.futures
+    ranged = True
 
-    from dryad_tpu.io.providers import retry_transient
+    def __init__(self, url: str):
+        self.c, path = hdfs_client(url)
+        self.path = self.dir = path.rstrip("/")
 
-    def fetch(args, s, e):
-        _k, _sp, dt, tail, rb, base_off = args
-        # route MID-STREAM ranged reads through the provider
-        # retry/backoff path whole-partition reads already enjoy: the
-        # whole segment range re-issues from scratch (ranged GETs are
-        # idempotent), so one flaky datanode hop — an empty 200, a
-        # truncated body, a dropped connection past the per-request
-        # retries — costs a backoff, not a multi-hour streamed job
-        raw = retry_transient(
-            lambda: _read_exact(c, part, base_off + s * rb,
-                                (e - s) * rb),
-            what=f"hdfs ranged read {part!r}", retries=2)
-        # bytearray copy -> writable array (frombuffer over bytes
-        # would hand downstream kernels read-only buffers)
-        return np.frombuffer(bytearray(raw), dt).reshape((e - s,) + tail)
+    def read_meta(self) -> bytes:
+        return self.c.read_all(self.path + "/meta.json")
 
-    # a chunk's per-segment ranges are independent — fetch them in
-    # parallel (each costs a namenode redirect + datanode GET; serial
-    # fetches would be latency-bound, per-channel IO thread role)
-    with concurrent.futures.ThreadPoolExecutor(
-            max_workers=min(8, max(len(layout), 1))) as pool:
-        for s in range(0, cnt, chunk_rows):
-            e = min(s + chunk_rows, cnt)
-            arrs = list(pool.map(lambda a: fetch(a, s, e), layout))
-            cols: Dict[str, Any] = {}
-            str_parts: Dict[str, Dict[int, np.ndarray]] = {}
-            for (k, str_part, *_rest), arr in zip(layout, arrs):
-                if str_part is None:
-                    cols[k] = arr
-                else:
-                    str_parts.setdefault(k, {})[str_part] = arr
-            for k, parts in str_parts.items():
-                cols[k] = (parts[0], parts[1])
-            yield cols, e - s
+    def clear_stale(self) -> None:
+        self.c.delete(self.path + ".tmp", recursive=True)
+
+    def begin(self, shared: bool = False) -> None:
+        self.dir = self.path + (".tmp" if shared
+                                else ".tmp-" + uuid.uuid4().hex[:12])
+        self.c.mkdirs(self.dir)   # idempotent; shared writers may race
+
+    def put(self, p: int, data: bytes) -> None:
+        self.c.create(hdfs_part_path(self.dir, p), data)
+
+    def get(self, p: int) -> bytes:
+        return self.c.read_all(hdfs_part_path(self.dir, p))
+
+    def fill(self, p: int, segs: List[np.ndarray]) -> None:
+        _fill_ranged(self.c, hdfs_part_path(self.dir, p), segs)
+
+    def get_range(self, p: int, off: int, ln: int) -> bytes:
+        return _read_exact(self.c, hdfs_part_path(self.dir, p), off, ln)
+
+    def what(self, p: int) -> str:
+        return f"hdfs part {hdfs_part_path(self.dir, p)!r}"
+
+    def commit(self, manifest: bytes) -> None:
+        self.c.create(self.dir + "/meta.json", manifest)
+        self.c.delete(self.path, recursive=True)   # False = nothing to remove
+        self.c.rename(self.dir, self.path)
 
 
 # -- block locality ----------------------------------------------------------
 
 
-def hdfs_preferred_hosts(url: str, partitions: Sequence[int],
-                         client: Optional[WebHdfsClient] = None
+def hdfs_preferred_hosts(url: str, partitions: Sequence[int]
                          ) -> List[str]:
     """Ordered locality hints for the given store partitions: hosts
     holding more of the partitions' block bytes first (the reference's
@@ -573,7 +423,7 @@ def hdfs_preferred_hosts(url: str, partitions: Sequence[int],
     degrades to a no-op hint, never an error."""
     import concurrent.futures
 
-    c, path = _resolve(url, client)
+    c, path = hdfs_client(url)
     parts = list(partitions)
     # one namenode round trip per partition — run them concurrently so
     # building a big store's farm specs isn't serialized on RTTs

@@ -9,8 +9,6 @@ and range-bounds sampling (DryadLinqSampler.cs:42 role).
 
 from __future__ import annotations
 
-import json
-import os
 from typing import Any, Dict, List, Optional, Tuple
 
 import numpy as np
@@ -200,95 +198,46 @@ def _write_partitions(out_path: str, schema, part_chunks, part_ids,
     reference's per-vertex HDFS output writers (DrHdfsClient.cpp write
     side, channelbufferhdfs.cpp)."""
     import jax
-    from dryad_tpu import native
     from dryad_tpu.exec import ooc
+    from dryad_tpu.io import store
 
     if compression not in (None, "gzip"):
         raise StreamJobError(f"unknown compression {compression!r}")
-    hdfs = out_path.startswith("hdfs://")
     if out_path.startswith("s3://"):
         raise StreamJobError(
             "cluster parallel output to s3:// is not supported (no "
             "atomic multi-object commit across writers); use a shared "
             "filesystem or hdfs:// target")
-    from dryad_tpu.io.store import (chunk_segments, part_checksums,
-                                    segments_blob)
-    if hdfs:
-        from dryad_tpu.io.webhdfs import hdfs_client, hdfs_part_path
-        hc, hpath = hdfs_client(out_path)
-        hpath = hpath.rstrip("/")
-        tmp = hpath + ".tmp"
-    else:
-        tmp = out_path + ".tmp"
     # clear any stale temp dir from a crashed previous job BEFORE anyone
     # uploads, behind a barrier — a leftover part-NNNNN.bin from a dead
     # run with more partitions would otherwise ride the rename into the
     # committed store.  Process 0 clears; the allgather is the fence.
     if jax.process_index() == 0:
-        if hdfs:
-            hc.delete(tmp, recursive=True)
-        elif os.path.exists(tmp):
-            import shutil
-            shutil.rmtree(tmp, ignore_errors=True)
+        store.clear_shared_temp(out_path)
     _host_allgather(np.zeros((1,), np.int32), mesh)
-    if hdfs:
-        hc.mkdirs(tmp)   # idempotent; every writer may race to create it
-    else:
-        os.makedirs(tmp, exist_ok=True)
-    my_counts: List[int] = []
-    my_sums: List[int] = []
+    writer = store.StoreWriter(out_path, store.store_schema(schema),
+                               partitioning, compression, capacity,
+                               shared=True)
     for g, chunks in zip(part_ids, part_chunks):
         merged = ooc._concat_hchunks(schema, list(chunks))
-        segs = chunk_segments(schema, merged.cols)
-        if hdfs:
-            hc.create(hdfs_part_path(tmp, g),
-                      segments_blob(segs, compression))
-        else:
-            native.write_files([os.path.join(tmp, f"part-{g:05d}.bin")],
-                               [segs], compress=(compression == "gzip"))
-        my_counts.append(merged.n)
-        my_sums.append(int(part_checksums(schema, [merged.n], [segs])[0][0],
-                           16))
+        writer.add_chunk(merged.n, merged.cols, g)
 
     # allgather (counts, checksums) — doubles as the write barrier.
     # uint32 lanes only: jax without x64 silently truncates 64-bit arrays,
     # so a partition's 64-bit digest rides as (hi, lo) words; its leaf
     # digests do not ride, and the manifest records none (readers verify
     # by the partition digest alone)
-    sums = np.asarray(my_sums, np.uint64)
-    arr = np.stack([np.asarray(my_counts, np.uint32),
+    sums = np.asarray([int(h, 16) for h in writer.checksums], np.uint64)
+    arr = np.stack([np.asarray(writer.counts, np.uint32),
                     (sums >> np.uint64(32)).astype(np.uint32),
                     sums.astype(np.uint32)], axis=1)
     allinfo = _host_allgather(arr, mesh)  # [nprocs, dpp, 3]
     if jax.process_index() == 0:
-        from dryad_tpu.io.store import build_meta
         flat = allinfo.reshape(-1, 3).astype(np.uint64)
-        counts = [int(x) for x in flat[:, 0]]
-        checksums = ["%016x" % int((h << np.uint64(32)) | l)
-                     for h, l in zip(flat[:, 1], flat[:, 2])]
-        store_schema = {}
-        for k, spec in schema.items():
-            if spec["kind"] == "str":
-                store_schema[k] = {"kind": "str",
-                                   "max_len": spec["max_len"]}
-            else:
-                store_schema[k] = {"kind": "dense", "dtype": spec["dtype"],
-                                   "shape": list(spec.get("shape", ()))}
-        meta = build_meta(store_schema, counts, checksums,
-                          partitioning=partitioning,
-                          compression=compression, capacity=capacity)
-        if hdfs:
-            hc.create(tmp + "/meta.json",
-                      json.dumps(meta, indent=1).encode())
-            hc.delete(hpath, recursive=True)
-            hc.rename(tmp, hpath)
-        else:
-            with open(os.path.join(tmp, "meta.json"), "w") as f:
-                json.dump(meta, f, indent=1)
-            if os.path.exists(out_path):
-                import shutil
-                shutil.rmtree(out_path)
-            os.rename(tmp, out_path)
+        writer.commit(
+            [int(x) for x in flat[:, 0]],
+            ["%016x" % int((h << np.uint64(32)) | l)
+             for h, l in zip(flat[:, 1], flat[:, 2])])
     # post-commit barrier so no worker reports success (or starts the next
     # job's waves) before the rename happened
     _host_allgather(np.zeros((1,), np.int32), mesh)

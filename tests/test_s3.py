@@ -19,7 +19,7 @@ import pytest
 
 from dryad_tpu import Context
 from dryad_tpu.io.s3 import S3Client, S3Config, S3Error, sign_v4
-from dryad_tpu.io.s3_store import s3_read_part_segments, s3_store_meta
+from dryad_tpu.io.store import read_parts, store_meta
 
 ACCESS, SECRET = "AKIDTEST", "s3cr3t-key"
 
@@ -356,17 +356,17 @@ def test_s3_store_overwrite_is_atomic_at_meta(s3env, tmp_path):
     url = "s3://bkt/over/store"
     a = np.arange(40, dtype=np.int32)
     ctx.from_columns({"x": a}).to_store(url)
-    old_meta = s3_store_meta(url)
+    old_meta = store_meta(url)
     assert old_meta.get("generation")
 
     b = np.arange(100, 160, dtype=np.int32)
     ctx.from_columns({"x": b}).to_store(url)
-    new_meta = s3_store_meta(url)
+    new_meta = store_meta(url)
     assert new_meta["generation"] != old_meta["generation"]
 
     # a reader that captured the OLD meta before the overwrite still
     # decodes the OLD data, checksum-clean
-    segs = s3_read_part_segments(url, old_meta, 0)
+    segs = read_parts(url, old_meta, [0])[0][0]
     got = np.concatenate([np.asarray(s).reshape(-1).view(np.int32)
                           for s in segs[:1]])
     assert set(got.tolist()) <= set(a.tolist())
@@ -387,7 +387,7 @@ def test_s3_store_overwrite_is_atomic_at_meta(s3env, tmp_path):
     bucket, prefix = parse_s3_url(url)
     keys = [k for k, _ in s3_client().list_objects(bucket, prefix)]
     gens = {k.split("/")[-2] for k in keys if k.endswith(".bin")}
-    g3 = s3_store_meta(url)["generation"]
+    g3 = store_meta(url)["generation"]
     assert g3 in gens and new_meta["generation"] in gens
     assert old_meta["generation"] not in gens
 
