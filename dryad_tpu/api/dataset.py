@@ -581,9 +581,12 @@ class Context:
         return Dataset(self, node)
 
     def read_store_stream(self, path: str,
-                          chunk_rows: int | None = None):
+                          chunk_rows: int | None = None,
+                          columns: Sequence[str] | None = None):
         """Stream a persisted store through the plain Dataset API —
         the >HBM path (1 TB TeraSort north star, BASELINE.md config 2).
+        ``columns``: stream the named stored columns only (in-process; a
+        cluster's workers stream whole partitions).
 
         On a cluster Context this is an ORDINARY Dataset too: the query
         plans through the normal lowering (exchanges included) and the
@@ -600,7 +603,7 @@ class Context:
                             _npartitions=self.nparts)
             return Dataset(self, node)
         from dryad_tpu.exec.ooc import ChunkSource
-        cs = ChunkSource.from_store(path, cr)
+        cs = ChunkSource.from_store(path, cr, columns=columns)
         return self.from_stream(cs)
 
     def _auto_chunk_rows(self, store_path: str) -> int | None:
@@ -644,13 +647,22 @@ class Context:
         from dryad_tpu.io.providers import open_uri
         return open_uri(self, uri, **kw)
 
-    def from_store(self, path: str, capacity: int | None = None) -> "Dataset":
+    def from_store(self, path: str, capacity: int | None = None,
+                   columns: Sequence[str] | None = None) -> "Dataset":
         """Load a persisted dataset (FromStore, DryadLinqContext.cs:1176).
         Persisted partitioning metadata is honored for shuffle elimination
         (AssumeHashPartition parity, DryadLinqQueryable.cs:3408).
         ``path`` may be local, ``s3://``, or ``hdfs://`` (io/store.py
         scheme dispatch); the same goes for ``read_store_stream`` and
-        ``to_store``."""
+        ``to_store``.
+
+        ``columns`` (stored column names; None = all): only those columns
+        are fetched from the store, verified (by their leaf digests),
+        stacked and put on the device (``io/store.read_parts``), in-core
+        and streamed; a partitioning claim survives only if all its keys
+        are among them.  Left whole: a cluster's ``DeferredSource`` —
+        its workers read whole partitions, and what a query does not name
+        is pruned on the device as before."""
         from dryad_tpu.io.store import read_store, store_meta
         # the read is eager, so it is a query of its own in the trace
         with trace.span("from_store", "query", source=path):
@@ -660,13 +672,15 @@ class Context:
                     and sum(meta.get("counts", [])) >= auto):
                 # size-threshold streaming: a big store never tries to fit
                 # in HBM (VERDICT r2 next-round item 1)
-                return self.read_store_stream(path)
+                return self.read_store_stream(path, columns=columns)
             pmeta = meta.get("partitioning", {"kind": "none"})
             part = E.Partitioning(pmeta.get("kind", "none"),
                                   tuple(pmeta.get("keys", ())))
             # re-blocking across a different mesh size destroys hash
-            # placement
-            if meta["npartitions"] != self.nparts:
+            # placement; so does leaving a key of it unread
+            if meta["npartitions"] != self.nparts or (
+                    columns is not None and self.cluster is None
+                    and not set(part.keys) <= set(columns)):
                 part = E.Partitioning.none()
             if self.cluster is not None:
                 from dryad_tpu.runtime.sources import (DeferredSource,
@@ -678,7 +692,8 @@ class Context:
                                 _partitioning=part)
                 return Dataset(self, node)
             pdata = read_store(path, self.mesh, capacity=capacity,
-                               verify=self.config.store_verify_checksums)
+                               verify=self.config.store_verify_checksums,
+                               columns=columns)
             return self.from_pdata(pdata, partitioning=part)
 
     # -- iteration ---------------------------------------------------------

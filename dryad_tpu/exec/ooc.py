@@ -244,7 +244,8 @@ class ChunkSource:
 
     @staticmethod
     def from_store(path: str, chunk_rows: int,
-                   partitions: Optional[Sequence[int]] = None
+                   partitions: Optional[Sequence[int]] = None,
+                   columns: Optional[Sequence[str]] = None
                    ) -> "ChunkSource":
         """Stream a persisted store (io/store.py layout) partition by
         partition, slicing each into chunks.  Individual partitions must fit
@@ -258,11 +259,13 @@ class ChunkSource:
         so partitions BELOW the threshold take the whole-part verified
         read like every other store.  ``partitions`` restricts to the
         listed store partitions (the per-worker subset of a cluster
-        streamed job)."""
+        streamed job); ``columns`` to the named stored columns
+        (``io/store.read_parts``: only their leaves are read and verified,
+        and the stream's schema holds only them)."""
         from dryad_tpu.io import store
 
         meta = store.store_meta(path)
-        schema = meta["schema"]
+        schema = store.kept_schema(meta["schema"], columns)
         part_ids = (list(range(meta["npartitions"]))
                     if partitions is None else list(partitions))
 
@@ -275,7 +278,7 @@ class ChunkSource:
                 >= ChunkSource.RANGED_STREAM_MIN_BYTES}
 
         def read(p):
-            cols = store.read_parts(path, meta, [p])[1][0]
+            cols = store.read_parts(path, meta, [p], columns=columns)[1][0]
             return {k: cols[k] for k in schema}
 
         def it():
@@ -284,8 +287,8 @@ class ChunkSource:
                 if p in ranged_parts:
                     # integrity trade documented above: too big to hold,
                     # so stream unverified ranged chunks
-                    for cols, n in store.iter_part_chunks(path, meta, p,
-                                                          chunk_rows):
+                    for cols, n in store.iter_part_chunks(
+                            path, meta, p, chunk_rows, columns):
                         yield HChunk(cols, n)
                     continue
                 if store.is_remote_store(path):
@@ -310,9 +313,12 @@ class ChunkSource:
 
         src = ChunkSource(it, schema, chunk_rows)
         import hashlib
-        src.fingerprint = hashlib.sha256(repr(
-            ("store", path, meta.get("counts"), meta.get("checksums"),
-             sorted(part_ids))).encode()).hexdigest()
+        ident = ("store", path, meta.get("counts"), meta.get("checksums"),
+                 sorted(part_ids))
+        if len(schema) < len(meta["schema"]):
+            # two column sets of one store are two sources
+            ident += (tuple(schema),)
+        src.fingerprint = hashlib.sha256(repr(ident).encode()).hexdigest()
         return src
 
     @staticmethod

@@ -85,12 +85,12 @@ class _DeltaCatalog(Catalog):
         self._table = table
         self._partitions = list(partitions)
 
-    def dataset(self, ctx, name: str, loader=None):
+    def dataset(self, ctx, name: str, loader=None, columns=None):
         # ``loader`` (the service's scan-share hook) is ignored on
         # purpose: a delta scan reads an explicit partition subset, so
         # a shared full-table PData would be the WRONG rows
         if name != self._table:
-            return super().dataset(ctx, name)
+            return super().dataset(ctx, name, columns=columns)
         from dryad_tpu.api.dataset import Dataset
         from dryad_tpu.io.store import read_store, store_meta
         t = self.tables[name]
@@ -108,7 +108,8 @@ class _DeltaCatalog(Catalog):
         pd = read_store(t.path, ctx.mesh, capacity=cap,
                         partitions=self._partitions,
                         verify=getattr(ctx.config,
-                                       "store_verify_checksums", True))
+                                       "store_verify_checksums", True),
+                        columns=columns)
         ds = ctx.from_pdata(pd)
         assert isinstance(ds, Dataset)
         return ds, ds.node.data

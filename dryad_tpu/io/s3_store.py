@@ -93,6 +93,14 @@ class S3Prefix:
         return self.c.get_object(self.bucket,
                                  _part_key(self.prefix, p, self.gen))
 
+    def get_range(self, p: int, off: int, ln: int) -> bytes:
+        """``ln`` bytes of a part at ``off`` (one ranged GET) — a read of
+        some columns fetches a leaf this way.  ``ranged`` stays False: a
+        whole part is still one object, one GET."""
+        return self.c.get_object(self.bucket,
+                                 _part_key(self.prefix, p, self.gen),
+                                 rng=(off, off + ln - 1))
+
     def what(self, p: int) -> str:
         return "s3 object"
 

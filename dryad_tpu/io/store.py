@@ -72,7 +72,8 @@ from dryad_tpu.obs import trace
 __all__ = ["write_store", "read_store", "store_meta", "build_meta",
            "schema_row_bytes", "StoreIntegrityError", "is_remote_store",
            "append_store", "store_generation", "parts_since",
-           "part_checksums", "part_layout", "store_schema", "StoreWriter",
+           "part_checksums", "part_layout", "store_schema", "kept_schema",
+           "StoreWriter",
            "read_parts", "iter_part_chunks", "has_ranged_read",
            "clear_shared_temp"]
 
@@ -195,11 +196,37 @@ def chunk_segments(schema: Dict[str, Any],
             for leaf in part_layout(schema)]
 
 
-def _alloc_part_views(schema, n: int) -> Tuple[List[np.ndarray],
-                                               Dict[str, Any]]:
-    """Allocate one array a leaf for a partition's n valid rows, in file
-    order; return (ordered segment list, ``_columns`` of them)."""
+def kept_schema(schema: Dict[str, Any],
+                columns: Optional[Sequence[str]]) -> Dict[str, Any]:
+    """The schema of a read of ``columns`` (stored column names; None =
+    all): the named columns' specs in the manifest's column order, so that
+    a source's column order is a function of the manifest and the names,
+    not of how a caller listed them — the schema itself where that is every
+    column.  A name the schema lacks and an empty list are refused."""
+    if columns is None:
+        return schema
+    names = set(columns)
+    if not names:
+        raise ValueError("a read of no column: name at least one, or pass "
+                         "columns=None for all of them")
+    missing = sorted(names - set(schema))
+    if missing:
+        raise KeyError(f"the store has no column "
+                       f"{', '.join(map(repr, missing))} (it has "
+                       f"{', '.join(sorted(schema))})")
+    return schema if len(names) == len(schema) \
+        else {k: spec for k, spec in schema.items() if k in names}
+
+
+def _alloc_part_views(schema, n: int,
+                      held: Optional[Sequence[int]] = None
+                      ) -> Tuple[List[np.ndarray], Dict[str, Any]]:
+    """Allocate one array a leaf for a partition's n valid rows — every
+    leaf of the layout, or the leaves ``held`` (indices, ascending) — in
+    file order; return (ordered segment list, ``_columns`` of them)."""
     layout = part_layout(schema)
+    if held is not None:
+        layout = [layout[j] for j in held]
     segs = [np.empty((n,) + leaf.row_shape, leaf.dtype) for leaf in layout]
     return segs, _columns(layout, segs)
 
@@ -328,6 +355,22 @@ def _fill_parts(target, part_ids, segments, compression) -> None:
         fill_segments(segs, data, target.what(p))
 
 
+def _fill_ranges(target, part_ids, segments, ranges) -> None:
+    """Preallocated segments filled from byte ranges of their
+    (uncompressed) partitions, ``ranges[i][j]`` = (offset, nbytes) of
+    ``segments[i][j]``: locally ONE native call for all of them, a remote
+    partition one ranged request a segment."""
+    if isinstance(target, _LocalDir):
+        native.read_files([target.part(p) for p in part_ids], segments,
+                          offsets=[[off for off, _ in rs] for rs in ranges])
+        return
+    for p, segs, rs in zip(part_ids, segments, ranges):
+        for seg, (off, nb) in zip(segs, rs):
+            if nb:
+                fill_segments([seg], target.get_range(p, off, nb),
+                              target.what(p))
+
+
 def has_ranged_read(path: str, meta: Dict[str, Any]) -> bool:
     """Whether ``iter_part_chunks`` can stream this store's partitions:
     the target reads byte ranges (``hdfs://`` today) and the parts are
@@ -437,7 +480,8 @@ def parts_since(meta: Dict[str, Any], watermark: int) -> List[int]:
 
 
 def part_checksums(schema: Dict[str, Any], counts, segments,
-                   meta: Optional[Dict[str, Any]] = None
+                   meta: Optional[Dict[str, Any]] = None,
+                   leaves: Optional[Sequence[int]] = None
                    ) -> Tuple[List[str], Optional[List[List[str]]],
                               Dict[str, Any]]:
     """The ONE digest of every store writer and verifier:
@@ -448,18 +492,27 @@ def part_checksums(schema: Dict[str, Any], counts, segments,
     store not yet written.  ``leaf_checksums`` is None in the chained form,
     which has none; ``info`` says what ran (``algo``, ``blocks``,
     ``threads``) and rides on the ``store.verify`` / ``store.checksum``
-    spans."""
+    spans.
+
+    ``leaves`` (the block form only): the segments hold just these leaves
+    of the layout (indices, ascending).  Their leaf digests come out as
+    they are recorded — a leaf's digest does not depend on its neighbours —
+    and the partition digests, taken over a subset, compare with
+    nothing."""
     form = checksum_form(meta)
     if form["checksum_algo"] == "fnv64":
         return (["%016x" % native.checksum_segments(segs)
                  for segs in segments], None,
                 {"algo": "fnv64", "blocks": len(segments), "threads": 1})
-    sums, leaves, ran = native.digest_parts(
-        segments, [[leaf.nbytes for leaf in part_layout(schema, int(n))]
-                   for n in counts],
-        form["checksum_block"])
+    sizes = []
+    for n in counts:
+        layout = part_layout(schema, int(n))
+        sizes.append([layout[j].nbytes for j in
+                      (range(len(layout)) if leaves is None else leaves)])
+    sums, leaf_sums, ran = native.digest_parts(segments, sizes,
+                                               form["checksum_block"])
     return (["%016x" % h for h in sums],
-            [["%016x" % h for h in part] for part in leaves],
+            [["%016x" % h for h in part] for part in leaf_sums],
             {"algo": form["checksum_algo"], **ran})
 
 
@@ -687,83 +740,132 @@ def append_store(path: str, pd: PData) -> int:
 
 def verify_checksums(path: str, meta: Dict[str, Any],
                      segments: List[List[np.ndarray]],
-                     partitions: Optional[List[int]] = None
+                     partitions: Optional[List[int]] = None,
+                     leaves: Optional[Sequence[int]] = None
                      ) -> Optional[Dict[str, Any]]:
     """Compare freshly-read partition segments against the recorded
     checksums, in the form the manifest names and in ONE digest call for
     all of them; raise StoreIntegrityError naming the partition (and, where
     the manifest carries leaf digests, the column) on a mismatch.  Returns
     ``part_checksums``' info.  Stores written before format v3 carry no
-    checksums and are accepted as-is (None)."""
+    checksums and are accepted as-is (None).
+
+    ``leaves``: the segments hold these leaves of the layout only (a read
+    of some columns).  Each is compared with its ``leaf_checksums`` entry;
+    a manifest without them cannot vouch for a subset and is refused."""
     recorded = meta.get("checksums")
-    if not recorded:
+    if not recorded and leaves is None:
         return None
     parts = list(partitions if partitions is not None
                  else range(len(segments)))
     schema = meta["schema"]
     counts = [int(meta["counts"][p]) for p in parts]
     layout = part_layout(schema)
-    row_bytes = sum(leaf.row_bytes for leaf in layout)
+    held = list(range(len(layout))) if leaves is None else list(leaves)
+    row_bytes = sum(layout[j].row_bytes for j in held)
     for segs, p, n in zip(segments, parts, counts):
         have, want = _segments_nbytes([segs]), n * row_bytes
         if have != want:
             raise StoreIntegrityError(
                 f"partition {p} of {path}: {have} bytes read, the manifest's "
                 f"{n} rows are {want} — file truncated or tampered")
-    sums, leaves, ran = part_checksums(schema, counts, segments, meta)
-    rec_leaves = meta.get("leaf_checksums") if leaves is not None else None
+    rec_leaves = meta.get("leaf_checksums")
+    if leaves is not None and not rec_leaves:
+        raise ValueError(f"{path}: its manifest has no leaf digests, so "
+                         "some of its columns cannot be verified alone")
+    sums, leaf_sums, ran = part_checksums(schema, counts, segments, meta,
+                                          leaves)
     for i, p in enumerate(parts):
-        bad = [j for j, (got, rec) in enumerate(zip(leaves[i], rec_leaves[p]))
-               if got != rec] if rec_leaves else []
-        if sums[i] != recorded[p] or bad:
-            where = (f" (column {layout[bad[0]].column!r}, leaf {bad[0]})"
-                     if bad else "")
-            raise StoreIntegrityError(
-                f"partition {p} of {path}{where}: checksum {sums[i]} != "
-                f"recorded {recorded[p]} — file corrupted or tampered")
+        bad = next(((j, got, rec_leaves[p][j])
+                    for j, got in zip(held, leaf_sums[i])
+                    if got != rec_leaves[p][j]), None) \
+            if rec_leaves and leaf_sums else None
+        # a subset of the leaves has no partition digest to compare
+        if not bad and (leaves is not None or sums[i] == recorded[p]):
+            continue
+        where = (f" (column {layout[bad[0]].column!r}, leaf {bad[0]})"
+                 if bad else "")
+        got, rec = (sums[i], recorded[p]) if leaves is None else bad[1:]
+        raise StoreIntegrityError(
+            f"partition {p} of {path}{where}: checksum {got} != "
+            f"recorded {rec} — file corrupted or tampered")
     return ran
 
 
 def read_parts(path: str, meta: Dict[str, Any], part_ids: Sequence[int],
-               verify: bool = True
+               verify: bool = True,
+               columns: Optional[Sequence[str]] = None
                ) -> Tuple[List[List[np.ndarray]], List[Dict[str, Any]]]:
-    """The ONE whole-partition read: for the listed partitions, in order,
-    ``(segments, columns)`` — a partition's arrays in file order, and the
-    same arrays by column name (an array, or (data, lengths) of a string
-    column).  Allocate from the layout, fill through the byte target,
-    verify every byte in one digest call before returning."""
+    """The ONE partition read: for the listed partitions, in order,
+    ``(segments, columns)`` — the arrays that were read, in file order, and
+    the asked-for columns' arrays by name (an array, or (data, lengths) of a
+    string column).  Allocate from the layout, fill through the byte
+    target, verify every byte read in one digest call before returning.
+
+    ``columns`` (stored column names; None = all): only those columns'
+    leaves are allocated, filled — from their byte ranges of the partition
+    file, nothing between them touched — and verified, each by its entry
+    of the manifest's ``leaf_checksums``.  A store that cannot be read in
+    part is read whole, verified whole and its other columns dropped here:
+    a ``gzip`` store (ranges of a gzip stream do not decompress alone) and,
+    with ``verify`` on, a manifest without ``leaf_checksums`` (docs/
+    store_format.md, "Reading some columns").  Naming every column is the
+    whole read."""
     target = _target(path, meta)
     schema, compression = meta["schema"], meta.get("compression")
-    segments, columns = [], []
+    keep = kept_schema(schema, columns)
+    # the layout's leaves this read holds; None = all of them, the whole
+    # read.  What the manifest says decides, never a knob
+    held = None
+    if len(keep) < len(schema) and compression is None \
+            and (not verify or meta.get("leaf_checksums")):
+        held = [j for j, leaf in enumerate(part_layout(schema))
+                if leaf.column in keep]
+    row_bytes = schema_row_bytes(schema)
+    segments, out = [], []
     with trace.span("store.file_read", "io", files=len(part_ids)) as fsp:
         for p in part_ids:
-            segs, cols = _alloc_part_views(schema, meta["counts"][p])
+            n = meta["counts"][p]
+            segs, cols = _alloc_part_views(schema, n, held)
             segments.append(segs)
-            columns.append(cols)
+            out.append(cols)
             # a file cut short or grown is named as what it is, not as a
-            # failed read (a gzip file's size says nothing)
+            # failed read (a gzip file's size says nothing): it holds the
+            # whole layout's bytes, whichever of them are read
             if verify and compression is None \
                     and isinstance(target, _LocalDir):
-                have = os.path.getsize(target.part(p))
-                want = _segments_nbytes([segs])
+                have, want = os.path.getsize(target.part(p)), n * row_bytes
                 if have != want:
                     raise StoreIntegrityError(
                         f"partition {p} of {path}: the file holds "
                         f"{have} bytes, the manifest's "
                         f"{meta['counts'][p]} rows are {want} — "
                         "file truncated or tampered")
-        _fill_parts(target, part_ids, segments, compression)
+        if held is None:
+            _fill_parts(target, part_ids, segments, compression)
+        else:
+            layouts = [part_layout(schema, meta["counts"][p])
+                       for p in part_ids]
+            _fill_ranges(target, part_ids, segments,
+                         [[(layout[j].offset, layout[j].nbytes)
+                           for j in held] for layout in layouts])
         nbytes = _segments_nbytes(segments)
         fsp.set(bytes=nbytes)
     if verify:
         with trace.span("store.verify", "io", bytes=nbytes) as vsp:
             vsp.set(**(verify_checksums(path, meta, segments,
-                                        partitions=list(part_ids)) or {}))
-    return segments, columns
+                                        partitions=list(part_ids),
+                                        leaves=held) or {}))
+            if held is not None:
+                vsp.set(leaves=len(held) * len(part_ids))
+    if len(keep) < len(schema):
+        out = [{k: cols[k] for k in keep} for cols in out]
+    return segments, out
 
 
 def iter_part_chunks(path: str, meta: Dict[str, Any], p: int,
-                     chunk_rows: int):
+                     chunk_rows: int,
+                     columns: Optional[Sequence[str]] = None):
     """Yield one partition's rows as (columns, n) chunks of at most
     ``chunk_rows`` rows, each fetched by PER-LEAF ranged reads — host
     memory stays O(chunk_rows) even when the partition itself exceeds RAM
@@ -772,7 +874,8 @@ def iter_part_chunks(path: str, meta: Dict[str, Any], p: int,
     range, so a chunk is one range a leaf).
 
     ``has_ranged_read`` stores only; the store's digests cover whole
-    leaves and are NOT verifiable on this path."""
+    leaves and are NOT verifiable on this path.  ``columns``: the stream
+    takes those columns' leaves of the layout only."""
     import concurrent.futures
 
     from dryad_tpu.io.providers import retry_transient
@@ -782,7 +885,9 @@ def iter_part_chunks(path: str, meta: Dict[str, Any], p: int,
         raise IOError(f"{path}: iter_part_chunks streams uncompressed parts "
                       "of a target with ranged reads only")
     cnt = int(meta["counts"][p])
-    layout = part_layout(meta["schema"], cnt)
+    keep = kept_schema(meta["schema"], columns)
+    layout = [leaf for leaf in part_layout(meta["schema"], cnt)
+              if leaf.column in keep]
 
     def fetch(leaf: Leaf, s: int, e: int) -> np.ndarray:
         # route MID-STREAM ranged reads through the provider
@@ -813,7 +918,8 @@ def iter_part_chunks(path: str, meta: Dict[str, Any], p: int,
 
 def read_store(path: str, mesh, capacity: Optional[int] = None,
                partitions: Optional[List[int]] = None,
-               verify: bool = True) -> PData:
+               verify: bool = True,
+               columns: Optional[Sequence[str]] = None) -> PData:
     """Load a dataset store as sharded PData (FromStore,
     DryadLinqContext.cs:1176).
 
@@ -826,17 +932,28 @@ def read_store(path: str, mesh, capacity: Optional[int] = None,
 
     ``partitions`` reads only the listed store partitions (the per-task
     input granularity of the task farm — one vertex per partition file,
-    DrPartitionFile.cpp:607)."""
+    DrPartitionFile.cpp:607).
+
+    ``columns`` reads only the named stored columns (``read_parts``: their
+    leaves alone are fetched, verified, stacked and put on the device); the
+    PData holds them in the manifest's column order.  The ``store.read``
+    span says what that saved: ``columns`` of ``columns_stored``, ``bytes``
+    read of ``bytes_stored``."""
     meta = store_meta(path)
     part_ids = (list(range(meta["npartitions"])) if partitions is None
                 else list(partitions))
+    schema = kept_schema(meta["schema"], columns)
     with trace.span("store.read", "io", partitions=len(part_ids),
-                    columns=len(meta["schema"])) as sp:
+                    columns=len(schema),
+                    columns_stored=len(meta["schema"])) as sp:
         counts = [meta["counts"][p] for p in part_ids]
-        schema = meta["schema"]
         nparts = mesh.devices.size
-        segments, part_rows = read_parts(path, meta, part_ids, verify)
-        sp.set(bytes=_segments_nbytes(segments))
+        segments, part_rows = read_parts(path, meta, part_ids, verify,
+                                         columns)
+        sp.set(bytes=_segments_nbytes(segments),
+               bytes_stored=sum(int(n) for n in counts)
+               * schema_row_bytes(meta["schema"]))
+        del segments    # a store read whole for some columns: the others go
 
         if len(part_ids) == nparts:
             # verbatim per-partition load: placement-preserving

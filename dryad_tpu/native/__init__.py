@@ -35,6 +35,8 @@ _SIGNATURES = {
                                 _I64]),
     "dryad_file_jobs": (_I64, [ctypes.POINTER(ctypes.c_char_p), _I64,
                                ctypes.POINTER(_P), _P, _P, _I32, _I32]),
+    "dryad_read_ranges": (_I64, [ctypes.POINTER(ctypes.c_char_p), _I64,
+                                 ctypes.POINTER(_P), _P, _P, _P, _I32]),
     "dryad_compact_rows": (_I64, [_P, _P, _I64, _I64, _P, _P]),
     "dryad_fingerprint_seed": (_U64, [_P, _I64, _U64]),
     "dryad_digest_parts": (_I64, [ctypes.POINTER(_P), _P, _P, _P, _P, _I64,
@@ -163,32 +165,40 @@ def pack_bytes_list(items: Sequence[bytes], max_len: int, capacity: int
 
 
 def _file_jobs(paths: List[str], segments: List[List[np.ndarray]],
-               write: bool, nthreads: int = 8,
-               compress: bool = False) -> None:
+               write: bool, nthreads: int = 8, compress: bool = False,
+               offsets: Optional[List[List[int]]] = None) -> None:
     n = len(paths)
     if n == 0:
         return
+    if offsets is not None and (write or compress):
+        raise ValueError("file offsets go with a plain read only (ranges "
+                         "of a gzip stream do not decompress alone)")
     lib = _load()
     if lib is None:
         import gzip as _gz
 
         opener = (lambda p, m: _gz.open(p, m, compresslevel=1)) \
             if compress else open
-        for p, segs in zip(paths, segments):
+        for i, (p, segs) in enumerate(zip(paths, segments)):
             if write:
                 with opener(p, "wb") as f:
                     for s in segs:
                         f.write(memoryview(np.ascontiguousarray(s)).cast("B"))
             else:
                 with opener(p, "rb") as f:
-                    for s in segs:
+                    for j, s in enumerate(segs):
+                        if not s.nbytes:    # cast refuses an empty shape
+                            continue
                         mv = memoryview(s).cast("B")
+                        if offsets is not None:
+                            f.seek(offsets[i][j])
                         if compress:
                             mv[:] = f.read(mv.nbytes)
-                        else:
-                            f.readinto(mv)
+                        elif f.readinto(mv) != mv.nbytes:
+                            raise IOError(f"file job failed: {p} ends "
+                                          "inside a segment")
         return
-    flat_ptrs, flat_lens, offsets = [], [], [0]
+    flat_ptrs, flat_lens, bounds = [], [], [0]
     keep = []
     for segs in segments:
         for s in segs:
@@ -196,16 +206,26 @@ def _file_jobs(paths: List[str], segments: List[List[np.ndarray]],
             keep.append(s)
             flat_ptrs.append(s.ctypes.data)
             flat_lens.append(s.nbytes)
-        offsets.append(len(flat_ptrs))
+        bounds.append(len(flat_ptrs))
     c_paths = (ctypes.c_char_p * n)(*[p.encode() for p in paths])
     nseg = len(flat_ptrs)
     c_ptrs = (ctypes.c_void_p * nseg)(*flat_ptrs)
     lens_arr = np.asarray(flat_lens, np.int64)
-    offs_arr = np.asarray(offsets, np.int64)
-    mode = (1 if write else 0) + (2 if compress else 0)
-    rc = lib.dryad_file_jobs(
-        c_paths, n, c_ptrs, lens_arr.ctypes.data_as(ctypes.c_void_p),
-        offs_arr.ctypes.data_as(ctypes.c_void_p), mode, nthreads)
+    offs_arr = np.asarray(bounds, np.int64)
+    if offsets is not None:
+        at_arr = np.asarray([o for offs in offsets for o in offs], np.int64)
+        if at_arr.size != nseg:
+            raise ValueError(f"{at_arr.size} file offsets for {nseg} "
+                             "segments")
+        rc = lib.dryad_read_ranges(
+            c_paths, n, c_ptrs, lens_arr.ctypes.data_as(ctypes.c_void_p),
+            at_arr.ctypes.data_as(ctypes.c_void_p),
+            offs_arr.ctypes.data_as(ctypes.c_void_p), nthreads)
+    else:
+        mode = (1 if write else 0) + (2 if compress else 0)
+        rc = lib.dryad_file_jobs(
+            c_paths, n, c_ptrs, lens_arr.ctypes.data_as(ctypes.c_void_p),
+            offs_arr.ctypes.data_as(ctypes.c_void_p), mode, nthreads)
     if rc != 0:
         raise IOError(f"native file job failed: {paths[int(rc) - 1]}")
 
@@ -217,11 +237,15 @@ def write_files(paths: List[str], segments: List[List[np.ndarray]],
 
 
 def read_files(paths: List[str], segments: List[List[np.ndarray]],
-               nthreads: int = 8, compress: bool = False) -> None:
-    """Read each file's bytes contiguously into the given (preallocated,
-    writable) arrays."""
+               nthreads: int = 8, compress: bool = False,
+               offsets: Optional[List[List[int]]] = None) -> None:
+    """Read each file's bytes into the given (preallocated, writable)
+    arrays: contiguously from the file's first byte, or — the ranged form,
+    plain files only — ``segments[i][j]`` from file offset
+    ``offsets[i][j]``, nothing between two ranges touched.  One job a file
+    either way."""
     _file_jobs(paths, segments, write=False, nthreads=nthreads,
-               compress=compress)
+               compress=compress, offsets=offsets)
 
 
 def compact_rows(data: np.ndarray, lens: np.ndarray

@@ -257,11 +257,18 @@ class Catalog:
 
     # -- dataset construction ----------------------------------------------
 
-    def dataset(self, ctx, name: str, loader=None):
+    def dataset(self, ctx, name: str, loader=None, columns=None):
         """Root Dataset for ``name`` under ``ctx`` (a real api.Context
         or a :class:`SchemaContext`).  Returns ``(dataset, source
         data-handle)`` — the handle identity lets the service map plan
         source slots back to table names for warm-cache rebinding.
+
+        ``columns`` (stored column names the statement reads; None = all)
+        goes to ``ctx.from_store`` where the table is read from its store
+        here and now: only those columns are fetched, verified, stacked
+        and put on the device.  Ignored elsewhere — inline tables, a
+        ``SchemaContext``, a ``loader``'s whole shared table — where the
+        ``sql-scan`` projector prunes on the device as before.
 
         ``loader`` (optional, ``name -> PData``) supplies the source
         data instead of a fresh store/columns read — the service's
@@ -294,7 +301,7 @@ class Catalog:
                     part = E.Partitioning.none()
                 ds = ctx.from_pdata(loader(name), partitioning=part)
             else:
-                ds = ctx.from_store(t.path)
+                ds = ctx.from_store(t.path, columns=columns)
         elif t.kind == "inline":
             if use_loader:
                 ds = ctx.from_pdata(loader(name),

@@ -9,8 +9,9 @@ sources, and per-tenant admission when submitted through the service.
 
 Shape of the lowered chain::
 
-    FROM t [, u | JOIN u]  catalog.dataset() roots + rename Projector
-                           (a column the statement names becomes
+    FROM t [, u | JOIN u]  catalog.dataset() roots, read from the store
+                           at the columns the statement reads, + rename
+                           Projector (a column the statement names becomes
                            ``alias.col``; the others are dropped)
     WHERE, one table's     .where(Predicate) on that table's scan, below
                            its join; a column only it reads goes after
@@ -99,9 +100,6 @@ def lower(ctx, catalog: Catalog, bound: BoundSelect, loader=None,
     stored: Dict[str, int] = {}
 
     def root(table: str, alias: str, renames: Dict[str, str], span):
-        ds, data = catalog.dataset(ctx, table, loader=loader)
-        handles[id(data)] = table
-        _stamp(ds, span)
         pred = bound.scan_filters.get(alias)
         # ... and, until it has run, what the table's own filter reads
         pred_reads = prog_columns(pred) if pred is not None else set()
@@ -115,6 +113,11 @@ def lower(ctx, catalog: Catalog, bound: BoundSelect, loader=None,
                 catalog.get(table).schema[renames[p]]["kind"] == "str"))]
             only_pred = only_pred[1:]
         reads = keep + only_pred
+        # the store is read at the columns the statement reads, no others
+        ds, data = catalog.dataset(ctx, table, loader=loader,
+                                   columns=[renames[p] for p in reads])
+        handles[id(data)] = table
+        _stamp(ds, span)
         stored[table] = stored.get(table, 0) + len(renames)
         kept[table] = kept.get(table, 0) + len(keep)
         ds = _stamp(ds.select(_rename_projector(

@@ -23,6 +23,8 @@
 #ifdef __linux__
 #include <sched.h>
 #endif
+#include <fcntl.h>
+#include <unistd.h>
 #include <zlib.h>
 
 extern "C" {
@@ -115,6 +117,30 @@ static int read_one(const char* path, const Seg* segs, int64_t nsegs) {
   return 0;
 }
 
+// Ranged read: segment s is the segs[s].len bytes at file offset offs[s] —
+// the leaves a read of some columns keeps (io/store.read_parts), wherever
+// they lie in the partition file.  pread, so no byte between two kept
+// leaves is touched; a read cut short (the file ends inside a range) fails.
+static int read_one_ranges(const char* path, const Seg* segs,
+                           const int64_t* offs, int64_t nsegs) {
+  int fd = ::open(path, O_RDONLY);
+  if (fd < 0) return -1;
+  for (int64_t s = 0; s < nsegs; ++s) {
+    for (int64_t done = 0; done < segs[s].len;) {
+      ssize_t r = ::pread(fd, (void*)(segs[s].ptr + done),
+                          (size_t)(segs[s].len - done),
+                          (off_t)(offs[s] + done));
+      if (r <= 0) {
+        ::close(fd);
+        return -1;
+      }
+      done += r;
+    }
+  }
+  ::close(fd);
+  return 0;
+}
+
 // gzip variants (level-1 deflate): the per-channel compression transform of
 // the reference (GzipCompressionChannelTransform.cpp; job-level intermediate
 // compression mode, GraphManager DrGraph.cpp:47).
@@ -157,14 +183,16 @@ static int read_one_gz(const char* path, const Seg* segs, int64_t nsegs) {
   return 0;
 }
 
-// paths: array of n C strings; seg_offsets: n+1 prefix offsets into the
-// flat segs arrays.  write=1 writes, 0 reads.  Returns 0 on success, else
+// One job a file on a pool of nthreads workers.  mode: 0 = read, 1 = write,
+// 2 = read gzip, 3 = write gzip; seg_file_offs (mode 0 only, else null):
+// the file offset each segment is read from.  Returns 0 on success, else
 // the (1-based) index of the first failed job.
-// mode: 0 = read, 1 = write, 2 = read gzip, 3 = write gzip
-int64_t dryad_file_jobs(const char** paths, int64_t n,
-                        const uint8_t** seg_ptrs, const int64_t* seg_lens,
-                        const int64_t* seg_offsets, int32_t mode,
-                        int32_t nthreads) {
+static int64_t run_file_jobs(const char** paths, int64_t n,
+                             const uint8_t** seg_ptrs,
+                             const int64_t* seg_lens,
+                             const int64_t* seg_file_offs,
+                             const int64_t* seg_offsets, int32_t mode,
+                             int32_t nthreads) {
   if (nthreads < 1) nthreads = 1;
   if (nthreads > 64) nthreads = 64;
   std::atomic<int64_t> next(0), failed(0);
@@ -185,8 +213,10 @@ int64_t dryad_file_jobs(const char** paths, int64_t n,
                                  (int64_t)segs.size()); break;
         case 3: rc = write_one_gz(paths[i], segs.data(),
                                   (int64_t)segs.size()); break;
-        default: rc = read_one(paths[i], segs.data(),
-                               (int64_t)segs.size());
+        default: rc = seg_file_offs
+            ? read_one_ranges(paths[i], segs.data(), seg_file_offs + s0,
+                              (int64_t)segs.size())
+            : read_one(paths[i], segs.data(), (int64_t)segs.size());
       }
       if (rc != 0) failed.store(i + 1);
     }
@@ -196,6 +226,27 @@ int64_t dryad_file_jobs(const char** paths, int64_t n,
   for (int t = 0; t < nt; ++t) pool.emplace_back(worker);
   for (auto& th : pool) th.join();
   return failed.load();
+}
+
+// paths: array of n C strings; seg_offsets: n+1 prefix offsets into the
+// flat segs arrays.  Each file's segments are written (or read)
+// contiguously from its first byte.
+int64_t dryad_file_jobs(const char** paths, int64_t n,
+                        const uint8_t** seg_ptrs, const int64_t* seg_lens,
+                        const int64_t* seg_offsets, int32_t mode,
+                        int32_t nthreads) {
+  return run_file_jobs(paths, n, seg_ptrs, seg_lens, nullptr, seg_offsets,
+                       mode, nthreads);
+}
+
+// The ranged form of a read: flat segment s of file i is the seg_lens[s]
+// bytes at file offset seg_file_offs[s].
+int64_t dryad_read_ranges(const char** paths, int64_t n,
+                          const uint8_t** seg_ptrs, const int64_t* seg_lens,
+                          const int64_t* seg_file_offs,
+                          const int64_t* seg_offsets, int32_t nthreads) {
+  return run_file_jobs(paths, n, seg_ptrs, seg_lens, seg_file_offs,
+                       seg_offsets, 0, nthreads);
 }
 
 // ---------------------------------------------------------------------------

@@ -373,3 +373,91 @@ def test_spans_say_what_was_split_pruned_and_joined(devices8):
                                                      caps["lineitem"]]
         assert all(g["right_unique"] is False for g in got)
     assert all("join" in a["program"] for a in stage_spans)
+
+
+# -- the store is read at the columns the statement names ---------------------
+
+def _store_catalog(tmp_path, tables):
+    cat, paths = sql.Catalog(), {}
+    for name, cols in tables.items():
+        paths[name] = str(tmp_path / name)
+        Context().from_columns(cols).to_store(paths[name])
+        cat.register_store(name, paths[name])
+    return cat, paths
+
+
+def _store_reads(events, paths):
+    """table -> its ``store.read`` span's attrs, by the ``from_store`` span
+    the read nests in."""
+    spans = [e for e in events if e.get("event") == "span"]
+    source = {e["span"]: e["attrs"]["source"] for e in spans
+              if e["name"] == "from_store"}
+    table = {p: t for t, p in paths.items()}
+    return {table[source[e["parent"]]]: e["attrs"] for e in spans
+            if e["name"] == "store.read"}
+
+
+_Q6_HERE = Q6.replace("8766", str(8766 + 400)).replace("9131",
+                                                        str(9131 + 400))
+
+
+@pytest.mark.parametrize("text,names,reads", [
+    (_Q6_HERE, ["revenue"], {"lineitem": (4, 6, 16 / 24)}),
+    (Q3, ["l_orderkey", "revenue", "o_orderdate", "o_shippriority"],
+     {"customer": (2, 4), "orders": (4, 6), "lineitem": (4, 6, 16 / 24)})],
+    ids=["q6", "q3"])
+def test_a_store_is_read_at_the_columns_the_statement_names(
+        devices8, tmp_path, text, names, reads):
+    t = _tables()
+    cat, paths = _store_catalog(tmp_path, t)
+    events = []
+    got = sql.query(Context(event_log=events.append), cat, text).collect()
+    # the answers of the whole tables (an inline catalog holds every column)
+    want = sql.query(Context(), _catalog(t), text).collect()
+    assert sorted(got) == sorted(names)
+    for k in names:
+        assert np.array_equal(np.asarray(got[k]), np.asarray(want[k])), k
+    seen = _store_reads(events, paths)
+    assert sorted(seen) == sorted(reads)
+    for table, (cols, stored, *share) in reads.items():
+        a = seen[table]
+        assert (a["columns"], a["columns_stored"]) == (cols, stored), table
+        assert a["bytes"] < a["bytes_stored"]
+        if share:           # four 4-byte columns of lineitem's six
+            assert a["bytes"] / a["bytes_stored"] == pytest.approx(share[0])
+    # the plan is the plan of the whole tables: the scans' labels, and so
+    # the stage programs' names, do not say what the store handed over
+    labels = [n.label for n in E.walk(sql.query(Context(), cat, text).node)
+              if getattr(n, "label", "")]
+    assert labels == [n.label for n in E.walk(
+        sql.query(Context(), _catalog(t), text).node)
+        if getattr(n, "label", "")]
+
+
+def test_a_loader_still_gets_and_prunes_a_whole_table(devices8, tmp_path):
+    """The service's scan-share hook hands one whole table to queries that
+    name different columns: ``columns`` stops at it, and the ``sql-scan``
+    projector prunes on the device as before."""
+    from dryad_tpu.io.store import read_store
+    t = _tables()
+    cat, paths = _store_catalog(tmp_path, t)
+    ctx = Context()
+    loaded = {}
+
+    def loader(name):
+        loaded[name] = read_store(paths[name], ctx.mesh)
+        return loaded[name]
+    _mode, bound = sql.compile_query(cat, Q3)
+    ds, _handles = sql.lower(ctx, cat, bound, loader=loader)
+    assert {k: len(pd.batch.columns) for k, pd in loaded.items()} == {
+        "customer": 4, "orders": 6, "lineitem": 6}
+    got = ds.collect()
+    want = sql.query(Context(), cat, Q3).collect()
+    for k in want:
+        assert np.array_equal(np.asarray(got[k]), np.asarray(want[k])), k
+    # the sources differ, what leaves the scans does not
+    def scans(node):
+        return {n.label: n.fn.outputs for n in E.walk(node)
+                if getattr(n, "label", "").startswith("sql-scan")}
+    assert len(scans(ds.node)) == 3
+    assert scans(ds.node) == scans(sql.query(Context(), cat, Q3).node)
