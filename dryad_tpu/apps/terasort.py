@@ -4,7 +4,13 @@ The reference path: DryadLinqSampler (DryadLinqSampler.cs:42) samples keys,
 DrDynamicRangeDistributionManager picks split points, a range-partition
 shuffle redistributes, and each partition sorts locally.  Here: the planner's
 OrderBy lowering does exactly that with an all-to-all over ICI
-(plan/planner.py OrderBy; parallel/shuffle.range_exchange).
+(plan/planner.py OrderBy; parallel/shuffle.range_exchange).  The range
+exchange compares the whole key (every sort lane) and splits a run of
+equal keys by the rows' global input position (shuffle.range_dest), so
+duplicate-heavy keys — the sort benchmark's Daytona (skewed) input —
+stay balanced, and equal keys come out in input order.  The streamed
+form (terasort_ooc: exec/ooc.external_sort) buckets on the key's first
+lane and sorts each bucket whole.
 
 TeraSort records are 10-byte keys + 90-byte payloads; we carry them as a
 string key column plus a payload column.

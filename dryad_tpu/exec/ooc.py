@@ -678,13 +678,14 @@ def _make_scatter_fn(key: str, n_buckets: int):
     compile cache and re-XLA-compile every run."""
 
     def fn(b: Batch, bounds: jax.Array):
-        from dryad_tpu.parallel.shuffle import range_dest_lane
+        from dryad_tpu.parallel.shuffle import range_dest, range_key_lanes
 
-        from dryad_tpu.ops.kernels import searchsorted_small
-
-        lane = range_dest_lane(b.columns[key])
-        dest = searchsorted_small(bounds, lane,
-                                  side="right").astype(jnp.int32)
+        # the one range rule, handed the one lane this path samples
+        # (``bounds`` [n_buckets-1] over the primary's first lane): rows
+        # equal in it share a bucket, and a bucket that outgrows a chunk
+        # is re-bucketed or host-merged (_sorted_bucket_chunks)
+        dest, _ = range_dest(range_key_lanes(b, [(key, False)]),
+                             bounds[:, None])
         dest = jnp.where(b.valid_mask(), dest, n_buckets)  # padding last
         return _scatter_by_dest(b, dest, n_buckets)
 

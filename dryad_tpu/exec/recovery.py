@@ -271,6 +271,10 @@ class Run:
             need_slack = int(info[:, 1].max())
             need_exch = int(info[:, 2].max())
             of = need_scale > 0 or need_slack > 0
+            range_attrs = dict(rec.get("range", {}))
+            if range_attrs:
+                from dryad_tpu.exec.executor import _INFO_TIE
+                range_attrs["tie_rows"] = int(info[:, _INFO_TIE].sum())
             self._event({
                 "event": "stage_done", "stage": stage.id,
                 "label": stage.label, "attempt": 0,
@@ -283,7 +287,8 @@ class Run:
                 "out_bytes": rec.get("out_bytes", 0),
                 "deferred": True,
                 "dispatches": 1,   # program launch only; fetch amortized
-                "wall_s": rec["enqueue_s"], **rec.get("join", {})})
+                "wall_s": rec["enqueue_s"], **rec.get("join", {}),
+                **range_attrs})
             if not of:
                 # settled clean at the planned shapes: cross-check the
                 # measured rows/bytes against the static cost prediction

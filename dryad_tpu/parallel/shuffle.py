@@ -36,7 +36,8 @@ from dryad_tpu.ops.pallas_kernels import (hist_buckets, pallas_active,
 from dryad_tpu.parallel.mesh import PARTITION_AXIS
 
 __all__ = ["exchange_by_dest", "hash_exchange", "range_exchange",
-           "broadcast_gather", "range_dest_lane", "zip_exchange",
+           "broadcast_gather", "range_key_lanes", "global_position",
+           "range_dest", "range_tie_rows", "zip_exchange",
            "skew_join_exchange"]
 
 _DEST = "__dest"
@@ -380,37 +381,99 @@ def skew_join_exchange(left: Batch, right: Batch, left_keys, right_keys,
     return lout, rout, lnr, jnp.maximum(rnr1, rnr2), need_slack
 
 
-def range_dest_lane(col) -> jax.Array:
-    """uint32 ordering lane used for range partitioning decisions.
+def range_key_lanes(batch: Batch, keys: Sequence[Tuple[str, bool]]
+                    ) -> list:
+    """The uint32 lanes a range partitioner compares: every sort lane of
+    every ``(column, descending)`` key in key order (see
+    ops.kernels.sort_lanes_for).  A descending key's lanes are inverted,
+    so ascending lexicographic order of the lanes IS the requested order
+    and no destination is ever flipped."""
+    lanes = []
+    for name, desc in keys:
+        lanes.extend(sort_lanes_for(batch.columns[name], desc))
+    return lanes
 
-    The FIRST sort lane of the column (see ops.kernels.sort_lanes_for):
-    order-preserving for numerics; for strings it is the first 4 bytes, so
-    rows equal in the lane stay together (same destination) and global order
-    across partitions is still correct after local full-key sorts.
-    """
-    return sort_lanes_for(col, descending=False)[0]
+
+def global_position(batch: Batch, axes: tuple = (PARTITION_AXIS,)
+                    ) -> jax.Array:
+    """uint32 position of each row slot in the input taken in partition
+    order (valid rows of the partitions before this one, plus the local
+    index): the range partitioner's tiebreak lane.  A function of the
+    input alone, so a re-executed stage places every row where the first
+    run did.  Wraps beyond 2**32 rows, where it only blunts the
+    tiebreak's balance, never the order."""
+    counts = jax.lax.all_gather(batch.count, axes).reshape(-1)
+    me = jax.lax.axis_index(axes)
+    start = jnp.sum(jnp.where(jnp.arange(counts.shape[0]) < me, counts, 0))
+    return (start.astype(jnp.uint32)
+            + jnp.arange(batch.capacity, dtype=jnp.uint32))
 
 
-def range_exchange(batch: Batch, key: str, bounds: jax.Array,
-                   out_capacity: int, descending: bool = False,
+def range_dest(key_lanes: Sequence[jax.Array], bounds: jax.Array,
+               position: jax.Array | None = None
+               ) -> Tuple[jax.Array, jax.Array]:
+    """THE range destination rule: a row goes to the number of splitters
+    that are <= it, rows and splitters compared lexicographically as
+    tuples of uint32 lanes.
+
+    ``bounds`` is ``[P-1, K]`` (``[P-1, K+1]`` with ``position``): K
+    columns over the first K of ``key_lanes`` and, with ``position``, a
+    last column over that tiebreak lane.  With every key lane and the
+    position compared, a run of equal keys is cut by position wherever a
+    splitter's key falls inside it, so no key law can unbalance the
+    partitions; with a prefix of the lanes and no position (the streamed
+    paths sample the first lane alone) rows equal in the prefix stay
+    together.  Either way a later partition never holds a smaller
+    compared tuple than an earlier one.
+
+    Returns ``(dest int32 [n], tie bool [n])``; ``tie`` marks the rows
+    whose compared key lanes equal some splitter's — the ones only the
+    tiebreak (or the side of the comparison) placed."""
+    K = bounds.shape[1] - (position is not None)
+    n = key_lanes[0].shape[0]
+    dest = jnp.zeros((n,), jnp.int32)
+    tie = jnp.zeros((n,), bool)
+    # P-1 splitters, each a handful of 1-D compares that fuse into one
+    # elementwise pass over the rows; no [n, P-1] intermediate
+    for j in range(bounds.shape[0]):
+        ge = (position >= bounds[j, K] if position is not None
+              else jnp.ones((n,), bool))
+        eq = jnp.ones((n,), bool)
+        for k in range(K - 1, -1, -1):
+            same = key_lanes[k] == bounds[j, k]
+            ge = (key_lanes[k] > bounds[j, k]) | (same & ge)
+            eq = eq & same
+        dest = dest + ge.astype(jnp.int32)
+        tie = tie | eq
+    return dest, tie
+
+
+def range_exchange(batch: Batch, keys: Sequence[Tuple[str, bool]],
+                   bounds: jax.Array, out_capacity: int,
+                   tiebreak: bool = True,
                    send_slack: int = 2, axes: tuple = (PARTITION_AXIS,),
                    slot_rows: int | None = None
                    ) -> Tuple[Batch, jax.Array, jax.Array, jax.Array]:
-    """Repartition by range: row -> searchsorted(bounds, lane(key)).
-
-    ``bounds`` is a [P-1] uint32 array of split points over the ordering
-    lane, computed host-side from samples (the reference computes these in a
-    sampling stage: DryadLinqSampler.cs:42 + DrDynamicRangeDistributor.h:23).
-    """
-    from dryad_tpu.ops.kernels import searchsorted_small
-
-    lane = range_dest_lane(batch.columns[key])
-    dest = searchsorted_small(bounds, lane, side="right").astype(jnp.int32)
-    if descending:
-        P = bounds.shape[0] + 1
-        dest = (P - 1) - dest
+    """Repartition by range: row -> ``range_dest`` of its key lanes (and,
+    with ``tiebreak``, its global input position) against ``bounds``,
+    the sampled splitters (the reference computes these in a sampling
+    stage: DryadLinqSampler.cs:42 + DrDynamicRangeDistributor.h:23).
+    Partition p then holds only rows that sort at or before those of
+    partition p+1 under ``keys``; equal keys may straddle partitions,
+    in input order."""
+    dest, _ = range_dest(range_key_lanes(batch, keys), bounds,
+                         global_position(batch, axes) if tiebreak else None)
     return exchange_by_dest(batch, dest, out_capacity, send_slack, axes,
                             slot_rows=slot_rows)
+
+
+def range_tie_rows(batch: Batch, keys: Sequence[Tuple[str, bool]],
+                   bounds: jax.Array) -> jax.Array:
+    """Valid rows of this shard whose key equals a splitter's key (the
+    rows the tiebreak placed) — the ``tie_rows`` trace counter.
+    ``bounds`` as ``range_exchange`` takes them with ``tiebreak``."""
+    _, tie = range_dest(range_key_lanes(batch, keys), bounds[:, :-1])
+    return (tie & batch.valid_mask()).sum(dtype=jnp.int32)
 
 
 def zip_exchange(a: Batch, b: Batch, suffix: str = "_r",
@@ -433,17 +496,13 @@ def zip_exchange(a: Batch, b: Batch, suffix: str = "_r",
 
     zero = jnp.zeros((), jnp.int32)
     counts_a = jax.lax.all_gather(a.count, axes)  # [P]
-    counts_b = jax.lax.all_gather(b.count, axes)
-    me = jax.lax.axis_index(axes)
     P = counts_a.shape[0]
     if P == 1:  # single partition: already globally aligned
         return zip2(a, b, suffix), zero, zero
     starts_a = jnp.cumsum(counts_a) - counts_a  # exclusive prefix
     ends_a = starts_a + counts_a
     total_a = counts_a.sum()
-    start_b = jnp.sum(jnp.where(jnp.arange(P) < me, counts_b, 0))
-
-    gidx = start_b + jnp.arange(b.capacity, dtype=jnp.int32)
+    gidx = global_position(b, axes).astype(jnp.int32)
     from dryad_tpu.ops.kernels import searchsorted_small
     dest = searchsorted_small(ends_a, gidx, side="right").astype(jnp.int32)
     dest = jnp.where(gidx < total_a, dest, P)  # beyond left total: drop

@@ -564,17 +564,20 @@ class Planner:
                 f.ops.append(StageOp("sort", {"keys": tuple(n.keys)}))
                 return f
             src_id, f = self._materialize(f, label="sort-input")
-            primary, desc = n.keys[0]
-            ex = Exchange("range", keys=(primary,), out_capacity=f.capacity,
-                          descending=desc, bounds_from=src_id,
-                          bounds_key=primary)
+            ex = Exchange("range", keys=sort_keys, out_capacity=f.capacity,
+                          descending=tuple(d for _, d in n.keys),
+                          bounds_from=src_id)
             st = self._new_stage(
                 [Leg(src_id, [], ex)],
                 [StageOp("sort", {"keys": tuple(n.keys)})], "orderby")
-            # the exchange ranges on the primary only, but it routes equal
-            # primary lanes to ONE destination (ties co-located), and the
-            # local sort orders each partition by the full key list — the
-            # output is globally sorted by all sort keys when ascending
+            # the exchange compares the WHOLE key list, each key in its
+            # direction, and cuts a run of equal keys by input position
+            # (shuffle.range_dest): partition p holds only rows that
+            # sort at or before partition p+1's, ties may straddle, and
+            # the stable local sort orders each partition — the output
+            # is globally sorted by all sort keys, equal keys in input
+            # order.  The "range" claim is made for ascending keys only
+            # (its consumer, the elimination above, assumes ascending)
             return Fragment(st.id, [], f.capacity,
                             E.Partitioning("range", sort_keys)
                             if all_asc else E.Partitioning.none())
@@ -635,9 +638,8 @@ class Planner:
                 f.partitioning = E.Partitioning("range", tuple(n.keys))
                 return f
             src_id, f = self._materialize(f, label="range-input")
-            key = n.keys[0]
-            ex = Exchange("range", keys=(key,), out_capacity=f.capacity,
-                          bounds_from=src_id, bounds_key=key)
+            ex = Exchange("range", keys=tuple(n.keys),
+                          out_capacity=f.capacity, bounds_from=src_id)
             st = self._new_stage([Leg(src_id, [], ex)], [], "rangepartition")
             return Fragment(st.id, [], f.capacity,
                             E.Partitioning("range", tuple(n.keys)))

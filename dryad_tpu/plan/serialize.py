@@ -167,9 +167,8 @@ def graph_to_json(graph: StageGraph,
                 e = leg.exchange
                 ex = {"kind": e.kind, "keys": list(e.keys),
                       "out_capacity": e.out_capacity,
-                      "descending": e.descending,
+                      "descending": list(e.descending),
                       "bounds_from": e.bounds_from,
-                      "bounds_key": e.bounds_key,
                       "axis": e.axis}
             legs.append({"src": src,
                          "ops": [_op_to_json(o, fn_names, shared)
@@ -211,9 +210,14 @@ def graph_from_json(s: str, fn_table: Optional[Dict[str, Callable]] = None,
             ex = None
             if ld["exchange"] is not None:
                 e = ld["exchange"]
+                desc = e["descending"]
+                if isinstance(desc, bool):
+                    # a plan written before range exchanges compared
+                    # every key: one flag, for its one (primary) key
+                    desc = [desc] * len(e["keys"]) if desc else []
                 ex = Exchange(e["kind"], tuple(e["keys"]), e["out_capacity"],
-                              e["descending"], e["bounds_from"],
-                              e["bounds_key"], axis=e.get("axis"))
+                              tuple(desc), e["bounds_from"],
+                              axis=e.get("axis"))
             legs.append(Leg(lsrc, [_op_from_json(o, fn_table, shared)
                                    for o in ld["ops"]], ex))
         stages.append(Stage(id=sd["id"], legs=legs,

@@ -49,17 +49,25 @@ class Exchange:
 
     kind: hash | range | broadcast.  out_capacity resolved by the planner
     and scaled up by the executor on overflow (dynamic-repartition parity
-    with DrDynamicDistributionManager)."""
+    with DrDynamicDistributionManager).  A range exchange's ``keys`` are
+    ALL its sort keys, in order, ``descending`` their directions (empty =
+    all ascending): the splitters are sampled from stage ``bounds_from``
+    and compared over every sort lane of every key
+    (parallel/shuffle.range_dest)."""
 
     kind: str
     keys: Tuple[str, ...] = ()
     out_capacity: int = 0
-    descending: bool = False
+    descending: Tuple[bool, ...] = ()
     bounds_from: Optional[int] = None  # stage id whose output seeds range bounds
-    bounds_key: Optional[str] = None
     # None = global exchange over all mesh axes; "dp"/"dcn" = only that axis
     # (hierarchical aggregation hops, DrDynamicAggregateManager.h:99 parity)
     axis: Optional[str] = None
+
+    def sort_keys(self) -> Tuple[Tuple[str, bool], ...]:
+        """A range exchange's ``(column, descending)`` keys."""
+        desc = self.descending or (False,) * len(self.keys)
+        return tuple(zip(self.keys, desc))
 
 
 @dataclasses.dataclass
@@ -131,9 +139,9 @@ class Stage:
         def ex_fp(ex: Optional[Exchange]) -> str:
             if ex is None:
                 return "-"
+            desc = "".join("d" if d else "a" for d in ex.descending)
             return (f"{ex.kind}[{','.join(ex.keys)}]cap{ex.out_capacity}"
-                    f"{'desc' if ex.descending else ''}"
-                    f"{ex.bounds_key or ''}@{ex.axis or '*'}")
+                    f"{desc}@{ex.axis or '*'}")
 
         legs = ";".join(
             ",".join(op_fp(o) for o in leg.ops) + "=>" + ex_fp(leg.exchange)

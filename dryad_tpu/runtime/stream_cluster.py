@@ -247,9 +247,11 @@ def _write_partitions(out_path: str, schema, part_chunks, part_ids,
 # terminals
 
 
-def _sample_pass(cs, key: Optional[str]):
+def _sample_pass(cs, key: Optional[str], descending: bool = False):
     """One full pass over the local stream: (lane samples, chunk count,
-    row count).  Samples empty when key is None."""
+    row count).  Samples empty when key is None.  The lane is the first
+    sort lane of ``key`` in its direction — the lane
+    shuffle.range_key_lanes gives the streamed range exchange."""
     from dryad_tpu.exec import ooc
 
     samples: List[np.ndarray] = []
@@ -265,10 +267,10 @@ def _sample_pass(cs, key: Optional[str]):
         idx = np.linspace(0, chunk.n - 1, take).astype(np.int64)
         col = chunk.cols[key]
         if spec["kind"] == "str":
-            lane = ooc._host_sort_lanes(spec, (col[0][idx], col[1][idx]))[0]
+            sub = (col[0][idx], col[1][idx])
         else:
-            lane = ooc._host_sort_lanes(spec, col[idx])[0]
-        samples.append(lane)
+            sub = col[idx]
+        samples.append(ooc._host_sort_lanes(spec, sub, descending)[0])
     s = (np.concatenate(samples) if samples
          else np.zeros((0,), np.uint32))
     if len(s) > _MAX_SAMPLES:

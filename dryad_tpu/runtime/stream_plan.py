@@ -201,9 +201,12 @@ def _build_wave_fn(mesh, leg_ops: List[StageOp], ex: Exchange,
                 b, list(ex.keys), out_cap, send_slack=slack, axes=axes,
                 axis=ex.axis, slot_rows=slot_rows)
         elif ex.kind == "range":
+            # the one range rule, handed the one lane the streamed
+            # sample pass has (``bounds`` [P-1] over the primary's first
+            # lane, in its direction): rows equal in it stay together
             out, nr, nsl, slot = shuffle.range_exchange(
-                b, ex.keys[0], bounds, out_cap,
-                descending=ex.descending, send_slack=slack, axes=axes,
+                b, ex.sort_keys()[:1], bounds[:, None], out_cap,
+                tiebreak=False, send_slack=slack, axes=axes,
                 slot_rows=slot_rows)
         elif ex.kind == "broadcast":
             out, nr, nsl = shuffle.broadcast_gather(b, out_cap, axes=axes)
@@ -713,8 +716,8 @@ def execute_stream_plan(plan_json: str, fn_table, source_specs, mesh,
                 # role) from the exchange's own input streams
                 samples = []
                 for cs in pre_dev.streams:
-                    s, _, _ = _sample_pass(cs, leg.exchange.bounds_key
-                                           or leg.exchange.keys[0])
+                    s, _, _ = _sample_pass(
+                        cs, *leg.exchange.sort_keys()[0])
                     samples.append(s)
                 merged = (np.concatenate(samples) if samples
                           else np.zeros((0,), np.uint32))

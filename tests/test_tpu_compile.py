@@ -139,7 +139,8 @@ def test_exchange_compiles_on_four_chips(topo, tpu_tier, kind):
     mesh = Mesh(np.asarray(topo.devices), ("dp",))
     cap = 2048
     batch = _batch(NamedSharding(mesh, P("dp")), (4,), cap, **_TERASORT)
-    bounds = jax.ShapeDtypeStruct((3,), jnp.uint32,
+    # splitters over the key's 3 sort lanes and the position lane
+    bounds = jax.ShapeDtypeStruct((3, 4), jnp.uint32,
                                   sharding=NamedSharding(mesh, P()))
 
     def per_shard(b, bnd):
@@ -147,7 +148,8 @@ def test_exchange_compiles_on_four_chips(topo, tpu_tier, kind):
         if kind == "hash":
             out, *needs = shuffle.hash_exchange(b, ["key"], cap)
         else:
-            out, *needs = shuffle.range_exchange(b, "key", bnd, cap)
+            out, *needs = shuffle.range_exchange(b, [("key", False)],
+                                                 bnd, cap)
         return (jax.tree.map(lambda x: x[None], out),
                 jnp.stack([n.astype(jnp.int32) for n in needs])[None])
 
