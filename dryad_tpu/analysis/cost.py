@@ -70,7 +70,7 @@ _WORK_MULT = {
     "group_rank": 3.0, "dgroup_local": 3.0, "dgroup_partial": 3.0,
     "dgroup_merge": 3.0, "join": 2.0, "semi_anti": 2.0,
     "group_apply": 2.0, "flat_tokens": 2.0, "tokens_group_count": 2.0,
-    "flat_map": 2.0,
+    "flat_map": 2.0, "where_group": 3.0,
 }
 
 
@@ -353,6 +353,12 @@ def _abs_op(s: AbsState, op, nparts: int, config,
                                               dict(p["aggs"])),
             _abs_batch(s)) if known else None)
         return _abs_of_result(res, _dist_lo(rows), nparts, s, "group")
+    if k == "where_group":
+        # the executor's fused filter -> group: the plan's own ops, one
+        # after the other (the mask changes what runs, not what comes out)
+        for step in p["steps"] + [p["group"]]:
+            s = _abs_op(s, step, nparts, config, others)
+        return s
     if k in ("dgroup_local", "dgroup_partial", "dgroup_merge"):
         fns = {"dgroup_local": kernels.group_decompose_local,
                "dgroup_partial": kernels.group_decompose_partial}

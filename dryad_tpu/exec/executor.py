@@ -261,6 +261,19 @@ def _apply_op(b, op: StageOp, scale: int, others: List[Batch],
     if k == "group":
         keys = list(p["keys"])
         return kernels.group_aggregate(b, keys, dict(p["aggs"])), no
+    if k == "where_group":
+        # _fuse_stage_ops: the filters in front of a group-by are the
+        # group-by's row mask, each predicate evaluated where the plan
+        # has it (a later projection may drop the column it reads)
+        cols, keep = dict(b.columns), True
+        for step in p["steps"]:
+            if step.kind == "filter":
+                keep = keep & step.params["fn"](dict(cols))
+            else:
+                cols = dict(step.params["fn"](dict(cols)))
+        g = p["group"].params
+        return kernels.group_aggregate(Batch(cols, b.count), list(g["keys"]),
+                                       dict(g["aggs"]), where=keep), no
     if k == "group_apply":
         G0, C0, O0 = p["max_groups"], p["group_capacity"], p["out_capacity"]
         out, ng, ms, tot = kernels.group_regroup_apply(
@@ -426,17 +439,59 @@ def _apply_op(b, op: StageOp, scale: int, others: List[Batch],
     raise ValueError(f"unknown op kind {k}")
 
 
+def _rowwise(op: StageOp) -> bool:
+    """True for an op whose function is row-wise BY CONSTRUCTION: a SQL
+    row-expression program (sql/rowexpr: column references, literals,
+    elementwise operators — row i of the result reads row i of the
+    input and nothing else), so neither a dropped row nor a row's
+    position can show in another row.  An opaque callable may look at
+    positions (a running sum over the compacted prefix) and is not."""
+    from dryad_tpu.sql.rowexpr import Predicate, Projector
+    return ((op.kind == "fn" and isinstance(op.params["fn"], Projector))
+            or (op.kind == "filter"
+                and isinstance(op.params["fn"], Predicate)))
+
+
+def _where_group_end(ops, i: int) -> Optional[int]:
+    """Index of the ``group`` op that the filter ``ops[i]`` feeds through
+    row-wise ops alone (``filter, (fn | filter)*, group``), else None:
+    an exchange, a join, a sort, a take, the end of the leg or an opaque
+    function behind the filter still needs the compacted batch."""
+    j = i + 1
+    while j < len(ops) and _rowwise(ops[j]):
+        j += 1
+    return j if j < len(ops) and ops[j].kind == "group" else None
+
+
 def _fuse_stage_ops(ops):
-    """Executor-side peephole: flat_tokens immediately followed by a
-    count-only group over the token column becomes ONE fused op — the
-    windowed byte extraction (the tokenizer's dominant cost, ~10 ns per
-    gathered word) then runs only for group representatives
-    (ops/text.tokenize_group_count).  Plans ship unfused; fusion is a
-    per-execution rewrite, so workers and driver fuse identically."""
+    """Executor-side peephole.  Plans ship unfused; fusion is a
+    per-execution rewrite, so workers and driver fuse identically (and a
+    stage's program name and fingerprint stay the plan's).
+
+    * flat_tokens immediately followed by a count-only group over the
+      token column becomes ONE fused op — the windowed byte extraction
+      (the tokenizer's dominant cost, ~10 ns per gathered word) then
+      runs only for group representatives (ops/text.tokenize_group_count).
+    * a filter that feeds a group through row-wise ops alone
+      (``filter, (fn | filter)*, group``, _where_group_end) becomes ONE
+      ``where_group`` op: the predicates make the group-by's row mask
+      (kernels.group_aggregate ``where=``) where kernels.compact would
+      sort every row of every column to the front first — the group-by
+      reads validity from a mask and never from position.  The first
+      filter's predicate may be any callable (it sees the batch the plan
+      gives it); what follows it must be row-wise by construction
+      (_rowwise), since it now sees the dropped rows in place."""
     out = []
     i = 0
     while i < len(ops):
         op = ops[i]
+        if op.kind == "filter":
+            j = _where_group_end(ops, i)
+            if j is not None:
+                out.append(StageOp("where_group", {"steps": list(ops[i:j]),
+                                                   "group": ops[j]}))
+                i = j + 1
+                continue
         if (op.kind == "flat_tokens" and i + 1 < len(ops)
                 and ops[i + 1].kind == "group"):
             g = ops[i + 1]
@@ -455,6 +510,24 @@ def _fuse_stage_ops(ops):
         out.append(op)
         i += 1
     return out
+
+
+def _filter_counts(stage: Stage) -> Dict[str, int]:
+    """What a stage's program does with the plan's filter ops:
+    ``filters_masked`` became a group-by's row mask (_fuse_stage_ops),
+    ``filters_compacted`` still sort the batch (kernels.compact).  Known
+    from the plan; {} for a stage without a filter."""
+    masked = compacted = 0
+    for ops in [leg.ops for leg in stage.legs] + [stage.body]:
+        for op in _fuse_stage_ops(ops):
+            if op.kind == "where_group":
+                masked += sum(st.kind == "filter"
+                              for st in op.params["steps"])
+            elif op.kind == "filter":
+                compacted += 1
+    if not masked and not compacted:
+        return {}
+    return {"filters_masked": masked, "filters_compacted": compacted}
 
 
 def _apply_exchange(b: Batch, ex: Exchange, scale: int, slack: int, bounds,
@@ -977,6 +1050,7 @@ class Executor:
         # compare, the tiebreak included (static: the bounds' shape)
         range_attrs = ({} if bounds is None
                        else {"range_lanes": int(bounds.shape[1])})
+        filter_attrs = _filter_counts(stage)
         for attempt in range(max_retries + 1):
             # salt knobs are baked into compiled salted programs — they
             # must key the cache or a re-configured job reuses stale code
@@ -1037,7 +1111,8 @@ class Executor:
                 **facts}
             if span is not trace.NULL:
                 span.set(program="jit_" + stage_program_name(stage),
-                         cache_hit=cache_hit, **join_attrs, **range_attrs)
+                         cache_hit=cache_hit, **join_attrs, **range_attrs,
+                         **filter_attrs)
             t0 = time.time()
             out_batch, info = fn(*args)
             if defer is not None and attempt == 0:
@@ -1068,7 +1143,8 @@ class Executor:
                               "compile_s": round(compile_s, 4),
                               "out_bytes": out_bytes,
                               "enqueue_s": enqueue_s,
-                              "join": join_attrs, "range": range_attrs})
+                              "join": join_attrs, "range": range_attrs,
+                              "filters": filter_attrs})
                 stage._capacity_scale = scale
                 stage._send_slack = slack
                 stage._salted = salted
@@ -1108,7 +1184,8 @@ class Executor:
                 "compile_s": round(compile_s, 4),
                 "cache_hit": cache_hit,
                 "dispatches": 2,   # program launch + info fetch
-                "wall_s": round(wall, 4), **join_attrs, **range_attrs})
+                "wall_s": round(wall, 4), **join_attrs, **range_attrs,
+                **filter_attrs})
             decision = self._decide_needs(stage, scale, slack, salted,
                                           need_scale, need_slack,
                                           need_exch)

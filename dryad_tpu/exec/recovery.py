@@ -288,7 +288,7 @@ class Run:
                 "deferred": True,
                 "dispatches": 1,   # program launch only; fetch amortized
                 "wall_s": rec["enqueue_s"], **rec.get("join", {}),
-                **range_attrs})
+                **range_attrs, **rec.get("filters", {})})
             if not of:
                 # settled clean at the planned shapes: cross-check the
                 # measured rows/bytes against the static cost prediction

@@ -737,13 +737,37 @@ def _shift_fwd(a: jax.Array, fill) -> jax.Array:
     return jnp.concatenate([jnp.full((1,), fill, a.dtype), a[:-1]])
 
 
+def _live_rows(batch: Batch, where):
+    """(valid mask, valid count) of a group-by's input rows: the batch's
+    own prefix, less the rows a ``where`` mask drops.  ``where=None``
+    traces exactly what the lowerings traced before they took a mask."""
+    valid = batch.valid_mask()
+    if where is None:
+        return valid, batch.count
+    valid = valid & where
+    return valid, valid.sum(dtype=jnp.int32)
+
+
 def group_aggregate(batch: Batch, key_names: Sequence[str],
-                    aggs: Dict[str, Tuple[str, str | None]]) -> Batch:
+                    aggs: Dict[str, Tuple[str, str | None]],
+                    where=None) -> Batch:
     """GroupBy + decomposable aggregation.
 
     aggs: out_name -> (kind, value_column | None).  Kinds: sum, count, min,
     max, mean, any, all.  Output batch has the key columns (one representative
     row per group) plus one column per aggregate; count = number of groups.
+
+    where: optional ``bool[capacity]`` row mask (a scalar broadcasts) —
+    the groups of the rows that are valid AND kept, i.e. of
+    ``compact(batch, where)``, without the compaction: every lowering
+    reads a row's validity from a mask and never from its position (the
+    sort-based ones send a dropped row to the back in their own segment
+    sort, the one-hot one gives it the slot that matches nothing and
+    zeroes its values), so a filter in front of a group-by only has to
+    change that mask (exec.executor._fuse_stage_ops).  A dropped row may
+    hold anything, NaN and inf included.  Float sums can differ from the
+    compacted form's in the last ulp (rows meet the unstable segment
+    sort in another order); counts, min/max and keys are exact.
 
     This is the map-side combine of the reference's IDecomposable protocol
     (reference LinqToDryad/IDecomposable.cs:34): all kinds here are
@@ -766,12 +790,13 @@ def group_aggregate(batch: Batch, key_names: Sequence[str],
     ok, minmax_col = _boundary_eligible(batch, aggs)
     if ok:
         fallback = lambda b: _group_aggregate_boundary(  # noqa: E731
-            b, key_names, aggs, minmax_col)
+            b, key_names, aggs, minmax_col, where)
     else:
         fallback = lambda b: _group_aggregate_scan(  # noqa: E731
-            b, key_names, aggs)
+            b, key_names, aggs, where)
     if _matmul_group_eligible(batch, key_names, aggs):
-        return _group_aggregate_smallkey(batch, key_names, aggs, fallback)
+        return _group_aggregate_smallkey(batch, key_names, aggs, fallback,
+                                         where)
     return fallback(batch)
 
 
@@ -807,7 +832,7 @@ def _matmul_group_eligible(batch: Batch, key_names, aggs) -> bool:
 
 def _group_aggregate_smallkey(batch: Batch, key_names: Sequence[str],
                               aggs: Dict[str, Tuple[str, str | None]],
-                              fallback) -> Batch:
+                              fallback, where=None) -> Batch:
     """One-hot MXU group aggregation for small-span integer keys.
 
     The sort-based lowerings pay ~log^2(n) compare-exchange stages per
@@ -821,8 +846,7 @@ def _group_aggregate_smallkey(batch: Batch, key_names: Sequence[str],
     """
     kcol = batch.columns[key_names[0]]
     cap = batch.capacity
-    valid = batch.valid_mask()
-    n_valid = batch.count
+    valid, n_valid = _live_rows(batch, where)
     S = _SMALLKEY_SLOTS
     kmin = jnp.min(jnp.where(valid, kcol, jnp.iinfo(kcol.dtype).max))
     kmax = jnp.max(jnp.where(valid, kcol, jnp.iinfo(kcol.dtype).min))
@@ -915,7 +939,7 @@ def _group_aggregate_smallkey(batch: Batch, key_names: Sequence[str],
 
 def _group_aggregate_boundary(batch: Batch, key_names: Sequence[str],
                               aggs: Dict[str, Tuple[str, str | None]],
-                              minmax_col: str | None) -> Batch:
+                              minmax_col: str | None, where=None) -> Batch:
     """Boundary-carry group aggregation — scan-free.
 
     The round-4 profile (scratch probes, re-runnable via
@@ -943,9 +967,8 @@ def _group_aggregate_boundary(batch: Batch, key_names: Sequence[str],
     One unstable segment sort + one stable boundary densify + one
     streamed prefix pass — nothing else touches HBM.
     """
-    valid = batch.valid_mask()
+    valid, n_valid = _live_rows(batch, where)
     cap = batch.capacity
-    n_valid = batch.count
     idx = jnp.arange(cap, dtype=jnp.int32)
 
     kcol0 = batch.columns[key_names[0]]
@@ -1109,7 +1132,8 @@ def _group_aggregate_boundary(batch: Batch, key_names: Sequence[str],
 
 
 def _group_aggregate_scan(batch: Batch, key_names: Sequence[str],
-                          aggs: Dict[str, Tuple[str, str | None]]) -> Batch:
+                          aggs: Dict[str, Tuple[str, str | None]],
+                          where=None) -> Batch:
     """Segmented-scan group aggregation — the general path (2-D value
     columns, 8-byte sums, string or multi-column min/max)."""
     # Scatter- and gather-free lowering (TPU: scatters serialize, random
@@ -1124,9 +1148,8 @@ def _group_aggregate_scan(batch: Batch, key_names: Sequence[str],
     # column rides as one raw lane and is rebuilt from the sorted lane,
     # and the segment sort runs UNSTABLE (measured ~2x cheaper; nothing
     # observes in-segment value order).
-    valid = batch.valid_mask()
+    valid, n_valid = _live_rows(batch, where)
     cap = batch.capacity
-    n_valid = batch.count
     idx = jnp.arange(cap, dtype=jnp.int32)
 
     kcol0 = batch.columns[key_names[0]]

@@ -129,16 +129,24 @@ def _pagerank_join(ctx):
             .with_capacity(64))
 
 
+def _where_groupby(ctx):
+    """WHERE -> GROUP BY: the executor groups under the filter's mask
+    (one fused ``where_group`` op), and the model follows it."""
+    return (_kv(ctx).where(lambda c: c["v"] < 0.5)
+            .group_by(["k"], {"s": ("sum", "v"), "n": ("count", None)}))
+
+
 APPS = {"wordcount": _wordcount, "terasort": _terasort,
         "groupbyreduce": _groupbyreduce, "kmeans": _kmeans_step,
-        "pagerank-join": _pagerank_join}
+        "pagerank-join": _pagerank_join, "where-groupby": _where_groupby}
 
 
 @pytest.mark.parametrize("app", sorted(APPS))
 def test_soundness_sweep(app):
     """Predicted per-stage byte intervals are upper bounds on measured
     ``out_bytes`` (within 4x) and the runtime cross-check stays silent:
-    zero ``cost_model_miss`` events across the five bench apps."""
+    zero ``cost_model_miss`` events across the five bench apps and the
+    fused filter -> group-by."""
     log = EventLog(level=2)
     ctx = _ctx(log)
     APPS[app](ctx).collect()

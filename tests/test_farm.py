@@ -121,6 +121,9 @@ def test_farm_reassigns_on_worker_death(cluster):
         killer.cancel()
     _check(vals, results)               # completed without worker 1
     assert any(e["event"] == "task_reassigned" for e in farm.events)
+    # the farm sees the killed worker's socket close before the kernel
+    # has torn the process down: wait for the exit before asking
+    cluster._procs[1].wait(timeout=30)
     assert not cluster.alive()          # the gang lost a member...
     ctx = Context(cluster=cluster)      # ...and gang jobs auto-restart it
     assert ctx.from_columns({"v": np.arange(10, dtype=np.int32)}).count() \

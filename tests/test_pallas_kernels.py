@@ -321,6 +321,140 @@ def test_executor_fuses_tokens_group():
     assert res == dict(ref)
 
 
+def _seq(kinds):
+    """A stage-op list from short names: ``filter`` / ``fn`` carry SQL
+    row-expression programs, ``filter!`` / ``fn!`` opaque lambdas."""
+    from dryad_tpu.plan.stages import StageOp
+    from dryad_tpu.sql.rowexpr import Predicate, Projector
+    make = {
+        "filter": lambda: StageOp("filter", {"fn": Predicate(
+            ["bin", "<", ["col", "x"], ["lit", 5, "int"]])}),
+        "filter!": lambda: StageOp("filter",
+                                   {"fn": lambda c: c["x"] < 5}),
+        "fn": lambda: StageOp("fn", {"fn": Projector(
+            {"x": ["col", "x"], "k": ["col", "k"]})}),
+        "fn!": lambda: StageOp("fn", {"fn": lambda c: dict(c)}),
+        "group": lambda: StageOp("group", {"keys": ["k"],
+                                           "aggs": {"s": ("sum", "x")}}),
+        "sort": lambda: StageOp("sort", {"keys": [("k", False)]}),
+        "take": lambda: StageOp("take", {"n": 3}),
+        "distinct": lambda: StageOp("distinct", {"keys": ["k"]}),
+        "dgroup_local": lambda: StageOp("dgroup_local", {
+            "keys": ["k"], "decs": {}, "box": None}),
+    }
+    return [make[k]() for k in kinds]
+
+
+@pytest.mark.parametrize("kinds,fused", [
+    # a filter that feeds a group-by through row-wise ops is its mask
+    (["filter", "group"], ["where_group"]),
+    (["filter!", "group"], ["where_group"]),        # nothing between
+    (["fn", "filter", "fn", "fn", "group", "fn", "sort"],
+     ["fn", "where_group", "fn", "sort"]),          # Q1's stage
+    (["filter", "fn", "filter", "group"], ["where_group"]),
+    (["filter", "filter", "group"], ["where_group"]),
+    # an opaque function behind the filter may look at positions
+    (["filter", "fn!", "group"], ["filter", "fn!", "group"]),
+    # ... but the filter behind IT feeds the group through nothing
+    (["filter", "fn!", "filter!", "group"],
+     ["filter", "fn!", "where_group"]),
+    (["filter", "filter!", "group"], ["filter", "where_group"]),
+    # everything else behind a filter wants the compacted batch
+    (["filter", "sort"], ["filter", "sort"]),
+    (["filter", "take"], ["filter", "take"]),
+    (["filter", "distinct"], ["filter", "distinct"]),
+    (["filter", "dgroup_local"], ["filter", "dgroup_local"]),
+    (["fn", "filter", "fn"], ["fn", "filter", "fn"]),   # a join's leg
+    (["filter", "fn", "sort", "group"], ["filter", "fn", "sort", "group"]),
+    (["fn", "group"], ["fn", "group"]),
+])
+def test_which_filters_become_a_group_mask(kinds, fused):
+    from dryad_tpu.exec.executor import _fuse_stage_ops
+    ops = _seq(kinds)
+    out = _fuse_stage_ops(ops)
+    assert [o.kind for o in out] == [k.rstrip("!") for k in fused]
+    for o in out:
+        if o.kind == "where_group":
+            # the plan's own ops, in the plan's order, group excluded
+            steps = o.params["steps"]
+            i = ops.index(steps[0])
+            assert steps == ops[i:i + len(steps)]
+            assert steps[0].kind == "filter"
+            assert o.params["group"] is ops[i + len(steps)]
+            assert o.params["group"].kind == "group"
+    assert [o.kind for o in ops] == [k.rstrip("!") for k in kinds]  # no edit
+
+
+@pytest.mark.parametrize("kinds", [["filter", "group"],
+                                   ["filter", "fn", "filter", "group"],
+                                   ["filter!", "group"],
+                                   ["filter", "fn!", "group"]])
+def test_masked_and_compacted_stage_ops_answer_alike(kinds):
+    """The fused op list against the plan's own, op by op through
+    ``_apply_op``, over a batch whose padding rows hold NaN."""
+    from dryad_tpu.data.columnar import batch_from_numpy, batch_to_numpy
+    from dryad_tpu.exec.executor import _apply_op, _fuse_stage_ops
+    rng = np.random.default_rng(5)
+    x = rng.integers(0, 10, 64).astype(np.float32)
+    x[48:] = np.nan
+    b = batch_from_numpy({"x": x, "k": rng.integers(0, 1000, 64)
+                          .astype(np.int32) * 7919}).with_count(48)
+    ops = _seq(kinds)
+
+    def run(seq):
+        cur = b
+        for op in seq:
+            cur, _needs = _apply_op(cur, op, 1, [])
+        t = batch_to_numpy(cur)
+        return dict(zip(t["k"].tolist(), t["s"].tolist()))
+
+    want = {}
+    for k, v in zip(np.asarray(b["k"])[:48].tolist(), x[:48].tolist()):
+        if v < 5:
+            want[k] = want.get(k, 0.0) + v
+    assert run(_fuse_stage_ops(ops)) == run(ops) == want
+
+
+def test_sql_plans_keep_their_names_and_fingerprints():
+    """Plans ship unfused: the stage program names and the fingerprints
+    of Q1, Q3 and Q6 on one partition are what they were before the
+    peephole learned the pattern (pinned from the parent commit), so
+    compile-cache keys, cluster shipping and traces' module names stay."""
+    import hashlib
+
+    import jax
+
+    from dryad_tpu import sql
+    from dryad_tpu.api.dataset import Context
+    from dryad_tpu.exec.executor import (_filter_counts,
+                                         stage_program_name)
+    from dryad_tpu.parallel.mesh import make_mesh
+    from dryad_tpu.plan.planner import plan_query
+    from test_sql_tpch_join import Q1, Q3, Q6, _catalog, _tables_q1
+
+    ctx = Context(mesh=make_mesh(jax.devices()[:1]))
+    cat = _catalog(_tables_q1())
+    pinned = {
+        "Q1": (Q1, ["stage_output_fn_filter_fn_group_fn_sort"],
+               "066956d4ca173f14", (1, 0)),
+        "Q3": (Q3, ["stage_join_fn_filter_fn_filter_fn_join",
+                    "stage_join_fn_filter_fn_join",
+                    "stage_output_fn_group_fn_sort_take"],
+               "7dc8824c480b75ca", (0, 3)),
+        "Q6": (Q6, ["stage_output_fn_filter_fn_group_fn"],
+               "0c3720dc4eba0949", (1, 0)),
+    }
+    for name, (text, names, digest, counts) in pinned.items():
+        g = plan_query(sql.query(ctx, cat, text).node, ctx.nparts,
+                       config=ctx.config)
+        assert [stage_program_name(s) for s in g.stages] == names, name
+        fp = "\n".join(s.fingerprint() for s in g.stages)
+        assert hashlib.sha256(fp.encode()).hexdigest()[:16] == digest, name
+        got = [_filter_counts(s) for s in g.stages]
+        assert (sum(c.get("filters_masked", 0) for c in got),
+                sum(c.get("filters_compacted", 0) for c in got)) == counts
+
+
 def test_tokenize_letter_delims_match_unfused():
     """Letter delimiters + lower: classification must see RAW bytes on
     both paths (review finding: lowering before classification split

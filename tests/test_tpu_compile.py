@@ -120,6 +120,22 @@ def test_group_aggregate_compiles(topo, tpu_tier):
     assert "tpu_custom_call" in c.as_text()
 
 
+@pytest.mark.parametrize("keys,cols", [
+    (["c"], {"c": jnp.int32}),
+    (["f", "g"], {"f": ("str", 1), "g": ("str", 1)})],
+    ids=["one-hot", "boundary"])
+def test_masked_group_aggregate_compiles(topo, tpu_tier, keys, cols):
+    """A filter's mask into the group-by (``where=``): Q6's shape (one
+    constant key, the one-hot matmul with the sort fallback beside it)
+    and Q1's (two 1-byte strings, the boundary path)."""
+    sh = _one_chip(topo)
+    b = _batch(sh, (), 4096, v=jnp.float32, **cols)
+    m = jax.ShapeDtypeStruct((4096,), jnp.bool_, sharding=sh)
+    _compile(lambda x, w: kernels.group_aggregate(
+        x, keys, {"n": ("count", None), "s": ("sum", "v"),
+                  "a": ("mean", "v")}, where=w), b, m)
+
+
 def test_hash_join_compiles(topo, tpu_tier):
     """The TPU-tier packed single-gather of the join probe
     (kernels._packed_gather, gated on pallas_active())."""
