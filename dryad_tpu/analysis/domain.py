@@ -101,11 +101,13 @@ class ColSpec:
     * dense: ``[capacity, *shape] dtype`` — row_bytes = itemsize * prod
     * str: ``[capacity, repeat?, max_len] u8`` data + int32 lengths —
       row_bytes = repeat * (max_len + 4)
+    * int64: ``[capacity] int32`` upper + ``[capacity] uint32`` lower
+      words (data/columnar.Int64Column) — row_bytes = 8
 
     ``repeat`` models window axes (sliding_window) on either kind.
     """
 
-    kind: str                      # "dense" | "str"
+    kind: str                      # "dense" | "str" | "int64"
     dtype: str = "int32"
     shape: Tuple[int, ...] = ()
     max_len: int = 0
@@ -115,6 +117,8 @@ class ColSpec:
     def row_bytes(self) -> int:
         if self.kind == "str":
             return self.repeat * (self.max_len + 4)
+        if self.kind == "int64":
+            return 8
         n = 1
         for d in self.shape:
             n *= int(d)
@@ -145,6 +149,8 @@ def schema_from_store_schema(store_schema: Dict[str, Any]) -> Schema:
     for k, spec in store_schema.items():
         if spec["kind"] == "str":
             out[k] = ColSpec("str", max_len=int(spec["max_len"]))
+        elif spec["kind"] == "int64":
+            out[k] = ColSpec("int64")
         else:
             out[k] = ColSpec("dense", dtype=str(spec["dtype"]),
                              shape=tuple(int(d)
@@ -178,6 +184,8 @@ def schema_from_columns(columns: Dict[str, Any],
                 rep *= int(d)
             out[k] = ColSpec("str", max_len=int(data.shape[-1]),
                              repeat=rep)
+        elif hasattr(v, "hi") and hasattr(v, "lo"):     # Int64Column
+            out[k] = ColSpec("int64")
         else:
             out[k] = _leaf_spec(v, lead_dims)
     return out
@@ -206,7 +214,7 @@ def abstract_batch(schema: Schema, capacity: int):
     analyzer treats post-window UDFs as approximate."""
     import jax
 
-    from dryad_tpu.data.columnar import Batch, StringColumn
+    from dryad_tpu.data.columnar import Batch, Int64Column, StringColumn
     sds = jax.ShapeDtypeStruct
     cols: Dict[str, Any] = {}
     for k, spec in schema.items():
@@ -215,6 +223,9 @@ def abstract_batch(schema: Schema, capacity: int):
             cols[k] = StringColumn(
                 sds((capacity,) + mid + (spec.max_len,), np.uint8),
                 sds((capacity,) + mid, np.int32))
+        elif spec.kind == "int64":
+            cols[k] = Int64Column(sds((capacity,), np.int32),
+                                  sds((capacity,), np.uint32))
         else:
             rep = () if spec.repeat == 1 else (spec.repeat,)
             cols[k] = sds((capacity,) + rep + spec.shape,

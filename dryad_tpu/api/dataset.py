@@ -998,7 +998,17 @@ class Dataset:
     def group_by(self, keys: Sequence[str],
                  aggs: Dict[str, Tuple[str, Optional[str]]]) -> "Dataset":
         """GroupBy + decomposable aggregates: aggs maps output column ->
-        (kind, value_column), kind in sum/count/min/max/mean/any/all.
+        (kind, value_column), kind in sum/count/min/max/mean/any/all/sum64.
+
+        ``sum`` keeps its column's type: over an ``int32`` column it is an
+        ``int32`` that wraps past 2**31 (the planner's own merges of
+        counts and of any/all ride it, and a caller's ``select`` may do
+        arithmetic on the result).  ``sum64`` over an integer column of at
+        most 32 bits is exact to 64 bits: the result is a
+        ``data.columnar.Int64Column`` (two 32-bit words; the package runs
+        without x64), collected as numpy ``int64``; it can be an
+        ``order_by`` key, be stored, and be summed again with ``sum``.
+        SQL's ``SUM`` over integers is ``sum64``.
 
         Supported-workload assumption: groups are identified by a 64-bit
         key hash (ops/hashing.py).  Keys that collide in all 64 bits are
@@ -1084,7 +1094,7 @@ class Dataset:
              right_keys: Sequence[str] | None = None,
              expansion: float | None = None,
              broadcast: bool = False, how: str = "inner",
-             right_unique: bool = False) -> "Dataset":
+             right_unique: bool | str = False) -> "Dataset":
         """Equi-join.  ``how`` in inner/left/right/full: "left" keeps
         unmatched left rows with right columns zero-filled; "right" keeps
         unmatched right rows (left non-key columns zero-filled, left key
@@ -1105,7 +1115,15 @@ class Dataset:
         two distinct keys agreeing in all 64 hash bits would mis-join,
         a ~n^2/2^64 probability budget (the same one group_by/distinct
         document).  Keep right_unique off for adversarially constructed
-        keys with mismatched key dtypes."""
+        keys with mismatched key dtypes.
+
+        ``right_unique="verified"``: the right side's join columns hold
+        a key that WAS verified over its rows — a store written with
+        ``to_store(unique=...)``, which is how the SQL lowering comes to
+        pass it (sql/lower.py).  The stage's program is then the lookup
+        kernel alone: no duplicate check, no second kernel.  On rows
+        that do repeat a key the result is undefined; on data of
+        unknown uniqueness use ``True``."""
         return Dataset(self.ctx, E.Join(
             parents=(self.node, other.node), left_keys=tuple(left_keys),
             right_keys=tuple(right_keys or left_keys),
@@ -1458,17 +1476,30 @@ class Dataset:
             out = {k: v[:n] for k, v in out.items()}
         return out
 
-    def to_store(self, path: str, compression: str | None = None) -> None:
+    def to_store(self, path: str, compression: str | None = None,
+                 unique: Sequence[str] | None = None) -> None:
         """Execute and persist (ToStore + Submit,
         DryadLinqQueryable.cs:3909,4032).  ``compression="gzip"`` enables
         the per-partition compression transform (reference
-        GzipCompressionChannelTransform.cpp)."""
+        GzipCompressionChannelTransform.cpp).
+
+        ``unique=[...]`` declares one key of one or more columns: no two
+        rows agree on all of them.  The write verifies it over every row
+        (io/store.check_unique) and refuses with ``StoreKeyError`` where
+        it does not hold; ``meta.json`` then carries it, and a SQL join
+        whose build side is this table on these columns runs the lookup
+        kernel alone (sql/lower.py).  In-memory writes only."""
         from dryad_tpu.io.store import write_store
         part = self.node.partitioning
         if compression is None:
             compression = self.ctx.config.store_compression
         if compression not in (None, "gzip"):
             raise ValueError(f"unknown compression {compression!r}")
+        if unique and (self.ctx.cluster is not None or self._streaming()):
+            raise NotImplementedError(
+                "to_store(unique=...) verifies the key over rows held on "
+                "this process's devices: not on a cluster or a streamed "
+                "source")
         if self.ctx.cluster is not None:
             # parallel output: every worker writes its own partitions
             # (compression included); process 0 merges meta + commits
@@ -1493,7 +1524,7 @@ class Dataset:
             sp.set(sink=path, rows=write_store(
                 path, pd, partitioning={"kind": part.kind,
                                         "keys": list(part.keys)},
-                compression=compression))
+                compression=compression, unique=unique))
 
     def count(self) -> int:
         if self.ctx.local_debug:

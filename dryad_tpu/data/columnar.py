@@ -32,6 +32,7 @@ import numpy as np
 
 __all__ = [
     "StringColumn",
+    "Int64Column",
     "Batch",
     "Schema",
     "batch_from_numpy",
@@ -74,7 +75,55 @@ class StringColumn:
         return cls(*children)
 
 
-Column = Any  # jax.Array | StringColumn
+@jax.tree_util.register_pytree_node_class
+@dataclasses.dataclass
+class Int64Column:
+    """A 64-bit signed integer column as two 32-bit words.
+
+    The package runs without ``jax_enable_x64``, where an ``int64`` array
+    is silently cut to ``int32`` on its way to the device.  What must not
+    wrap — ``SUM`` over an integer column — is held as the value's upper
+    word (signed) and lower word (unsigned): ``value = hi * 2**32 + lo``.
+    The kernels that carry rows move the two words as two lanes
+    (ops/kernels._pack_columns_u32), a sort compares ``hi`` then ``lo``
+    (sort_lanes_for), ``collect`` and the store hand back numpy ``int64``
+    (``to_numpy``).  Arithmetic and comparisons on it are not provided:
+    the SQL binder types it ``bigint`` and rejects them at bind time.
+    """
+
+    hi: jax.Array  # [capacity] int32
+    lo: jax.Array  # [capacity] uint32
+
+    @property
+    def capacity(self) -> int:
+        return self.hi.shape[0]
+
+    def gather(self, idx: jax.Array) -> "Int64Column":
+        return Int64Column(jnp.take(self.hi, idx, axis=0),
+                           jnp.take(self.lo, idx, axis=0))
+
+    def tree_flatten(self):
+        return (self.hi, self.lo), None
+
+    @classmethod
+    def tree_unflatten(cls, aux, children):
+        return cls(*children)
+
+    @staticmethod
+    def from_numpy(values) -> "Int64Column":
+        """Host ``int64`` values -> the two words (numpy leaves)."""
+        v = np.asarray(values, np.int64)
+        return Int64Column((v >> 32).astype(np.int32),
+                           (v & 0xFFFFFFFF).astype(np.uint32))
+
+    @staticmethod
+    def to_numpy(hi, lo) -> np.ndarray:
+        """The two words (any leading shape) -> numpy ``int64``."""
+        return (np.asarray(hi).astype(np.int64) << 32) \
+            | np.asarray(lo).astype(np.int64)
+
+
+Column = Any  # jax.Array | StringColumn | Int64Column
 
 
 @jax.tree_util.register_pytree_node_class
@@ -96,7 +145,7 @@ class Batch:
     @property
     def capacity(self) -> int:
         for c in self.columns.values():
-            if isinstance(c, StringColumn):
+            if isinstance(c, (StringColumn, Int64Column)):
                 return c.capacity
             return c.shape[0]
         raise ValueError("Batch has no columns")
@@ -121,7 +170,7 @@ class Batch:
         """Row gather; ``idx`` is [new_capacity] int32.  Keeps count unless given."""
         cols = {}
         for k, v in self.columns.items():
-            if isinstance(v, StringColumn):
+            if isinstance(v, (StringColumn, Int64Column)):
                 cols[k] = v.gather(idx)
             else:
                 cols[k] = jnp.take(v, idx, axis=0)
@@ -157,6 +206,9 @@ class Batch:
                 cols[k] = StringColumn(
                     jnp.pad(v.data, ((0, extra), (0, 0))),
                     jnp.pad(v.lengths, (0, extra)))
+            elif isinstance(v, Int64Column):
+                cols[k] = Int64Column(jnp.pad(v.hi, (0, extra)),
+                                      jnp.pad(v.lo, (0, extra)))
             else:
                 pad = [(0, extra)] + [(0, 0)] * (v.ndim - 1)
                 cols[k] = jnp.pad(v, pad)
@@ -185,6 +237,8 @@ class Schema:
         for k, v in batch.columns.items():
             if isinstance(v, StringColumn):
                 fields[k] = ("str", v.max_len)
+            elif isinstance(v, Int64Column):
+                fields[k] = ("int64",)
             else:
                 fields[k] = ("dense", v.dtype, v.shape[1:])
         return cls(fields)
@@ -196,6 +250,9 @@ class Schema:
                 cols[k] = StringColumn(
                     jnp.zeros((capacity, spec[1]), jnp.uint8),
                     jnp.zeros((capacity,), jnp.int32))
+            elif spec[0] == "int64":
+                cols[k] = Int64Column(jnp.zeros((capacity,), jnp.int32),
+                                      jnp.zeros((capacity,), jnp.uint32))
             else:
                 _, dtype, trailing = spec
                 cols[k] = jnp.zeros((capacity,) + tuple(trailing), dtype)
@@ -259,6 +316,8 @@ def batch_to_numpy(batch: Batch) -> Dict[str, Any]:
     for k, v in batch.columns.items():
         if isinstance(v, StringColumn):
             out[k] = string_column_to_list(v, n)
+        elif isinstance(v, Int64Column):
+            out[k] = Int64Column.to_numpy(v.hi, v.lo)[:n]
         else:
             out[k] = np.asarray(v)[:n]
     return out
@@ -278,6 +337,11 @@ def concat_batches(batches: Sequence[Batch], capacity: int | None = None) -> Bat
             flat = [s for v in vals for s in v]
             merged[k] = string_column_from_list(
                 flat, cap, batches[0].columns[k].max_len)
+        elif isinstance(batches[0].columns[k], Int64Column):
+            wide = Int64Column.from_numpy(
+                np.pad(np.concatenate(vals), (0, cap - total)))
+            merged[k] = Int64Column(jnp.asarray(wide.hi),
+                                    jnp.asarray(wide.lo))
         else:
             arr = np.concatenate(vals, axis=0)
             pad = [(0, cap - total)] + [(0, 0)] * (arr.ndim - 1)

@@ -21,12 +21,12 @@ import numpy as np
 from jax import lax
 from jax.sharding import NamedSharding, PartitionSpec
 
-from dryad_tpu.data.columnar import Batch, StringColumn
+from dryad_tpu.data.columnar import Batch, Int64Column, StringColumn
 from dryad_tpu.parallel.mesh import batch_sharding
 
 __all__ = ["PData", "pdata_from_host", "pdata_to_host", "put_batch",
            "fetch_partitions", "replicate_tree", "collect_replicated",
-           "batch_nbytes"]
+           "batch_nbytes", "key_hashes"]
 
 
 def batch_nbytes(tree) -> int:
@@ -65,7 +65,11 @@ def put_batch(tree, mesh):
 # Bytes of one chunk of one partition (its rows: the largest power of two
 # that stays under this, and never more than the capacity).  Large enough
 # that a copy runs at the link's rate, small enough that what is moved past
-# a partition's count stays a few per cent of it.
+# a partition's count stays a few per cent of it.  A power of two ALWAYS, a
+# column shorter than one chunk included (its last chunk overlaps the one
+# before): the wide reshape of 400,000 rows of 6-11 bytes took the TPU
+# compiler 138-164 s a column where 262,144 rows take 0.7 s (PERF.md
+# section 6, PR 36).
 _FETCH_CHUNK_BYTES = 8 << 20
 
 # A chunk whose rows are narrower than this many elements leaves the device
@@ -78,8 +82,8 @@ _FETCH_LINK_WIDTH = 512
 
 
 def _fetch_chunk_rows(cap: int, row_bytes: int) -> int:
-    rows = max(1, _FETCH_CHUNK_BYTES // max(row_bytes, 1))
-    return min(1 << (rows.bit_length() - 1), max(cap, 1))
+    rows = max(1, min(_FETCH_CHUNK_BYTES // max(row_bytes, 1), cap))
+    return 1 << (rows.bit_length() - 1)
 
 
 def _row_elems(x) -> int:
@@ -261,9 +265,7 @@ class PData:
     @property
     def capacity(self) -> int:
         for c in self.batch.columns.values():
-            if isinstance(c, StringColumn):
-                return c.data.shape[1]
-            return c.shape[1]
+            return jax.tree.leaves(c)[0].shape[1]
         raise ValueError("empty PData")
 
     @property
@@ -374,6 +376,21 @@ def maybe_shrink_for_collect(pd: PData, config=None) -> PData:
     return pd if new_cap is None else shrink_pdata(pd, new_cap)
 
 
+def key_hashes(batch: Batch, counts, keys: Sequence[str]) -> np.ndarray:
+    """The 64-bit key hash of every valid row of a stacked ``[P, cap]``
+    batch over the columns ``keys``, as numpy ``uint64`` — the hash the
+    joins and the group-bys compare (ops/hashing.hash_batch_keys), taken
+    on the device, 8 bytes a row brought to the host.  What a store's
+    ``unique`` declaration is verified over (io/store.check_unique)."""
+    from dryad_tpu.ops.hashing import hash_batch_keys
+    hi, lo = jax.jit(jax.vmap(
+        lambda b: hash_batch_keys(b, list(keys))))(batch)
+    hi, lo = np.asarray(hi), np.asarray(lo)
+    live = np.arange(hi.shape[1])[None, :] < np.asarray(counts)[:, None]
+    return (hi[live].astype(np.uint64) << np.uint64(32)) \
+        | lo[live].astype(np.uint64)
+
+
 def pdata_to_host(pd: PData) -> Dict[str, Any]:
     """Collect valid rows to host, partition order preserved."""
     from dryad_tpu import native
@@ -389,8 +406,11 @@ def pdata_to_host(pd: PData) -> Dict[str, Any]:
                 n = int(counts[p])
                 vals.extend(native.unpack_rows(data[p, :n], lens[p, :n]))
             out[k] = vals
+            continue
+        if isinstance(v, Int64Column):      # the two words -> numpy int64
+            arr = Int64Column.to_numpy(v.hi, v.lo)
         else:
             arr = np.asarray(v)
-            out[k] = np.concatenate(
-                [arr[p, : counts[p]] for p in range(pd.nparts)], axis=0)
+        out[k] = np.concatenate(
+            [arr[p, : counts[p]] for p in range(pd.nparts)], axis=0)
     return out

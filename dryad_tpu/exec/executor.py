@@ -530,6 +530,30 @@ def _filter_counts(stage: Stage) -> Dict[str, int]:
     return {"filters_masked": masked, "filters_compacted": compacted}
 
 
+def _wide_sum_count(stage: Stage) -> Dict[str, int]:
+    """``int64_sums``: the integer sums a stage's group-bys accumulate in
+    64 bits (aggregate kind ``sum64``, kernels.group_aggregate).  Known
+    from the plan; {} for a stage without a group-by."""
+    groups = [op for ops in [leg.ops for leg in stage.legs] + [stage.body]
+              for op in ops if op.kind == "group"]
+    if not groups:
+        return {}
+    return {"int64_sums": sum(spec[0] == "sum64" for g in groups
+                              for spec in g.params["aggs"].values()
+                              if isinstance(spec, tuple))}
+
+
+def _join_kernel(params) -> str:
+    """Which join a stage's program holds, from the plan: ``lookup``
+    (kernels._lookup_join alone: the right side's key was verified where
+    it was written), ``hash`` (kernels.hash_join's general body alone),
+    ``checked`` (both, and a run-time duplicate check that picks)."""
+    ru = params.get("right_unique", False)
+    if not ru or params.get("how", "inner") not in ("inner", "left"):
+        return "hash"
+    return "lookup" if ru == "verified" else "checked"
+
+
 def _apply_exchange(b: Batch, ex: Exchange, scale: int, slack: int, bounds,
                     axes: tuple = (PARTITION_AXIS,),
                     slot_rows: int | None = None
@@ -647,7 +671,8 @@ class Executor:
         """``facts`` (optional) is filled while the program is traced
         with what only its shapes tell: ``join_in_bytes``, the bytes of
         a join's two inputs as the program holds them (every leg's ops
-        and exchange applied), capacity x row bytes over all shards."""
+        and exchange applied), capacity x row bytes over all shards, and
+        ``build_rows``, the rows of capacity of its right input."""
         def per_shard(*args):
             leg_batches = [
                 _squeeze(b) for b in args[:n_legs]]
@@ -717,6 +742,8 @@ class Executor:
                             x.size * x.dtype.itemsize
                             for b in (cur, rest[0])
                             for x in jax.tree.leaves(b.columns))
+                        facts["build_rows"] = \
+                            self.nparts * rest[0].capacity
                     cur, nd = _apply_op(cur, op, scale, rest,
                                         self.axes, slack)
                     rest = []
@@ -1050,7 +1077,7 @@ class Executor:
         # compare, the tiebreak included (static: the bounds' shape)
         range_attrs = ({} if bounds is None
                        else {"range_lanes": int(bounds.shape[1])})
-        filter_attrs = _filter_counts(stage)
+        filter_attrs = {**_filter_counts(stage), **_wide_sum_count(stage)}
         for attempt in range(max_retries + 1):
             # salt knobs are baked into compiled salted programs — they
             # must key the cache or a re-configured job reuses stale code
@@ -1108,6 +1135,7 @@ class Executor:
                 "out_capacity": join.params["out_capacity"] * scale,
                 "right_unique": bool(join.params.get("right_unique",
                                                      False)),
+                "join_kernel": _join_kernel(join.params),
                 **facts}
             if span is not trace.NULL:
                 span.set(program="jit_" + stage_program_name(stage),

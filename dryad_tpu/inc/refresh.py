@@ -193,7 +193,7 @@ def _merge_state(plan: DeltaPlan, prev: Dict[str, Any],
         else:
             for a, (kind, _in) in aggs.items():
                 cur, new = cols[a][i], partial[a][r]
-                if kind in ("sum", "count"):
+                if kind in ("sum", "sum64", "count"):
                     cols[a][i] = cur + new
                 elif kind == "min":
                     cols[a][i] = min(cur, new)

@@ -61,16 +61,18 @@ import os
 import shutil
 from typing import Any, Dict, List, NamedTuple, Optional, Sequence, Tuple
 
+import jax
 import numpy as np
 
 from dryad_tpu import native
-from dryad_tpu.data.columnar import Batch, StringColumn
+from dryad_tpu.data.columnar import Batch, Int64Column, StringColumn
 from dryad_tpu.exec.data import (PData, batch_nbytes, fetch_partitions,
-                                 put_batch)
+                                 key_hashes, put_batch)
 from dryad_tpu.obs import trace
 
 __all__ = ["write_store", "read_store", "store_meta", "build_meta",
            "schema_row_bytes", "StoreIntegrityError", "is_remote_store",
+           "StoreKeyError", "check_unique",
            "append_store", "store_generation", "parts_since",
            "part_checksums", "part_layout", "store_schema", "kept_schema",
            "StoreWriter",
@@ -87,6 +89,9 @@ _FORMAT_VERSION = {"fnv64": 3, CHECKSUM_ALGO: 4}
 
 _REMOTE_SCHEMES = ("s3://", "hdfs://")
 
+# schema kinds whose rows are a pair of arrays above the store
+_PAIR_KINDS = ("str", "int64")
+
 
 def is_remote_store(path: str) -> bool:
     """True for store paths served by a remote byte target (s3:// object
@@ -101,13 +106,40 @@ class StoreIntegrityError(RuntimeError):
     ms_fprint.cpp)."""
 
 
+class StoreKeyError(ValueError):
+    """The rows do not bear out a ``unique`` declaration: two of them
+    share the key (or, to the join kernels, look as if they did: equal
+    64-bit key hashes), or the key names no column of theirs."""
+
+
+def check_unique(hashes: np.ndarray, unique: Sequence[str],
+                 what: str) -> None:
+    """Hold rows to a ``unique`` declaration, given their 64-bit key
+    hashes (``exec.data.key_hashes``): sorted, adjacent pairs compared —
+    the check ``kernels.hash_join(right_unique=True)`` makes of its right
+    side in every run, made once, where the rows are written or
+    registered.  Two distinct keys that collide in all 64 bits are
+    refused as duplicates (the lookup join tells rows apart by that hash),
+    and so is a key that hashes to the all-ones value padding rows sort
+    under."""
+    s = np.sort(hashes)
+    dup = int((s[1:] == s[:-1]).sum())
+    if dup or (s.size and s[-1] == np.uint64(0xFFFFFFFFFFFFFFFF)):
+        raise StoreKeyError(
+            f"{what}: the columns {list(unique)} are declared unique, but "
+            + (f"{dup} of {s.size} rows repeat an earlier row's key"
+               if dup else "a key hashes to the padding rows' value"))
+
+
 # ---------------------------------------------------------------------------
 # the layout
 
 
 class Leaf(NamedTuple):
-    """One leaf of a partition file: a dense column's array, or the data
-    (``str_part`` 0) or the lengths (``str_part`` 1) of a string column."""
+    """One leaf of a partition file: a dense column's array (``str_part``
+    None), or the first (``str_part`` 0) or the second (1) array of a
+    column held as two: a string column's data and lengths, a 64-bit
+    integer column's upper and lower words."""
     column: str
     str_part: Optional[int]
     dtype: np.dtype
@@ -120,7 +152,9 @@ class Leaf(NamedTuple):
 def part_layout(schema: Dict[str, Any], n: int = 0) -> List[Leaf]:
     """THE layout: the leaves of a partition of ``n`` rows in file order
     (columns sorted by name; a string column is its padded bytes, then its
-    int32 lengths), each with where it starts and how long it is.  Rows
+    int32 lengths; an ``int64`` column its int32 upper words, then its
+    uint32 lower words, as the device holds it), each with where it
+    starts and how long it is.  Rows
     [s, e) of a leaf are the ``(e - s) * row_bytes`` bytes at
     ``offset + s * row_bytes``.  Every allocation, segment order, digest
     and ranged read of a store is read off this."""
@@ -131,6 +165,9 @@ def part_layout(schema: Dict[str, Any], n: int = 0) -> List[Leaf]:
         if spec["kind"] == "str":
             parts = [(0, np.dtype(np.uint8), (int(spec["max_len"]),)),
                      (1, np.dtype(np.int32), ())]
+        elif spec["kind"] == "int64":
+            parts = [(0, np.dtype(np.int32), ()),
+                     (1, np.dtype(np.uint32), ())]
         else:
             parts = [(None, np.dtype(spec["dtype"]),
                       tuple(int(d) for d in spec.get("shape", ())))]
@@ -157,6 +194,8 @@ def store_schema(schema: Dict[str, Any]) -> Dict[str, Any]:
     for k, spec in schema.items():
         if spec["kind"] == "str":
             out[k] = {"kind": "str", "max_len": spec["max_len"]}
+        elif spec["kind"] == "int64":
+            out[k] = {"kind": "int64"}
         else:
             out[k] = {"kind": "dense", "dtype": spec["dtype"],
                       "shape": list(spec.get("shape", ()))}
@@ -170,6 +209,8 @@ def pdata_schema(pd: "PData") -> Dict[str, Any]:
     for k, v in pd.batch.columns.items():
         if isinstance(v, StringColumn):
             schema[k] = {"kind": "str", "max_len": int(v.data.shape[2])}
+        elif isinstance(v, Int64Column):
+            schema[k] = {"kind": "int64"}
         else:
             schema[k] = {"kind": "dense", "dtype": np.dtype(v.dtype).name,
                          "shape": list(v.shape[2:])}
@@ -177,12 +218,12 @@ def pdata_schema(pd: "PData") -> Dict[str, Any]:
 
 
 def _columns(layout: List[Leaf], arrays) -> Dict[str, Any]:
-    """name -> array (dense) or (data, lengths) (string), from one array a
-    leaf in file order — the shape a chunk's and a partition's rows have
-    everywhere above the store."""
+    """name -> array (dense), (data, lengths) (string) or (upper words,
+    lower words) (int64), from one array a leaf in file order — the shape
+    a chunk's and a partition's rows have everywhere above the store."""
     cols: Dict[str, Any] = {}
     for leaf, a in zip(layout, arrays):
-        # a string column's data (str_part 0) comes first, then its lengths
+        # of a column held as two arrays, str_part 0 comes first
         cols[leaf.column] = (cols[leaf.column], a) if leaf.str_part else a
     return cols
 
@@ -414,7 +455,8 @@ def build_meta(schema: Dict[str, Any], counts: List[int],
                generation: int = 0,
                part_generations: Optional[List[int]] = None,
                leaf_checksums: Optional[List[List[str]]] = None,
-               form: Optional[Dict[str, Any]] = None
+               form: Optional[Dict[str, Any]] = None,
+               unique: Optional[Sequence[str]] = None
                ) -> Dict[str, Any]:
     """The ONE meta.json constructor (``StoreWriter.commit`` calls it for
     every writer), so format_version / field skew cannot happen.
@@ -439,7 +481,12 @@ def build_meta(schema: Dict[str, Any], counts: List[int],
     ``leaf_checksums[p]`` are partition p's leaf digests in file order —
     what a read of some columns only verifies those columns by; a writer
     that cannot carry them (the cluster writer's allgather of one digest a
-    partition) leaves them out and readers verify ``checksums`` alone."""
+    partition) leaves them out and readers verify ``checksums`` alone.
+
+    ``unique``: the columns that together are a key of the stored rows —
+    no two rows agree on all of them — VERIFIED by the writer over the
+    rows it wrote (``write_store``); the field is left out where none was
+    declared, and a store without it has no key."""
     rb = schema_row_bytes(schema)
     form = form or checksum_form()
     return {
@@ -461,6 +508,7 @@ def build_meta(schema: Dict[str, Any], counts: List[int],
         "part_generations": (list(part_generations)
                              if part_generations is not None
                              else [0] * len(counts)),
+        **({"unique": list(unique)} if unique else {}),
     }
 
 
@@ -553,9 +601,18 @@ class StoreWriter:
                  compression: Optional[str] = None,
                  capacity: Optional[int] = None,
                  old: Optional[Dict[str, Any]] = None,
-                 shared: bool = False):
+                 shared: bool = False,
+                 unique: Optional[Sequence[str]] = None):
         if compression not in (None, "gzip"):
             raise ValueError(f"unknown compression {compression!r}")
+        if old is not None and old.get("unique"):
+            # the key was verified over the rows of the write; appended
+            # rows would have to be checked against every row there is
+            raise StoreKeyError(
+                f"{path}: the store declares {old['unique']} unique, and "
+                "an append is not verified against the stored rows — "
+                "write the store anew (to_store(unique=...))")
+        self.unique = list(unique) if unique else None
         self.schema = schema
         self.partitioning = partitioning
         self.compression = compression
@@ -618,7 +675,8 @@ class StoreWriter:
                                   partitioning=self.partitioning,
                                   compression=self.compression,
                                   capacity=self.capacity,
-                                  leaf_checksums=leaves)
+                                  leaf_checksums=leaves,
+                                  unique=self.unique)
                 if self._gen is not None:
                     meta["generation"] = self._gen
             else:
@@ -661,13 +719,14 @@ def fetch_part_segments(pd: PData, schema, counts: np.ndarray):
     for leaf in part_layout(schema):
         v = pd.batch.columns[leaf.column]
         leaves.append(v if leaf.str_part is None
-                      else (v.data, v.lengths)[leaf.str_part])
+                      else jax.tree.leaves(v)[leaf.str_part])
     return fetch_partitions(leaves, counts)
 
 
 def write_store(path: str, pd: PData,
                 partitioning: Optional[Dict[str, Any]] = None,
-                compression: Optional[str] = None) -> int:
+                compression: Optional[str] = None,
+                unique: Optional[Sequence[str]] = None) -> int:
     """Persist a PData (ToStore, DryadLinqQueryable.cs:3909) to a local,
     ``s3://`` or ``hdfs://`` path, atomically (``StoreWriter``); returns
     the rows it wrote.
@@ -675,12 +734,23 @@ def write_store(path: str, pd: PData,
     ``compression="gzip"`` writes level-1 gzip partition files (the
     per-channel compression transform of the reference,
     GzipCompressionChannelTransform.cpp).  Checksums are over the
-    UNCOMPRESSED segments, verified on read."""
+    UNCOMPRESSED segments, verified on read.
+
+    ``unique``: columns that together are a key of these rows.  Verified
+    here, over all partitions, before a byte is written (``check_unique``;
+    ``StoreKeyError`` where two rows share the key), then recorded in the
+    manifest, where ``sql.Catalog.register_store`` finds it."""
     with trace.span("store.write", "io", partitions=pd.nparts) as sp:
         counts = np.asarray(pd.counts)
         schema = pdata_schema(pd)
+        if unique:
+            missing = [k for k in unique if k not in schema]
+            if missing:
+                raise StoreKeyError(f"{path}: unique names {missing}, no "
+                                    f"column of {sorted(schema)}")
+            check_unique(key_hashes(pd.batch, counts, unique), unique, path)
         writer = StoreWriter(path, schema, partitioning, compression,
-                             pd.capacity)
+                             pd.capacity, unique=unique)
         segments = []
         fetched = fetch_part_segments(pd, schema, counts)
         for p in range(pd.nparts):
@@ -966,7 +1036,7 @@ def read_store(path: str, mesh, capacity: Optional[int] = None,
         # partitioning claims)
         concat: Dict[str, Any] = {}
         for k in schema:
-            if schema[k]["kind"] == "str":
+            if schema[k]["kind"] in _PAIR_KINDS:
                 concat[k] = (np.concatenate([pr[k][0] for pr in part_rows]),
                              np.concatenate([pr[k][1] for pr in part_rows]))
             else:
@@ -979,7 +1049,7 @@ def read_store(path: str, mesh, capacity: Optional[int] = None,
         offs = np.cumsum([0] + sizes)
         part_rows = [{k: ((concat[k][0][offs[p]:offs[p + 1]],
                            concat[k][1][offs[p]:offs[p + 1]])
-                          if schema[k]["kind"] == "str"
+                          if schema[k]["kind"] in _PAIR_KINDS
                           else concat[k][offs[p]:offs[p + 1]])
                       for k in schema} for p in range(nparts)]
         return _stack_partitions(schema, part_rows, sizes, cap, mesh)
@@ -1007,6 +1077,13 @@ def _stack_partitions(schema, part_rows: List[Dict[str, Any]],
                     sd[p, : counts[p]] = d
                     sl[p, : counts[p]] = l
                 cols[k] = StringColumn(sd, sl)
+            elif spec["kind"] == "int64":
+                words = [np.zeros((nparts, cap), dt)
+                         for dt in (np.int32, np.uint32)]
+                for p in range(nparts):
+                    for w, rows in zip(words, part_rows[p][k]):
+                        w[p, : counts[p]] = rows
+                cols[k] = Int64Column(*words)
             else:
                 first = part_rows[0][k]
                 stacked = np.zeros((nparts, cap) + first.shape[1:],

@@ -26,7 +26,7 @@ from typing import Any, Dict, List, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 
-from dryad_tpu.data.columnar import Batch, StringColumn
+from dryad_tpu.data.columnar import Batch, Int64Column, StringColumn
 from dryad_tpu.ops.hashing import hash_batch_keys
 
 __all__ = [
@@ -38,7 +38,7 @@ __all__ = [
     "concat2", "take", "AGG_KINDS",
 ]
 
-AGG_KINDS = ("sum", "count", "min", "max", "mean", "any", "all")
+AGG_KINDS = ("sum", "count", "min", "max", "mean", "any", "all", "sum64")
 
 
 def searchsorted_small(bounds: jax.Array, q: jax.Array,
@@ -88,6 +88,10 @@ def _pack_columns_u32(cols: Dict[str, Any]) -> Tuple[List[jax.Array], List]:
             lanes.extend(w[:, j] for j in range(k))
             lanes.append(v.lengths.astype(jnp.uint32))
             spec.append((name, "str", L, k + 1))
+        elif isinstance(v, Int64Column):
+            lanes.append(jax.lax.bitcast_convert_type(v.hi, jnp.uint32))
+            lanes.append(v.lo)
+            spec.append((name, "i64", None, 2))
         else:
             tail = v.shape[1:]
             flat = v.reshape(v.shape[0], -1) if tail else v[:, None]
@@ -121,6 +125,9 @@ def _unpack_columns_u32(lanes: List[jax.Array], spec: List) -> Dict[str, Any]:
                 jnp.stack(w[:-1], axis=1), jnp.uint8)
             data = data4.reshape(data4.shape[0], -1)[:, :L]
             cols[name] = StringColumn(data, w[-1].astype(jnp.int32))
+        elif kind == "i64":
+            cols[name] = Int64Column(
+                jax.lax.bitcast_convert_type(w[0], jnp.int32), w[1])
         else:
             dtype, tail = meta
             if dtype.itemsize == 4:
@@ -452,6 +459,11 @@ def _string_sort_lanes(col: StringColumn, descending: bool) -> List[jax.Array]:
 def sort_lanes_for(col, descending: bool = False) -> List[jax.Array]:
     if isinstance(col, StringColumn):
         return _string_sort_lanes(col, descending)
+    if isinstance(col, Int64Column):
+        # the signed upper word, sign bit flipped, then the lower word
+        lanes = [jax.lax.bitcast_convert_type(col.hi, jnp.uint32)
+                 ^ jnp.uint32(0x80000000), col.lo]
+        return [~l for l in lanes] if descending else lanes
     return _dense_sort_lanes(col, descending)
 
 
@@ -463,7 +475,7 @@ def _lanes_reconstructible(col) -> bool:
     they keep riding the packed value path."""
     if isinstance(col, StringColumn):
         return True
-    if col.ndim != 1:
+    if isinstance(col, Int64Column) or col.ndim != 1:
         return False
     if col.dtype in (jnp.int64, jnp.uint64, jnp.float64):
         return False
@@ -701,6 +713,74 @@ def _neutral_for(kind: str, dtype):
     raise ValueError(kind)
 
 
+# ---------------------------------------------------------------------------
+# 64-bit integer sums on two 32-bit words (the package runs without x64)
+#
+# ``("sum64", column)`` over a 1-D integer column of at most 32 bits, and
+# ``("sum", column)`` over an Int64Column (the merge of partial wide
+# sums), give an Int64Column: exact modulo 2**64, so exact for any 2**24
+# rows of any int32 values (|total| < 2**55).
+
+
+def _is_wide_sum(kind: str, col) -> bool:
+    return kind == "sum64" or (kind == "sum"
+                               and isinstance(col, Int64Column))
+
+
+def _wide_words(col) -> Tuple[jax.Array, jax.Array]:
+    """(upper word int32, lower word uint32) of the 64-bit values a wide
+    sum adds up: an Int64Column as it is, a narrower integer column
+    (_check_wide_aggs has seen to that) sign- or zero-extended."""
+    if isinstance(col, Int64Column):
+        return col.hi, col.lo
+    if jnp.issubdtype(col.dtype, jnp.unsignedinteger):
+        return (jnp.zeros(col.shape, jnp.int32), col.astype(jnp.uint32))
+    v = col.astype(jnp.int32)
+    return v >> 31, jax.lax.bitcast_convert_type(v, jnp.uint32)
+
+
+def _wide_add(a, b):
+    """(hi, lo) + (hi, lo) modulo 2**64 — associative, so it serves a
+    scan and a reduce."""
+    lo = a[1] + b[1]
+    return a[0] + b[0] + (lo < b[1]).astype(jnp.int32), lo
+
+
+def _wide_sub(a, b):
+    """(hi, lo) - (hi, lo) modulo 2**64: the lower words' difference and
+    its borrow."""
+    return (a[0] - b[0] - (a[1] < b[1]).astype(jnp.int32), a[1] - b[1])
+
+
+def _wide_prefix(hi: jax.Array, lo: jax.Array):
+    """Inclusive prefix sums of 64-bit values held as words, in two
+    streamed 32-bit passes: the lower word is the wrapping uint32 prefix;
+    a step carried where that prefix came out below what it added; the
+    upper word is the prefix of the values' upper words and the carries."""
+    from dryad_tpu.ops.pallas_kernels import prefix_sum
+    plo = prefix_sum(lo)
+    return prefix_sum(hi + (plo < lo).astype(jnp.int32)), plo
+
+
+def _check_wide_aggs(batch: Batch, aggs) -> None:
+    """An Int64Column can be summed and nothing else (no lowering holds a
+    64-bit min/max/mean); ``sum64`` reads a narrow integer column."""
+    for _out, (kind, vname) in aggs.items():
+        if kind == "count":
+            continue
+        col = batch.columns[vname]
+        if isinstance(col, Int64Column) and kind != "sum":
+            raise ValueError(f"aggregate {kind!r} over the 64-bit integer "
+                             f"column {vname!r}: only sum is provided")
+        if kind == "sum64" and (
+                isinstance(col, (StringColumn, Int64Column))
+                or col.ndim != 1
+                or not jnp.issubdtype(col.dtype, jnp.integer)
+                or col.dtype.itemsize > 4):
+            raise ValueError("a 64-bit sum (sum64) needs a 1-D integer "
+                             f"column of at most 32 bits, got {col!r}")
+
+
 def _boundary_eligible(batch: Batch, aggs) -> Tuple[bool, str | None]:
     """Can this agg set run on the boundary-carry path?  Returns
     (ok, the single min/max order column or None).  Requirements: sum/
@@ -713,6 +793,8 @@ def _boundary_eligible(batch: Batch, aggs) -> Tuple[bool, str | None]:
         if kind == "count":
             continue
         col = batch.columns[vname]
+        if _is_wide_sum(kind, col):
+            continue
         if isinstance(col, StringColumn) or col.ndim != 1:
             return False, None
         if kind in ("sum", "mean"):
@@ -754,8 +836,16 @@ def group_aggregate(batch: Batch, key_names: Sequence[str],
     """GroupBy + decomposable aggregation.
 
     aggs: out_name -> (kind, value_column | None).  Kinds: sum, count, min,
-    max, mean, any, all.  Output batch has the key columns (one representative
-    row per group) plus one column per aggregate; count = number of groups.
+    max, mean, any, all, sum64.  Output batch has the key columns (one
+    representative row per group) plus one column per aggregate; count =
+    number of groups.
+
+    ``sum`` accumulates in its column's own type (an ``int32`` sum wraps);
+    ``sum64`` over an integer column of at most 32 bits, and ``sum`` over
+    an Int64Column (the merge of partial 64-bit sums), accumulate in two
+    32-bit words and give an Int64Column, exact modulo 2**64, on the
+    boundary and the scan lowering (the one-hot path takes float sums
+    only).
 
     where: optional ``bool[capacity]`` row mask (a scalar broadcasts) —
     the groups of the rows that are valid AND kept, i.e. of
@@ -787,6 +877,7 @@ def group_aggregate(batch: Batch, key_names: Sequence[str],
     extremes.  Groups containing NaN can therefore answer differently
     across the two lowerings; all other inputs agree exactly.
     """
+    _check_wide_aggs(batch, aggs)
     ok, minmax_col = _boundary_eligible(batch, aggs)
     if ok:
         fallback = lambda b: _group_aggregate_boundary(  # noqa: E731
@@ -823,7 +914,7 @@ def _matmul_group_eligible(batch: Batch, key_names, aggs) -> bool:
         if kind not in ("sum", "mean"):
             return False
         col = batch.columns[vname]
-        if isinstance(col, StringColumn) or \
+        if isinstance(col, (StringColumn, Int64Column)) or \
                 not jnp.issubdtype(col.dtype, jnp.floating) or \
                 col.dtype.itemsize != 4:
             return False
@@ -994,8 +1085,12 @@ def _group_aggregate_boundary(batch: Batch, key_names: Sequence[str],
             if a.dtype != jnp.uint32 else a
 
     sum_cols: Dict[str, jax.Array] = {}     # cumsum inputs, native dtype
+    wide: set = set()       # the entries summed in 64 bits ("#w:" + name)
     for _out, (kind, vname) in aggs.items():
-        if kind in ("sum", "mean") and vname not in sum_cols:
+        if kind != "count" and _is_wide_sum(kind, batch.columns[vname]):
+            sum_cols["#w:" + vname] = batch.columns[vname]
+            wide.add("#w:" + vname)
+        elif kind in ("sum", "mean") and vname not in sum_cols:
             sum_cols[vname] = batch.columns[vname]
         elif kind in ("any", "all"):
             ik = "#i:" + vname
@@ -1006,8 +1101,9 @@ def _group_aggregate_boundary(batch: Batch, key_names: Sequence[str],
     # the sorted column is rebuilt from the sorted key lane instead —
     # one fewer sort operand (sort cost is linear in operands, measured)
     rebuild_sum = (minmax_col is not None and minmax_col in sum_cols)
-    carry = [_as_u32(v) for name, v in sum_cols.items()
-             if not (rebuild_sum and name == minmax_col)]
+    carry = [_as_u32(lane) for name, v in sum_cols.items()
+             if not (rebuild_sum and name == minmax_col)
+             for lane in jax.tree.leaves(v)]
     if dense_fast:
         pack_spec = None
     else:
@@ -1036,6 +1132,19 @@ def _group_aggregate_boundary(batch: Batch, key_names: Sequence[str],
     csums: Dict[str, Tuple[jax.Array, ...]] = {}
     j = 0
     for name, v in sum_cols.items():
+        if name in wide:
+            if isinstance(v, Int64Column):
+                sv = Int64Column(jax.lax.bitcast_convert_type(
+                    scarry[n_pack + j], jnp.int32), scarry[n_pack + j + 1])
+                j += 2
+            else:
+                sv = scarry[n_pack + j]
+                j += 1
+                if v.dtype != jnp.uint32:
+                    sv = jax.lax.bitcast_convert_type(sv, v.dtype)
+            csums[name] = _wide_prefix(
+                *(jnp.where(svalid, w, 0) for w in _wide_words(sv)))
+            continue
         if rebuild_sum and name == minmax_col:
             sv = _dense_lanes_invert([svord], v.dtype, False)
         else:
@@ -1096,6 +1205,13 @@ def _group_aggregate_boundary(batch: Batch, key_names: Sequence[str],
     for name, v in sum_cols.items():
         o = cs_off[name]
         c = dl[o]
+        if name in wide:
+            # a group's sum: its end row's prefix less the previous
+            # group's, the borrow taken off the upper word
+            c = (jax.lax.bitcast_convert_type(c, jnp.int32), dl[o + 1])
+            dcs[name] = Int64Column(*_wide_sub(
+                c, (_shift_fwd(c[0], 0), _shift_fwd(c[1], 0))))
+            continue
         if v.dtype != jnp.uint32:
             c = jax.lax.bitcast_convert_type(c, v.dtype)
         if v.dtype == jnp.float32:
@@ -1111,6 +1227,8 @@ def _group_aggregate_boundary(batch: Batch, key_names: Sequence[str],
     for out_name, (kind, vname) in aggs.items():
         if kind == "count":
             o = cnt_g
+        elif _is_wide_sum(kind, batch.columns[vname]):
+            o = dcs["#w:" + vname]
         elif kind == "sum":
             o = dcs[vname]
         elif kind == "mean":
@@ -1197,7 +1315,9 @@ def _group_aggregate_scan(batch: Batch, key_names: Sequence[str],
     for out_name, (kind, vname) in aggs.items():
         if kind == "count":
             continue
-        if kind in ("sum", "mean"):
+        if _is_wide_sum(kind, scols[vname]):
+            _slot("sum64", vname, _wide_words(scols[vname]), _wide_add)
+        elif kind in ("sum", "mean"):
             _slot("sum", vname, scols[vname], jnp.add)
         elif kind == "min":
             _slot("min", vname, scols[vname], jnp.minimum)
@@ -1215,6 +1335,8 @@ def _group_aggregate_scan(batch: Batch, key_names: Sequence[str],
     for out_name, (kind, vname) in aggs.items():
         if kind == "count":
             o = run_cnt
+        elif _is_wide_sum(kind, scols[vname]):
+            o = Int64Column(*scanned[slots[("sum64", vname)]])
         elif kind in ("sum", "mean"):
             s = scanned[slots[("sum", vname)]]
             if kind == "sum":
@@ -1258,6 +1380,9 @@ def _mask_rows(col, keep: jax.Array):
         m2 = keep.reshape(-1, 1)
         return StringColumn(jnp.where(m2, col.data, 0),
                             jnp.where(keep, col.lengths, 0))
+    if isinstance(col, Int64Column):
+        return Int64Column(jnp.where(keep, col.hi, 0),
+                           jnp.where(keep, col.lo, 0))
     m = keep.reshape(keep.shape + (1,) * (col.ndim - 1))
     return jnp.where(m, col, 0)
 
@@ -1317,8 +1442,11 @@ def _seg_scan_multi(vals_ops, is_start: jax.Array):
         fb, vb = b[0], b[1:]
         out = []
         for (xa, xb, (_, op)) in zip(va, vb, vals_ops):
-            m = fb.reshape(fb.shape + (1,) * (xa.ndim - 1))
-            out.append(jnp.where(m, xb, op(xa, xb)))
+            # a value may be a tuple of arrays (a 64-bit sum's words)
+            out.append(jax.tree.map(
+                lambda y, z: jnp.where(
+                    fb.reshape(fb.shape + (1,) * (y.ndim - 1)), y, z),
+                xb, op(xa, xb)))
         return (fa | fb,) + tuple(out)
 
     res = jax.lax.associative_scan(
@@ -1783,6 +1911,7 @@ def _join_out_names(left: Batch, right: Batch, right_keys, suffix: str):
     return rmap
 
 
+@jax.named_scope("lookup_join")     # a trace tells its sorts from a group-by's
 def _lookup_join(left: Batch, right: Batch, left_keys: Sequence[str],
                  right_keys: Sequence[str], out_capacity: int,
                  suffix: str, how: str) -> Tuple[Batch, jax.Array]:
@@ -1793,23 +1922,36 @@ def _lookup_join(left: Batch, right: Batch, left_keys: Sequence[str],
     gather (~10.7 ns/row x columns x out_capacity, measured — the
     dominant join cost).  With at most ONE right row per key, each left
     row is its own output row, so the join is a merge: sort the union of
-    both sides by 64-bit key hash with rights first in each run, forward-
-    fill the right payload by segmented max (a single fused multi-scan —
-    at most one right per segment, everything else contributes zero), and
-    compact the left rows.  Zero gathers.
+    both sides by 64-bit key hash with rights first in each run, hand
+    each left row the payload of the latest right row before it, and
+    compact the left rows.
 
-    Match verification: when the two sides' key columns pack to the SAME
-    u32 lane layout (same dtype / string max_len — the common case), the
-    right row's packed key lanes ride the fill and each left row
-    byte-compares them against its own carried key lanes, so a 64-bit
-    hash collision is caught exactly like the general kernel's
-    _keys_equal.  When the layouts differ (e.g. joining an i32 key to an
-    i64 key column), verification falls back to the 64-bit hash pair
-    itself — the same ~n^2/2^64 budget every hash group documents.  The
-    caller-facing ``right_unique`` path also RUNTIME-verifies right-side
-    uniqueness and falls back to the general kernel on duplicates
-    (covering hash-collision-induced apparent duplicates).
+    The hand-down is a prefix SUM, not a scan over segments: the right
+    side alone is put in key-hash order first (it is the small side), and
+    each of its rows carries its payload lanes LESS the previous right
+    row's (uint32, wrapping).  Valid rights keep that order in the union
+    (their hashes are distinct), so the wrapping prefix sum of a lane
+    over the union telescopes to the latest right row's value at every
+    position — one streamed pass a lane (pallas_kernels.prefix_sum) where
+    a segmented associative scan is log-depth in passes and, unrolled by
+    the compiler, hundreds of MB of program at millions of rows.
+
+    Match verification: a left row matches when a right row precedes it
+    and that row's key is its own.  When the two sides' key columns pack
+    to the SAME u32 lane layout (same dtype / string max_len — the common
+    case), the right row's packed key lanes are handed down with the
+    payload and each left row byte-compares them against its own carried
+    key lanes, so a 64-bit hash collision is caught exactly like the
+    general kernel's _keys_equal.  When the layouts differ (e.g. joining
+    an i32 key to an i64 key column), the right row's 64-bit hash pair is
+    handed down and compared instead — the same ~n^2/2^64 budget every
+    hash group documents.  The caller-facing ``right_unique=True`` path
+    also RUNTIME-verifies right-side uniqueness and falls back to the
+    general kernel on duplicates (covering hash-collision-induced
+    apparent duplicates); on duplicates this kernel's result is
+    undefined.
     """
+    from dryad_tpu.ops.pallas_kernels import prefix_sum
     lhi, llo = hash_batch_keys(left, left_keys)
     rhi, rlo = hash_batch_keys(right, right_keys)
     lvalid = left.valid_mask()
@@ -1819,18 +1961,11 @@ def _lookup_join(left: Batch, right: Batch, left_keys: Sequence[str],
     cl, cr = left.capacity, right.capacity
     n = cl + cr
 
-    hi = jnp.concatenate([lhi, rhi])
-    lo = jnp.concatenate([llo, rlo])
-    # rights sort BEFORE lefts within a key run, so a forward fill sees
-    # the payload
-    side = jnp.concatenate([jnp.ones((cl,), jnp.uint32),
-                            jnp.zeros((cr,), jnp.uint32)])
-
     lpack, lspec = _pack_columns_u32(dict(left.columns))
     rmap = _join_out_names(left, right, right_keys, suffix)
     rpack, rspec = _pack_columns_u32(
         {name: right.columns[k] for k, name in rmap})
-    # byte verification (carried packed key lanes): only when both
+    # byte verification (handed-down packed key lanes): only when both
     # sides' key columns pack identically — offsets of the left key
     # lanes within lpack, and the right keys packed under the left
     # names so the specs are directly comparable
@@ -1839,8 +1974,6 @@ def _lookup_join(left: Batch, right: Batch, left_keys: Sequence[str],
     for entry in lspec:
         loff[entry[0]] = (off, entry[1:])
         off += entry[3]
-    vpack: List[jax.Array] = []
-    lkey_lane_idx: List[int] = []
     vlanes, vspec = _pack_columns_u32(
         {ln: right.columns[rn]
          for ln, rn in zip(left_keys, right_keys)})
@@ -1849,42 +1982,49 @@ def _lookup_join(left: Batch, right: Batch, left_keys: Sequence[str],
               and all(ln in loff and loff[ln][1] == entry[1:]
                       for ln, entry in zip(left_keys, vspec)))
     if verify:
-        vpack = vlanes
+        own: List[int] = []        # lpack lanes the handed-down key meets
         for ln, entry in zip(left_keys, vspec):
             o = loff[ln][0]
-            lkey_lane_idx.extend(range(o, o + entry[3]))
-    nv = len(vpack)
+            own.extend(range(o, o + entry[3]))
+    else:
+        vlanes = [rhi, rlo]
+    nr = len(rpack)
+
+    # the right side alone in key-hash order (an index sort and one
+    # packed gather: it is the small side), each row less its predecessor
+    _, _, rorder = jax.lax.sort(
+        (rhi, rlo, jnp.arange(cr, dtype=jnp.int32)), num_keys=3,
+        is_stable=False)
+    hand = jnp.take(jnp.stack(rpack + vlanes, axis=1), rorder, axis=0)
+    hand = hand - jnp.concatenate([jnp.zeros_like(hand[:1]), hand[:-1]])
+
+    # rights sort BEFORE lefts within a key run, so the latest right row
+    # before a left row of its key is its match
     zl = jnp.zeros((cr,), jnp.uint32)
     zr = jnp.zeros((cl,), jnp.uint32)
     lanes = [jnp.concatenate([l, zl]) for l in lpack]
-    nr = len(rpack)
-    lanes += [jnp.concatenate([zr, r]) for r in rpack]
-    lanes.append(jnp.concatenate([zr, rvalid.astype(jnp.uint32)]))
-    lanes += [jnp.concatenate([zr, v]) for v in vpack]
-
-    skeys, sl = _sort_carrying([hi, lo, side], lanes, n, stable=False)
+    lanes += [jnp.concatenate([zr, hand[:, j]])
+              for j in range(hand.shape[1])]
+    srhi, srlo = jnp.take(rhi, rorder), jnp.take(rlo, rorder)
+    skeys, sl = _sort_carrying(
+        [jnp.concatenate([lhi, srhi]), jnp.concatenate([llo, srlo]),
+         jnp.concatenate([jnp.ones((cl,), jnp.uint32), zl])],
+        lanes, n, stable=False)
     shi, slo, sside = skeys
     n_valid = left.count + right.count
-    is_start, _is_end, _ng = _segment_flags(
-        _lane_differs(shi, slo), n_valid)
-
-    # forward-fill the right payload + presence (+ the verify key lanes)
-    # within each key segment: one fused multi-scan of max ops (<=1
-    # right per segment, zeros elsewhere, so max IS the fill)
-    fill_in = [(sl[len(lpack) + j], jnp.maximum)
-               for j in range(nr + 1 + nv)]
-    filled = _seg_scan_multi(fill_in, is_start) if fill_in else []
-    present = filled[nr] > 0
-    if verify:
-        # byte-equality of the filled right key lanes vs each left
-        # row's own carried key lanes — exact collision rejection
-        eq = jnp.ones((n,), jnp.bool_)
-        for j, li in enumerate(lkey_lane_idx):
-            eq = eq & (filled[nr + 1 + j] == sl[li])
-        present = present & eq
-
     idx = jnp.arange(n, dtype=jnp.int32)
-    is_left = (sside == 1) & (idx < n_valid)
+    live = idx < n_valid            # valid rows sort before the sentinels
+    is_left = (sside == 1) & live
+
+    # hand the right rows' lanes down: the wrapping prefix sum of the
+    # differences is the latest right row's value
+    filled = [prefix_sum(sl[len(lpack) + j])
+              for j in range(nr + len(vlanes))]
+    present = prefix_sum(((sside == 0) & live).astype(jnp.int32)) > 0
+    mine = [sl[li] for li in own] if verify else [shi, slo]
+    for got, want in zip(filled[nr:], mine):
+        present = present & (got == want)
+
     keep = is_left & present if how == "inner" else is_left
     total = keep.sum(dtype=jnp.int32)
 
@@ -1913,7 +2053,8 @@ def _lookup_join(left: Batch, right: Batch, left_keys: Sequence[str],
 def hash_join(left: Batch, right: Batch, left_keys: Sequence[str],
               right_keys: Sequence[str], out_capacity: int,
               suffix: str = "_r", how: str = "inner",
-              right_unique: bool = False) -> Tuple[Batch, jax.Array]:
+              right_unique: bool | str = False
+              ) -> Tuple[Batch, jax.Array]:
     """Equi-join; output columns = left columns + right non-key columns
     (right name suffixed on collision).  Returns ``(batch, overflow)``.
 
@@ -1945,7 +2086,16 @@ def hash_join(left: Batch, right: Batch, left_keys: Sequence[str],
     64-bit hashes, the gather-free merge-fill path (_lookup_join) runs;
     duplicates (or hash collisions that look like them) fall back to this
     general kernel inside the same compiled program (lax.cond).
+
+    ``right_unique="verified"`` (inner/left only): the right side's key
+    was held to that very check where its rows were written
+    (io/store.check_unique, 64-bit hash collisions included), so the
+    program is _lookup_join alone — no check, no ``cond``, no second
+    kernel.
     """
+    if right_unique == "verified" and how in ("inner", "left"):
+        return _lookup_join(left, right, left_keys, right_keys,
+                            out_capacity, suffix, how)
     if right_unique and how in ("inner", "left"):
         rhi0, rlo0 = hash_batch_keys(right, right_keys)
         rv = right.valid_mask()

@@ -99,6 +99,8 @@ def _agg(kind: str, vals: List[Any]):
         return len(vals)
     if kind == "sum":
         return np.sum(vals, axis=0)
+    if kind == "sum64":
+        return np.sum(np.asarray(vals, np.int64))
     if kind == "min":
         return np.min(vals, axis=0)
     if kind == "max":

@@ -27,7 +27,7 @@ from typing import Sequence, Tuple
 import jax
 import jax.numpy as jnp
 
-from dryad_tpu.data.columnar import Batch, StringColumn
+from dryad_tpu.data.columnar import Batch
 from dryad_tpu.ops.hashing import hash_batch_keys
 from dryad_tpu.ops.kernels import (_pack_columns_u32, _unpack_columns_u32,
                                    _sort_carrying, sort_lanes_for)
@@ -166,12 +166,7 @@ def _exchange_one_axis_gather(batch: Batch, dest: jax.Array, axis: str,
     def a2a(x):
         return jax.lax.all_to_all(x, axis, 0, 0, tiled=True)
 
-    recv_cols = {}
-    for k, v in send.columns.items():
-        if isinstance(v, StringColumn):
-            recv_cols[k] = StringColumn(a2a(v.data), a2a(v.lengths))
-        else:
-            recv_cols[k] = a2a(v)
+    recv_cols = {k: jax.tree.map(a2a, v) for k, v in send.columns.items()}
     recv_counts = jax.lax.all_to_all(send_counts, axis, 0, 0, tiled=True)
 
     s_idx = jnp.repeat(jnp.arange(D, dtype=jnp.int32), C)
@@ -529,12 +524,7 @@ def broadcast_gather(batch: Batch, out_capacity: int,
     def ag(x):
         return jax.lax.all_gather(x, axes, axis=0, tiled=True)
 
-    cols = {}
-    for k, v in batch.columns.items():
-        if isinstance(v, StringColumn):
-            cols[k] = StringColumn(ag(v.data), ag(v.lengths))
-        else:
-            cols[k] = ag(v)
+    cols = {k: jax.tree.map(ag, v) for k, v in batch.columns.items()}
     counts = jax.lax.all_gather(batch.count, axes)  # [P]
     D = counts.shape[0]
     s_idx = jnp.repeat(jnp.arange(D, dtype=jnp.int32), cap)

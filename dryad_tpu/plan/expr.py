@@ -299,8 +299,10 @@ class Join(Node):
     how: str = "inner"
     # caller hint: right keys are unique (a lookup/dimension table) —
     # enables the gather-free merge-fill join path, VERIFIED at runtime
-    # (falls back to the general path when duplicates appear)
-    right_unique: bool = False
+    # (falls back to the general path when duplicates appear); or
+    # "verified": a key established where the right side's rows were
+    # written — the merge-fill path alone, nothing checked at runtime
+    right_unique: bool | str = False
 
     @property
     def npartitions(self) -> int:
@@ -321,6 +323,11 @@ class OrderBy(Node):
 
     @property
     def partitioning(self) -> Partitioning:
+        # as the planner: the claim is made for ascending keys only (a
+        # reader that relies on it sorts each partition and keeps their
+        # order, which under a descending key is the reverse)
+        if any(desc for _, desc in self.keys):
+            return Partitioning.none()
         return Partitioning("range", tuple(k for k, _ in self.keys))
 
 

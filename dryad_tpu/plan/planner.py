@@ -50,6 +50,10 @@ def _decompose_aggs(aggs: Dict[str, Tuple[str, Optional[str]]]):
             partial[out] = (kind, col)
             merge_kind = "sum" if kind == "sum" else kind
             final[out] = (merge_kind, out)
+        elif kind == "sum64":
+            # partial 64-bit sums (Int64Column) merge by the wide sum
+            partial[out] = (kind, col)
+            final[out] = ("sum", out)
         elif kind == "mean":
             partial[out + "__sum"] = ("sum", col)
             partial[out + "__cnt"] = ("count", None)

@@ -9,7 +9,8 @@ query text):
 * DTA302 unknown table, DTA303 unknown column, DTA304 ambiguous
   column / duplicate alias / duplicate output name,
 * DTA305 type mismatches (including aggregate-shape errors: a
-  non-grouped column in an aggregated SELECT),
+  non-grouped column in an aggregated SELECT, and any use of a 64-bit
+  sum — type ``bigint`` — but to select it, order by it or sum it),
 * DTA306 recognized-but-unsupported constructs (a FROM-list table that
   no equality joins among them: never a cross product).
 
@@ -55,6 +56,11 @@ class BoundJoin:
     # the build side — the larger input probes, so a key/foreign-key
     # join fits the stage's first out_capacity
     swap: bool = False
+    # the build side is ONE base table and the join's columns cover the
+    # key its catalog entry carries (verified where its rows were
+    # written): at most one build row a probe row, so the stage runs
+    # the lookup join alone (_Binder._mark_unique)
+    unique: bool = False
 
 
 @dataclasses.dataclass
@@ -239,6 +245,9 @@ class _Binder:
                 continue
             (l_i, r_i) = (0, 1) if tags[0] == "left" else (1, 0)
             lt, rt = sides[l_i][2], sides[r_i][2]
+            if "bigint" in (lt, rt):
+                self._no_bigint(c.span)
+                continue
             if lt != rt and {lt, rt} != {"int", "float"}:
                 self.diag("DTA305",
                           f"JOIN key type mismatch: {sides[l_i][1]} is "
@@ -338,10 +347,7 @@ class _Binder:
         from the catalog's row counts: the stage's out_capacity is its
         left capacity, and a key/foreign-key join returns at most the
         rows of its larger (foreign-key) side — so the larger side goes
-        left.  Outer joins keep the written sides.  Nothing here says
-        that a side's keys are unique (the catalog holds no constraint),
-        so no join asks for the merge join (``right_unique``): asked for
-        blindly it compiles both join kernels into every join stage."""
+        left.  Outer joins keep the written sides."""
         rows = self.catalog.get(self.stmt.table.name).rows
         for j in joins:
             t_rows = self.catalog.get(j.table).rows
@@ -350,6 +356,27 @@ class _Binder:
                 rows = max(rows, t_rows)
             elif j.how != "left":
                 rows += t_rows
+
+    def _mark_unique(self, joins: List[BoundJoin],
+                     base_renames: Dict[str, str]) -> None:
+        """Mark the inner and left joins whose build (right) side is one
+        base table — its own filter and projection may lie between: a
+        subset of a key's rows is still unique — and whose join columns
+        cover the key that table's catalog entry carries.  The build
+        side is the joined table, or under ``swap`` what is joined so
+        far, which is one base table at the first join only."""
+        for i, j in enumerate(joins):
+            if j.how not in ("inner", "left"):
+                continue
+            if not j.swap:
+                table, cols = j.table, {j.renames[k] for k in j.right_keys}
+            elif i == 0:
+                table = self.stmt.table.name
+                cols = {base_renames.get(k) for k in j.left_keys}
+            else:
+                continue
+            key = self.catalog.get(table).unique
+            j.unique = bool(key) and set(key) <= cols
 
     # -- expressions -------------------------------------------------------
 
@@ -376,6 +403,14 @@ class _Binder:
             return None, None
         return hit
 
+    def _no_bigint(self, span):
+        self.diag("DTA305",
+                  "a 64-bit integer (a SUM over integers, type bigint) can "
+                  "be selected, ordered by, stored and summed again; "
+                  "arithmetic and comparisons on it are not provided",
+                  span)
+        return None, None
+
     def bind_expr(self, e, scope: _Scope,
                   want: Optional[str] = None) -> Tuple[Optional[Prog],
                                                        Optional[str]]:
@@ -399,6 +434,8 @@ class _Binder:
             prog, typ = self.bind_expr(e.operand, scope)
             if prog is None:
                 return None, None
+            if typ == "bigint":
+                return self._no_bigint(e.span)
             if e.op == "not":
                 if typ != "bool":
                     self.diag("DTA305",
@@ -418,6 +455,8 @@ class _Binder:
             if lp is None or rp is None:
                 return None, None
             op = e.op
+            if "bigint" in (lt, rt):
+                return self._no_bigint(e.span)
             if op in ("and", "or"):
                 if lt != "bool" or rt != "bool":
                     self.diag("DTA305",
@@ -494,6 +533,7 @@ class _Binder:
         where, scan_filters, residual = self._place_conjuncts(
             where, joins, scope)
         self._choose_sides(joins)
+        self._mark_unique(joins, base_renames or {})
 
         has_agg = any(isinstance(it.expr, N.Agg) for it in stmt.items)
         grouped = bool(stmt.group_by) or has_agg
@@ -533,6 +573,11 @@ class _Binder:
                 phys, typ = self._bind_col(g, scope)
                 if phys is None:
                     continue
+                if typ == "bigint":
+                    self.diag("DTA305",
+                              f"GROUP BY {g.name!r}: a 64-bit integer "
+                              f"column cannot be a group key", g.span)
+                    continue
                 group_keys.append(phys)
                 key_types[phys] = typ
                 pre_projection[phys] = ["col", phys]
@@ -558,13 +603,23 @@ class _Binder:
                         prog, in_typ = self.bind_expr(e.arg, scope)
                         if prog is None:
                             continue
-                        if kind != "count" and in_typ not in ("int",
-                                                              "float"):
+                        if kind != "count" and in_typ not in (
+                                ("int", "float", "bigint")
+                                if kind == "sum" else ("int", "float")):
                             self.diag(
                                 "DTA305",
                                 f"{e.func} needs a numeric argument, "
                                 f"got {in_typ}", e.span)
                             continue
+                        if kind == "sum" and in_typ == "int":
+                            # integers sum exactly, in 64 bits
+                            # (kernels.group_aggregate "sum64")
+                            kind = "sum64"
+                        elif kind == "mean" and in_typ == "int":
+                            # AVG's result is a float: average the
+                            # values as floats, no integer total to wrap
+                            prog = ["bin", "*", prog,
+                                    ["lit", 1.0, "float"]]
                         if kind == "count":
                             in_col = None  # COUNT(expr) == row count
                         else:
@@ -580,7 +635,8 @@ class _Binder:
                     else:
                         name = f"{e.func.lower()}_{agg_i}"
                     out_typ = ("int" if kind == "count" else
-                               "float" if kind == "mean" else in_typ)
+                               "float" if kind == "mean" else
+                               "bigint" if kind == "sum64" else in_typ)
                     if name in aggs or name in outputs:
                         self.diag("DTA304",
                                   f"duplicate output column {name!r} — "
@@ -658,6 +714,8 @@ class _Binder:
                           f"cannot grow — a standing query needs a "
                           f"store-backed base table", espan)
 
+        if stmt.distinct and "bigint" in output_types.values():
+            self._no_bigint(stmt.span)
         order_by: List[Tuple[str, bool]] = []
         for o in stmt.order_by:
             if o.name not in outputs:

@@ -38,7 +38,10 @@ class SchemaOnlyTableError(ValueError):
 def _norm_schema(schema: Dict[str, Any]) -> Dict[str, Dict[str, Any]]:
     """Normalize a store-manifest / user schema to
     ``{col: {"kind": "str", "max_len": n} | {"kind": "num",
-    "dtype": dtype_str}}``."""
+    "dtype": dtype_str}}``.  A store's ``int64`` column (two 32-bit
+    words on the device, data/columnar.Int64Column) is dtype ``bigint``;
+    an inline numpy ``int64`` array stays ``int64``: it reaches the
+    device as ``int32``, as it always has."""
     out: Dict[str, Dict[str, Any]] = {}
     for col, spec in schema.items():
         if isinstance(spec, str):
@@ -47,6 +50,8 @@ def _norm_schema(schema: Dict[str, Any]) -> Dict[str, Dict[str, Any]]:
         if spec.get("kind") == "str":
             out[col] = {"kind": "str",
                         "max_len": int(spec.get("max_len", 64))}
+        elif spec.get("kind") == "int64":
+            out[col] = {"kind": "num", "dtype": "bigint"}
         else:
             out[col] = {"kind": "num",
                         "dtype": str(spec.get("dtype", "int32"))}
@@ -103,10 +108,14 @@ def table_fingerprint(t: "CatalogTable") -> str:
 
 
 def sql_type_of(spec: Dict[str, Any]) -> str:
-    """Binder-facing type name: "int" | "float" | "bool" | "str"."""
+    """Binder-facing type name: "int" | "float" | "bool" | "str" |
+    "bigint" (a 64-bit sum: selected, ordered by and stored, nothing
+    else)."""
     if spec["kind"] == "str":
         return "str"
     dt = spec["dtype"]
+    if dt == "bigint":
+        return "bigint"
     if dt.startswith("float"):
         return "float"
     if dt.startswith("bool"):
@@ -118,13 +127,19 @@ class CatalogTable:
     def __init__(self, name: str, schema: Dict[str, Any],
                  path: Optional[str] = None,
                  columns: Optional[Dict[str, Any]] = None,
-                 rows: int = 0, str_max_len: Optional[int] = None):
+                 rows: int = 0, str_max_len: Optional[int] = None,
+                 unique: Optional[Any] = None):
         self.name = name
         self.schema = _norm_schema(schema)
         self.path = path
         self.columns = columns
         self.rows = int(rows)
         self.str_max_len = str_max_len
+        # the columns that together are a key of the table's rows, as
+        # VERIFIED where they were written (io/store.write_store) or
+        # registered (register_columns); None: no key is known
+        self.unique: Optional[Tuple[str, ...]] = \
+            tuple(unique) if unique else None
 
     @property
     def kind(self) -> str:
@@ -137,6 +152,8 @@ class CatalogTable:
                              "rows": self.rows}
         if self.path is not None:
             d["path"] = self.path
+        if self.unique:
+            d["unique"] = list(self.unique)
         return d
 
 
@@ -150,18 +167,23 @@ class Catalog:
 
     def register_store(self, name: str, path: str) -> "Catalog":
         """Register a persisted io/store.py store (local / s3:// /
-        hdfs://); schema and row statistics come from its manifest."""
+        hdfs://); schema, row statistics and the key its writer verified
+        (``unique``, if it declared one) come from its manifest."""
         from dryad_tpu.io.store import store_meta
         meta = store_meta(path)
         self.tables[name] = CatalogTable(
             name, meta["schema"], path=path,
-            rows=sum(meta.get("counts", ())))
+            rows=sum(meta.get("counts", ())), unique=meta.get("unique"))
         return self
 
     def register_columns(self, name: str, columns: Dict[str, Any],
-                         str_max_len: Optional[int] = None) -> "Catalog":
+                         str_max_len: Optional[int] = None,
+                         unique: Optional[Any] = None) -> "Catalog":
         """Register in-memory host columns (numpy arrays / lists;
-        lists of bytes|str are string columns)."""
+        lists of bytes|str are string columns).  ``unique``: columns
+        that together are a key of these rows — verified here as
+        ``to_store(unique=)`` verifies it (``StoreKeyError`` where two
+        rows share the key)."""
         import numpy as np
         schema: Dict[str, Any] = {}
         cols: Dict[str, Any] = {}
@@ -188,15 +210,35 @@ class Catalog:
                 schema[col] = {"kind": "num", "dtype": str(arr.dtype)}
                 rows = arr.shape[0]
                 cols[col] = v
+        if unique:
+            from dryad_tpu.data.columnar import batch_from_numpy
+            from dryad_tpu.exec.data import key_hashes
+            from dryad_tpu.io.store import StoreKeyError, check_unique
+            missing = [k for k in unique if k not in cols]
+            if missing:
+                raise StoreKeyError(f"table {name!r}: unique names "
+                                    f"{missing}, no column of {sorted(cols)}")
+            if rows:
+                import jax
+                b = batch_from_numpy({k: cols[k] for k in unique},
+                                     str_max_len=str_max_len or 64)
+                check_unique(key_hashes(jax.tree.map(lambda x: x[None], b),
+                                        [rows], unique),
+                             unique, f"table {name!r}")
         self.tables[name] = CatalogTable(name, schema,
                                          columns=cols, rows=rows,
-                                         str_max_len=str_max_len)
+                                         str_max_len=str_max_len,
+                                         unique=unique)
         return self
 
     def register_schema(self, name: str, schema: Dict[str, Any],
-                        rows: int = 0) -> "Catalog":
-        """Schema-only registration (offline EXPLAIN / golden plans)."""
-        self.tables[name] = CatalogTable(name, schema, rows=rows)
+                        rows: int = 0,
+                        unique: Optional[Any] = None) -> "Catalog":
+        """Schema-only registration (offline EXPLAIN / golden plans).
+        ``unique`` is taken as declared: there are no rows to hold it
+        to."""
+        self.tables[name] = CatalogTable(name, schema, rows=rows,
+                                         unique=unique)
         return self
 
     # -- lookup ------------------------------------------------------------
@@ -373,7 +415,8 @@ class Catalog:
                 # dataset() time
                 cat.tables[n] = CatalogTable(n, d["schema"],
                                              path=d["path"],
-                                             rows=d.get("rows", 0))
+                                             rows=d.get("rows", 0),
+                                             unique=d.get("unique"))
             elif d["kind"] == "inline" and "columns" in d:
                 cols = {}
                 for c, v in d["columns"].items():
@@ -384,10 +427,12 @@ class Catalog:
                         cols[c] = np.asarray(
                             v, dtype=d["schema"][c]["dtype"])
                 cat.register_columns(n, cols,
-                                     str_max_len=d.get("str_max_len"))
+                                     str_max_len=d.get("str_max_len"),
+                                     unique=d.get("unique"))
             else:
                 cat.register_schema(n, d["schema"],
-                                    rows=d.get("rows", 0))
+                                    rows=d.get("rows", 0),
+                                    unique=d.get("unique"))
         return cat
 
     def save(self, path: str) -> None:

@@ -15,7 +15,10 @@ Shape of the lowered chain::
                            ``alias.col``; the others are dropped)
     WHERE, one table's     .where(Predicate) on that table's scan, below
                            its join; a column only it reads goes after
-    JOIN / FROM-list keys  .join(...), the larger input on the left
+    JOIN / FROM-list keys  .join(...), the larger input on the left; a
+                           build side that is one base table joined on
+                           the key its store carries: right_unique=
+                           "verified", the lookup kernel alone
     WHERE, the residual    .where(Predicate) above the joins
     GROUP BY + aggregates  pre-Projector (keys + agg-input exprs)
                            -> .group_by(keys, aggs) [-> .where(HAVING)]
@@ -138,14 +141,20 @@ def lower(ctx, catalog: Catalog, bound: BoundSelect, loader=None,
     for j in bound.joins:
         other = root(j.table, j.alias, j.renames, j.span)
         lks = [live(k) for k in j.left_keys]
-        cur = _stamp(other.join(cur, j.right_keys, lks, how=j.how)
+        # a key the catalog carries was verified where the rows were
+        # written: no run-time check, no second kernel in the program
+        ru = "verified" if j.unique else False
+        cur = _stamp(other.join(cur, j.right_keys, lks, how=j.how,
+                                right_unique=ru)
                      if j.swap else
-                     cur.join(other, lks, j.right_keys, how=j.how), j.span)
+                     cur.join(other, lks, j.right_keys, how=j.how,
+                              right_unique=ru), j.span)
         if j.how == "inner":
             subst.update(zip(lks, j.right_keys) if j.swap
                          else zip(j.right_keys, lks))
     if span is not None:
-        span.set(columns_kept=kept, columns_stored=stored)
+        span.set(columns_kept=kept, columns_stored=stored,
+                 unique_joins=sum(j.unique for j in bound.joins))
     if bound.residual is not None:
         cur = _stamp(cur.where(Predicate(above(bound.residual)),
                                label="sql-where"),

@@ -86,6 +86,8 @@ def _const_like(cols: Dict[str, Any], value: Any, typ: str):
     (the lowering's global-aggregate key; api.dataset._const_key_like
     pattern)."""
     v = next(iter(cols.values()))
+    if hasattr(v, "hi") and hasattr(v, "lo"):   # Int64Column: its words
+        v = v.hi
     if _is_strcol(v):
         n = v.lengths.shape[0]
     elif hasattr(v, "shape"):

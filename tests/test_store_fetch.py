@@ -255,3 +255,16 @@ def test_schema_needs_no_device_slice():
     bf = PData(Batch({"b": jnp.zeros((1, 4, 2), jnp.bfloat16)},
                      jnp.asarray([4], jnp.int32)), 1)
     assert store.pdata_schema(bf)["b"]["dtype"] == "bfloat16"
+
+
+@pytest.mark.parametrize("cap,row_bytes", [
+    (400_000, 6), (400_000, 4), (60_000, 25), (2_556, 18), (1000, 4),
+    (12_000_000, 15), (8 << 20, 90), (1, 4), (0, 4)])
+def test_chunk_rows_are_a_power_of_two(cap, row_bytes):
+    """A column shorter than one chunk is cut by a power of two as well
+    (the TPU compiler took 138-164 s over the wide reshape of 400,000
+    rows; its chunks overlap instead), under the chunk's bytes."""
+    rows = xdata._fetch_chunk_rows(cap, row_bytes)
+    assert rows & (rows - 1) == 0 and 1 <= rows <= max(cap, 1)
+    assert rows * row_bytes <= max(xdata._FETCH_CHUNK_BYTES, row_bytes)
+    assert 2 * rows > min(max(cap, 1), xdata._FETCH_CHUNK_BYTES // row_bytes)
