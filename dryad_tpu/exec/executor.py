@@ -49,6 +49,9 @@ _SAMPLES_PER_PART = 4096
 _SLOT_FEEDBACK_LEGS = 4
 # lane of the stage info vector that carries a range exchange's tie_rows
 _INFO_TIE = 4 + _SLOT_FEEDBACK_LEGS
+# the two lanes after it: the rows the program's bounded gathers fetched,
+# and the rows they would have fetched unbounded (kernels.gather_tally)
+_INFO_GATHER = _INFO_TIE + 1
 # a program's name with jit's own "jit_" before it stays within 64
 _PROGRAM_NAME_MAX = 60
 
@@ -543,6 +546,15 @@ def _wide_sum_count(stage: Stage) -> Dict[str, int]:
                               if isinstance(spec, tuple))}
 
 
+def gather_info_attrs(info) -> Dict[str, int]:
+    """``gather_rows`` / ``gather_rows_cap`` of a fetched stage info
+    ``[P, lanes]``: the rows the program's bounded gathers fetched, over
+    all shards, and the rows the same gathers would have fetched
+    unbounded (their capacities)."""
+    return {"gather_rows": int(info[:, _INFO_GATHER].sum()),
+            "gather_rows_cap": int(info[:, _INFO_GATHER + 1].sum())}
+
+
 def _join_kernel(params) -> str:
     """Which join a stage's program holds, from the plan: ``lookup``
     (kernels._lookup_join alone: the right side's key was verified where
@@ -674,6 +686,10 @@ class Executor:
         and exchange applied), capacity x row bytes over all shards, and
         ``build_rows``, the rows of capacity of its right input."""
         def per_shard(*args):
+            with kernels.gather_tally() as gathers:
+                return ops_and_info(gathers, *args)
+
+        def ops_and_info(gathers, *args):
             leg_batches = [
                 _squeeze(b) for b in args[:n_legs]]
             bounds = args[n_legs] if has_bounds else None
@@ -753,16 +769,20 @@ class Executor:
                 needs = jnp.maximum(needs, nd)
             # ONE small per-shard info vector [need_scale, need_slack,
             # exchange_need_scale, out_count, slot_used x 4 legs,
-            # tie_rows]: the
+            # tie_rows, gather_rows, gather_rows_cap]: the
             # executor host-fetches exactly one array per stage — a
             # second fetch per stage costs a full link round trip, which
             # dominates iterative jobs on high-latency links.  The slot
             # lanes are the exchanges' own measured send-slot feedback
-            # (free: they ride the fetch that happens anyway), and so
-            # does the range exchange's tie counter (_INFO_TIE).
+            # (free: they ride the fetch that happens anyway), and so do
+            # the range exchange's tie counter (_INFO_TIE) and what the
+            # program's bounded gathers fetched (_INFO_GATHER).
+            fetched = sum((f for f, _ in gathers), jnp.zeros((), jnp.int32))
+            unbounded = min(sum(c for _, c in gathers), 2**31 - 1)
             info = jnp.concatenate([needs, exch_need[None],
                                     cur.count.astype(jnp.int32)[None],
-                                    slots, ties[None]])
+                                    slots, ties[None], fetched[None],
+                                    jnp.full((1,), unbounded, jnp.int32)])
             return _expand(cur), info[None]
 
         per_shard.__name__ = per_shard.__qualname__ = \
@@ -1194,6 +1214,8 @@ class Executor:
             if range_attrs:
                 range_attrs["tie_rows"] = int(info[:, _INFO_TIE].sum())
                 span.set(**range_attrs)
+            gather_attrs = gather_info_attrs(info)
+            span.set(**gather_attrs)
             out_bytes = int(sum(
                 x.size * x.dtype.itemsize
                 for x in jax.tree.leaves(out_batch)))
@@ -1213,7 +1235,7 @@ class Executor:
                 "cache_hit": cache_hit,
                 "dispatches": 2,   # program launch + info fetch
                 "wall_s": round(wall, 4), **join_attrs, **range_attrs,
-                **filter_attrs})
+                **filter_attrs, **gather_attrs})
             decision = self._decide_needs(stage, scale, slack, salted,
                                           need_scale, need_slack,
                                           need_exch)

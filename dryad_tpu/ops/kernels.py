@@ -20,7 +20,9 @@ Key idioms:
 
 from __future__ import annotations
 
+import contextlib
 import os
+import threading
 from typing import Any, Dict, List, Sequence, Tuple
 
 import jax
@@ -207,7 +209,8 @@ def _segment_flags(differs: jax.Array, n_valid):
 
 
 def _sort_segments_carry(hi: jax.Array, lo: jax.Array, valid: jax.Array,
-                         n_valid, value_lanes, stable: bool = True):
+                         n_valid, value_lanes, stable: bool = True,
+                         bounded: bool = True):
     """Value-carry hash segmentation: ONE stable variadic sort groups rows
     by the 64-bit hash (invalid rows fold to the all-ones sentinel and
     sort last — same collision budget as _hash_sort_segments), carrying
@@ -219,11 +222,17 @@ def _sort_segments_carry(hi: jax.Array, lo: jax.Array, valid: jax.Array,
 
     ``stable=False`` drops the in-segment order guarantee (XLA's stable
     sort costs ~2x the unstable one, measured) — safe only when nothing
-    downstream observes the order of rows WITHIN a hash segment."""
+    downstream observes the order of rows WITHIN a hash segment.
+
+    The valid rows are the first ``n_valid`` sorted rows, and that is
+    what bounds the value lanes' gather (_sort_carrying's ``live``: the
+    lanes past it come back zero); ``bounded=False`` is for a caller
+    that reads the sorted padding rows' lanes too."""
     cap = hi.shape[0]
     hi_s, lo_s = _sentinel_fold(hi, lo, valid)
-    (shi, slo), sorted_vals = _sort_carrying([hi_s, lo_s], value_lanes,
-                                             cap, stable=stable)
+    (shi, slo), sorted_vals = _sort_carrying(
+        [hi_s, lo_s], value_lanes, cap, stable=stable,
+        live=n_valid if bounded else None)
     is_start, is_end, num_groups = _segment_flags(
         _lane_differs(shi, slo), n_valid)
     return sorted_vals, is_start, is_end, num_groups
@@ -243,7 +252,7 @@ def _sort_segments_dense(key_lane: jax.Array, valid: jax.Array, n_valid,
     cap = key_lane.shape[0]
     inv = (~valid).astype(jnp.uint32)
     (sinv, skey), sorted_vals = _sort_carrying(
-        [inv, key_lane], value_lanes, cap, stable=False)
+        [inv, key_lane], value_lanes, cap, stable=False, live=n_valid)
     is_start, is_end, num_groups = _segment_flags(
         _lane_differs(skey), n_valid)
     return skey, sorted_vals, is_start, is_end, num_groups
@@ -264,8 +273,104 @@ _VALOPS_MAX_WORDS = 32
 # 8-operand sort at 250k rows) — huge caps with many carried words make
 # compiles take minutes and binaries enormous.  Above this
 # cap x operand budget, reorder via the 3-operand index sort + ONE
-# packed gather instead (slower on-device at huge n, but compilable).
+# packed gather instead: the gather pays ~10 ns a row it fetches, so a
+# caller that knows how many of its sorted rows are live hands that
+# count down and the gather fetches those alone (_gather_live).
 _VALOPS_MAX_ELEMS = 48 << 20
+# rows a trip of the bounded gather's loop fetches (_gather_live); one
+# value for every site.  On the chip 16 Ki, 64 Ki and 256 Ki rows a trip
+# cost the same to the millisecond at every live share
+# (benchmarks/gather_live_probe.py): the middle one, which rounds a
+# count up by at most a millisecond's rows
+_GATHER_CHUNK = 1 << 16
+
+# open bounded-gather tally of this thread (gather_tally), or nothing
+_TALLY = threading.local()
+
+
+@contextlib.contextmanager
+def gather_tally():
+    """Collect what the bounded gathers traced inside the block fetch:
+    a list of ``(rows fetched: traced int32 scalar, rows the unbounded
+    gather would have fetched: int)``, one entry a gather.  A stage
+    program sums them into its info vector (exec/executor).  A gather
+    traced under another trace than the block's own — a ``lax.cond``
+    branch, a loop body — is left out: its scalars cannot leave it."""
+    prev = getattr(_TALLY, "open", None)
+    entries: List[Tuple[jax.Array, int]] = []
+    _TALLY.open = (jax.core.get_opaque_trace_state(), entries)
+    try:
+        yield entries
+    finally:
+        _TALLY.open = prev
+
+
+def _gather_live(src: jax.Array, idx: jax.Array, live=None,
+                 lanes_first: bool = False) -> jax.Array:
+    """``jnp.take(src, idx, axis=0)`` in rows ``[0, live)`` and zeros in
+    rows ``[live, len(idx))``, at a cost that follows ``live``.  For the
+    callers whose rows past a count are padding that they mask anyway.
+
+    A zero buffer is filled ``_GATHER_CHUNK`` rows a trip, ``ceil(live /
+    chunk)`` trips (the last chunk is clamped to the buffer's end and
+    fetches some rows twice).  Where more than three quarters of the rows
+    are live the one whole take runs instead (a run-time choice on the
+    count, ``lax.cond``): on the chip a row of the loop costs up to 1.8
+    of a row of the whole take (benchmarks/gather_live_probe.py), so a
+    full batch pays what it paid before there was a loop.  An index
+    vector no longer than one chunk is always the whole take.
+
+    ``lanes_first``: ``src`` is a ``[rows, W]`` word matrix and the
+    result its transpose, ``[W, len(idx)]`` — each chunk is written
+    lane-major, so the lanes come out as rows with nothing unstacked at
+    capacity.  ``live=None`` is the one take."""
+    def whole():
+        g = jnp.take(src, idx, axis=0)
+        return g.T if lanes_first else g
+
+    cap = idx.shape[0]
+    if live is None or cap == 0:
+        return whole()
+    chunk = _GATHER_CHUNK
+    tail = src.shape[1:]
+    live = jnp.clip(jnp.asarray(live, jnp.int32), 0, cap)
+
+    def before_live(n, at=0):
+        keep = at + jnp.arange(n, dtype=jnp.int32) < live
+        return keep[None, :] if lanes_first else \
+            keep.reshape((n,) + (1,) * len(tail))
+
+    def whole_masked():
+        return jnp.where(before_live(cap), whole(), jnp.zeros((), src.dtype))
+
+    if cap <= chunk:        # one trip would fetch it all: nothing to bound
+        out, fetched = whole_masked(), cap
+    else:
+        trips = (live + (chunk - 1)) // chunk
+
+        def trip(i, out):
+            at = jnp.minimum(i * chunk, cap - chunk)
+            rows = jnp.take(
+                src, jax.lax.dynamic_slice(idx, (at,), (chunk,)), axis=0)
+            rows = jnp.where(before_live(chunk, at),
+                             rows.T if lanes_first else rows,
+                             jnp.zeros((), src.dtype))
+            return jax.lax.dynamic_update_slice(
+                out, rows,
+                (0, at) if lanes_first else (at,) + (0,) * len(tail))
+
+        def chunks():
+            shape = tail + (cap,) if lanes_first else (cap,) + tail
+            return jax.lax.fori_loop(0, trips, trip,
+                                     jnp.zeros(shape, src.dtype))
+
+        dense = live > cap - cap // 4
+        out = jax.lax.cond(dense, whole_masked, chunks)
+        fetched = jnp.where(dense, cap, jnp.minimum(trips * chunk, cap))
+    tally = getattr(_TALLY, "open", None)
+    if tally is not None and tally[0] == jax.core.get_opaque_trace_state():
+        tally[1].append((fetched, cap))
+    return out
 
 
 def _carry_fits(cap: int, n_key_lanes: int, n_val_lanes: int) -> bool:
@@ -273,10 +378,29 @@ def _carry_fits(cap: int, n_key_lanes: int, n_val_lanes: int) -> bool:
             and cap * (n_key_lanes + n_val_lanes) <= _VALOPS_MAX_ELEMS)
 
 
-def _sort_carrying(key_lanes, value_lanes, cap: int, stable: bool = True):
+def _gather_lanes(value_lanes, order: jax.Array, live=None):
+    """The lanes' rows in ``order``: ONE packed gather of the stacked
+    ``[cap, W]`` word matrix, of the first ``live`` rows alone where
+    given (_gather_live)."""
+    words = jnp.stack(value_lanes, axis=1)
+    if live is None:
+        g = jnp.take(words, order, axis=0)
+        return [g[:, j] for j in range(len(value_lanes))]
+    out = _gather_live(words, order, live, lanes_first=True)
+    return [out[j] for j in range(len(value_lanes))]
+
+
+def _sort_carrying(key_lanes, value_lanes, cap: int, stable: bool = True,
+                   live=None):
     """Sort by uint32 ``key_lanes`` (stable by default) returning the value
     lanes in sorted order — value-carry when the program-size budget
-    allows, else index sort + one packed gather (see _VALOPS_MAX_ELEMS)."""
+    allows, else index sort + one packed gather (see _VALOPS_MAX_ELEMS).
+
+    ``live`` (optional, a traced count): the caller's promise that only
+    the first ``live`` SORTED rows are read — the rest are padding it
+    masks.  The gather branch then fetches those rows alone and returns
+    zeros in the value lanes past them; the value-carry branch, and the
+    sorted key lanes of either, do not look at it."""
     value_lanes = list(value_lanes)
     if _carry_fits(cap, len(key_lanes), len(value_lanes)):
         out = jax.lax.sort(tuple(key_lanes) + tuple(value_lanes),
@@ -288,14 +412,12 @@ def _sort_carrying(key_lanes, value_lanes, cap: int, stable: bool = True):
     order = out[len(key_lanes)]
     if not value_lanes:
         return list(out[:len(key_lanes)]), []
-    words = jnp.stack(value_lanes, axis=1)
-    g = jnp.take(words, order, axis=0)
     return (list(out[:len(key_lanes)]),
-            [g[:, j] for j in range(len(value_lanes))])
+            _gather_lanes(value_lanes, order, live))
 
 
 def _sort_fused2(lanes: List[jax.Array], packed: List[jax.Array],
-                 cap: int):
+                 cap: int, live=None):
     """Runtime key-lane fusion for 2-key-lane sorts (multi-key sort key
     packing): when the VALID rows' lane spans satisfy
     span_a * span_b <= 2^32, the two lex lanes collapse into ONE fused
@@ -309,7 +431,8 @@ def _sort_fused2(lanes: List[jax.Array], packed: List[jax.Array],
     ([sinv, sla, slb], svals) structure either way (the fused branch
     rebuilds the sorted lanes from the fused lane — exact for valid
     rows; invalid rows' lanes are garbage both ways and every caller
-    masks them)."""
+    masks them).  ``live`` bounds either branch's gather
+    (_sort_carrying)."""
     inv, la, lb = lanes
     valid = inv == 0
     big = jnp.uint32(0xFFFFFFFF)
@@ -330,29 +453,33 @@ def _sort_fused2(lanes: List[jax.Array], packed: List[jax.Array],
     def fused(args):
         inv, la, lb, packed = args
         f = (la - la_min) * span_b + (lb - lb_min)
-        (sinv, sf), svals = _sort_carrying([inv, f], list(packed), cap)
+        (sinv, sf), svals = _sort_carrying([inv, f], list(packed), cap,
+                                           live=live)
         sla = sf // span_b + la_min
         slb = sf % span_b + lb_min
         return [sinv, sla, slb], list(svals)
 
     def general(args):
         inv, la, lb, packed = args
-        skeys, svals = _sort_carrying([inv, la, lb], list(packed), cap)
+        skeys, svals = _sort_carrying([inv, la, lb], list(packed), cap,
+                                      live=live)
         return list(skeys), list(svals)
 
     return jax.lax.cond(ok, fused, general, (inv, la, lb, tuple(packed)))
 
 
 def permute_by_sort(batch: Batch, key_lanes: Sequence[jax.Array],
-                    count=None, stable: bool = True) -> Batch:
+                    count=None, stable: bool = True, live=None) -> Batch:
     """Sort the batch's rows by the given uint32 key lanes (most
     significant first; stable by default), moving ALL columns as packed
     value operands of one variadic lax.sort — zero random gathers.
-    Falls back to lexsort+single-packed-gather for very wide rows."""
+    Falls back to lexsort+single-packed-gather for very wide rows, of
+    the first ``live`` sorted rows alone where the caller says that the
+    rest are padding (_sort_carrying)."""
     lanes, spec = _pack_columns_u32(dict(batch.columns))
     new_count = batch.count if count is None else count
     _, svals = _sort_carrying(list(key_lanes), lanes, batch.capacity,
-                              stable=stable)
+                              stable=stable, live=live)
     return Batch(_unpack_columns_u32(svals, spec), new_count)
 
 
@@ -374,10 +501,11 @@ def compact(batch: Batch, keep: jax.Array) -> Batch:
     n_keep = keep.sum(dtype=jnp.int32)
     if os.environ.get("DRYAD_NO_SORT_OPT"):
         return permute_by_sort(batch, ((~keep).astype(jnp.uint32),),
-                               count=n_keep)
+                               count=n_keep, live=n_keep)
     iota = jnp.arange(batch.capacity, dtype=jnp.uint32)
+    # the kept rows are the first n_keep sorted rows: all that is fetched
     return permute_by_sort(batch, ((~keep).astype(jnp.uint32), iota),
-                           count=n_keep, stable=False)
+                           count=n_keep, stable=False, live=n_keep)
 
 
 def filter_rows(batch: Batch, predicate) -> Batch:
@@ -589,9 +717,12 @@ def sort_by_columns(batch: Batch, keys: Sequence[Tuple[str, bool]]) -> Batch:
         # linear in operands); on cpu the fusion measured a wash
         # (BENCH_kernels r06), so it rides the same backend tier as the
         # pallas kernels.
-        skeys, svals = _sort_fused2(lanes, packed, batch.capacity)
+        skeys, svals = _sort_fused2(lanes, packed, batch.capacity,
+                                    live=batch.count)
     else:
-        skeys, svals = _sort_carrying(lanes, packed, batch.capacity)
+        # invalid rows sort last either way: the first count are valid
+        skeys, svals = _sort_carrying(lanes, packed, batch.capacity,
+                                      live=batch.count)
     cols = _unpack_columns_u32(svals, spec)
     valid_sorted = jnp.arange(batch.capacity, dtype=jnp.int32) < batch.count
     for name, (off, cnt, desc) in recon.items():
@@ -1111,7 +1242,9 @@ def _group_aggregate_boundary(batch: Batch, key_names: Sequence[str],
             {k: batch.columns[k] for k in key_names})
         carry = kp + carry
 
-    skeys, scarry = _sort_carrying(key_lanes, carry, cap, stable=False)
+    # dropped and padding rows sort last: the first n_valid are live
+    skeys, scarry = _sort_carrying(key_lanes, carry, cap, stable=False,
+                                   live=n_valid)
     if dense_fast:
         skey = skeys[1]
         differs = _lane_differs(skey)
@@ -1178,7 +1311,7 @@ def _group_aggregate_boundary(batch: Batch, key_names: Sequence[str],
     # (XLA's stable sort pays for an internal iota anyway, measured)
     dkeys, dl = _sort_carrying(
         [(~is_end).astype(jnp.uint32), idx.astype(jnp.uint32)],
-        dlanes, cap, stable=False)
+        dlanes, cap, stable=False, live=num_groups)
     didx_lane = dkeys[1]
 
     gmask = idx < num_groups
@@ -1362,7 +1495,8 @@ def _group_aggregate_scan(batch: Batch, key_names: Sequence[str],
     lanes2, spec2 = _pack_columns_u32(dense_in)
     if dense_fast:
         lanes2 = [skey] + lanes2
-    _, svals2 = _sort_carrying([(~is_end).astype(jnp.uint32)], lanes2, cap)
+    _, svals2 = _sort_carrying([(~is_end).astype(jnp.uint32)], lanes2, cap,
+                               live=num_groups)
     if dense_fast:
         skey2, svals2 = svals2[0], svals2[1:]
     dcols = _unpack_columns_u32(svals2, spec2)
@@ -1491,9 +1625,11 @@ def _hash_membership(hi: jax.Array, lo: jax.Array, flag: jax.Array,
     # prefix mask; callers concatenate whole-batch valid prefixes, and
     # _sort_segments_carry's sentinel fold sorts the invalid rows last
     # regardless, so is_start/is_end stay correct
+    # unbounded: the restore sort below needs every row's carried
+    # position, the padding rows' too, to be a permutation
     (sflag, siota), is_start, is_end, _ng = _sort_segments_carry(
         hi, lo, valid, valid.sum(dtype=jnp.int32),
-        (flag.astype(jnp.uint32), iota), stable=False)
+        (flag.astype(jnp.uint32), iota), stable=False, bounded=False)
     fwd = _seg_scan_reduce(sflag, is_start, jnp.maximum)
     bwd = _seg_scan_reduce(sflag, is_end, jnp.maximum, reverse=True)
     tot = jnp.maximum(fwd, bwd)
@@ -1802,7 +1938,7 @@ def distinct(batch: Batch, key_names: Sequence[str] | None = None) -> Batch:
     slanes, is_start, _is_end, num_groups = _sort_segments_carry(
         hi, lo, batch.valid_mask(), batch.count, lanes)
     _, svals2 = _sort_carrying([(~is_start).astype(jnp.uint32)], slanes,
-                               cap)
+                               cap, live=num_groups)
     cols = _unpack_columns_u32(svals2, spec)
     gmask = idx < num_groups
     return Batch({k: _mask_rows(v, gmask) for k, v in cols.items()},
@@ -1849,15 +1985,19 @@ def scalar_aggregate(batch: Batch,
 # join
 
 
-def _keys_equal(a: Batch, a_idx, a_names, b: Batch, b_idx, b_names) -> jax.Array:
+def _keys_equal(a: Batch, a_idx, a_names, b: Batch, b_idx, b_names,
+                live=None) -> jax.Array:
+    """Row-wise equality of the key columns at the two index vectors; of
+    the first ``live`` pairs alone where given (the rest compare zeros
+    and the caller masks them)."""
     eq = jnp.ones(a_idx.shape, jnp.bool_)
     for an, bn in zip(a_names, b_names):
         ca, cb = a.columns[an], b.columns[bn]
         if isinstance(ca, StringColumn):
-            la = jnp.take(ca.lengths, a_idx)
-            lb = jnp.take(cb.lengths, b_idx)
-            da = jnp.take(ca.data, a_idx, axis=0)
-            db = jnp.take(cb.data, b_idx, axis=0)
+            la = _gather_live(ca.lengths, a_idx, live)
+            lb = _gather_live(cb.lengths, b_idx, live)
+            da = _gather_live(ca.data, a_idx, live)
+            db = _gather_live(cb.data, b_idx, live)
             L = min(ca.max_len, cb.max_len)
             pos = jnp.arange(L, dtype=jnp.int32)[None, :]
             m = pos < la[:, None]
@@ -1865,11 +2005,13 @@ def _keys_equal(a: Batch, a_idx, a_names, b: Batch, b_idx, b_names) -> jax.Array
             # if max_lens differ, longer-side extra bytes imply inequality via length
             eq = eq & (la == lb) & beq
         else:
-            eq = eq & (jnp.take(ca, a_idx, axis=0) == jnp.take(cb, b_idx, axis=0))
+            eq = eq & (_gather_live(ca, a_idx, live)
+                       == _gather_live(cb, b_idx, live))
     return eq
 
 
-def _packed_gather(cols: Dict[str, Any], idx: jax.Array) -> Dict[str, Any]:
+def _packed_gather(cols: Dict[str, Any], idx: jax.Array,
+                   live=None) -> Dict[str, Any]:
     """Gather rows of several columns with ONE fused word-matrix gather:
     pack the columns to u32 lanes, take the stacked [cap, W] matrix
     once, unpack.  TPU random gathers pay a per-ROW cost (~10.7 ns
@@ -1880,20 +2022,17 @@ def _packed_gather(cols: Dict[str, Any], idx: jax.Array) -> Dict[str, Any]:
     on cpu the stack/unpack copies made the packed form ~2x SLOWER
     (BENCH_kernels r06 join_gather at 262k rows), so other backends
     keep one take per column — the same backend tier gating the pallas
-    kernels (force_interpret() routes tests through the packed form)."""
+    kernels (force_interpret() routes tests through the packed form).
+    ``live``: only the first ``live`` output rows are read — either form
+    fetches those alone and leaves zeros past them (_gather_live)."""
     from dryad_tpu.ops.pallas_kernels import pallas_active
     if pallas_active() is None:
-        out: Dict[str, Any] = {}
-        for k, v in cols.items():
-            out[k] = v.gather(idx) if isinstance(v, StringColumn) \
-                else jnp.take(v, idx, axis=0)
-        return out
+        return {k: jax.tree.map(lambda x: _gather_live(x, idx, live), v)
+                for k, v in cols.items()}
     lanes, spec = _pack_columns_u32(cols)
     if not lanes:
         return {}
-    w = jnp.stack(lanes, axis=1)
-    g = jnp.take(w, idx, axis=0)
-    return _unpack_columns_u32([g[:, j] for j in range(len(lanes))], spec)
+    return _unpack_columns_u32(_gather_lanes(lanes, idx, live), spec)
 
 
 def _join_out_names(left: Batch, right: Batch, right_keys, suffix: str):
@@ -2006,12 +2145,14 @@ def _lookup_join(left: Batch, right: Batch, left_keys: Sequence[str],
     lanes += [jnp.concatenate([zr, hand[:, j]])
               for j in range(hand.shape[1])]
     srhi, srlo = jnp.take(rhi, rorder), jnp.take(rlo, rorder)
+    n_valid = left.count + right.count
+    # the sentinels sort last and a prefix sum reads no row after its
+    # own: the first n_valid sorted rows are all that anything reads
     skeys, sl = _sort_carrying(
         [jnp.concatenate([lhi, srhi]), jnp.concatenate([llo, srlo]),
          jnp.concatenate([jnp.ones((cl,), jnp.uint32), zl])],
-        lanes, n, stable=False)
+        lanes, n, stable=False, live=n_valid)
     shi, slo, sside = skeys
-    n_valid = left.count + right.count
     idx = jnp.arange(n, dtype=jnp.int32)
     live = idx < n_valid            # valid rows sort before the sentinels
     is_left = (sside == 1) & live
@@ -2033,7 +2174,8 @@ def _lookup_join(left: Batch, right: Batch, left_keys: Sequence[str],
         # unmatched (or collision-rejected) left rows zero-fill the
         # right columns (how="left")
         out_lanes.append(jnp.where(present, filled[j], 0))
-    _, dl = _sort_carrying([(~keep).astype(jnp.uint32)], out_lanes, n)
+    _, dl = _sort_carrying([(~keep).astype(jnp.uint32)], out_lanes, n,
+                           live=total)
 
     def _fit(a):
         return a[:out_capacity] if n >= out_capacity else jnp.concatenate(
@@ -2156,25 +2298,30 @@ def hash_join(left: Batch, right: Batch, left_keys: Sequence[str],
     t = jnp.arange(out_capacity, dtype=jnp.int32)
     lid = searchsorted_big(cum, t, side="right").astype(jnp.int32)
     lid_c = jnp.minimum(lid, left.capacity - 1)
-    base = cum[lid_c] - mult[lid_c]
-    rid = (jnp.take(start, lid_c) + (t - base)).astype(jnp.int32)
+    # slots at or past ``total`` hold nothing (slot_valid): every gather
+    # over the slots fetches the first ``live`` alone and leaves zeros
+    # behind them, which index row 0 and are masked like what was there
+    live = jnp.minimum(total, out_capacity)
+    base = _gather_live(cum, lid_c, live) - _gather_live(mult, lid_c, live)
+    rid = (_gather_live(start, lid_c, live) + (t - base)).astype(jnp.int32)
     rid = jnp.clip(rid, 0, right.capacity - 1)
     slot_valid = t < total
 
     # verify true key equality (hash collisions) then compact; also exclude
     # candidates that landed in the right-side padding region, whose contents
     # are unspecified and may hold stale real keys
-    rid_abs = jnp.take(order, rid)   # sorted position -> original row
-    eq = _keys_equal(left, lid_c, left_keys, right, rid_abs, right_keys)
+    rid_abs = _gather_live(order, rid, live)  # sorted position -> own row
+    eq = _keys_equal(left, lid_c, left_keys, right, rid_abs, right_keys,
+                     live)
     keep_match = slot_valid & eq & (rid < right.count)
     keep = keep_match
     if left_synth:
-        synth_slot = slot_valid & jnp.take(synth_row, lid_c)
+        synth_slot = slot_valid & _gather_live(synth_row, lid_c, live)
         keep = keep | synth_slot
 
     # one packed gather per side (probe + verify + gather fused around
     # it — see _packed_gather) instead of one random gather per column
-    out_cols = _packed_gather(dict(left.columns), lid_c)
+    out_cols = _packed_gather(dict(left.columns), lid_c, live)
     rkeyset = set(right_keys)
     rpayload = {}
     for k, v in right.columns.items():
@@ -2182,7 +2329,7 @@ def hash_join(left: Batch, right: Batch, left_keys: Sequence[str],
             continue
         name = k if k not in out_cols else k + suffix
         rpayload[name] = v
-    for name, g in _packed_gather(rpayload, rid_abs).items():
+    for name, g in _packed_gather(rpayload, rid_abs, live).items():
         if left_synth:
             # unmatched left rows zero-fill the right columns
             g = _mask_rows(g, ~synth_slot)

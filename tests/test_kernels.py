@@ -243,28 +243,28 @@ def test_pack_unpack_roundtrip():
         assert np.array_equal(np.asarray(out[k]), np.asarray(cols[k])), k
 
 
-def test_permute_by_sort_wide_fallback(monkeypatch):
+@pytest.mark.parametrize("live", [0, 1, 7, 8, 9, 50])
+def test_permute_by_sort_wide_fallback(monkeypatch, live):
     """The lexsort+packed-gather fallback (rows wider than
-    _VALOPS_MAX_WORDS) produces the same result as the value-carry path."""
-    import numpy as np
-
-    import jax.numpy as jnp
-
+    _VALOPS_MAX_WORDS) produces the same result as the value-carry path
+    on the ``live`` valid rows — the rows its bounded gather fetches, a
+    chunk of 8 a trip — and zeros behind them."""
     from dryad_tpu.data.columnar import Batch
-    from dryad_tpu.ops import kernels
 
     n = 50
     rng = np.random.RandomState(6)
     b = Batch({"k": jnp.asarray(rng.randint(0, 9, n, np.int32)),
                "v": jnp.asarray(rng.randn(n).astype(np.float32))},
-              jnp.asarray(n, jnp.int32))
+              jnp.asarray(live, jnp.int32))
     want = kernels.sort_by_columns(b, [("k", False)])
     monkeypatch.setattr(kernels, "_VALOPS_MAX_WORDS", 0)
+    monkeypatch.setattr(kernels, "_GATHER_CHUNK", 8)
     got = kernels.sort_by_columns(b, [("k", False)])
     assert np.array_equal(np.asarray(got.columns["k"]),
                           np.asarray(want.columns["k"]))
-    assert np.allclose(np.asarray(got.columns["v"]),
-                       np.asarray(want.columns["v"]))
+    assert np.allclose(np.asarray(got.columns["v"])[:live],
+                       np.asarray(want.columns["v"])[:live])
+    assert not np.asarray(got.columns["v"])[live:].any()
 
 
 def test_pack_roundtrip_half_precision():
@@ -355,3 +355,265 @@ def test_sort_reconstruction_stability():
     out = batch_to_numpy(kernels.sort_by_columns(b, [("k", False)]))
     ref = sorted(range(n), key=lambda i: (k[i], i))
     np.testing.assert_array_equal(out["v"], v[ref])
+
+
+# ---------------------------------------------------------------------------
+# the gather behind a sort fetches the live rows (ISSUE 37): every kernel
+# whose sorted rows past a count are padding, on the index sort + bounded
+# gather (_VALOPS_MAX_ELEMS patched to 0, a chunk of 8 rows a trip),
+# against its own value-carry path
+
+_CHUNK, _CAP = 8, 29
+_LIVES = (0, 1, _CHUNK - 1, _CHUNK, _CHUNK + 1, _CAP)
+# past three quarters live the one whole take runs in the loop's place
+_DENSE = _CAP - _CAP // 4
+_JITS = {}
+
+
+def _both_paths(monkeypatch, name, fn, *args):
+    """``fn(*args)``, jitted, on the value-carry path and on the bounded
+    gather path.  ``name`` names ``fn`` and every static choice in it: a
+    program is traced once a name and path, whatever the counts."""
+    outs = []
+    for path in ("carry", "gather"):
+        with monkeypatch.context() as m:
+            if path == "gather":
+                m.setattr(kernels, "_VALOPS_MAX_ELEMS", 0)
+                m.setattr(kernels, "_GATHER_CHUNK", _CHUNK)
+            if (name, path) not in _JITS:
+                _JITS[name, path] = jax.jit(lambda *a, _fn=fn: _fn(*a))
+            outs.append(jax.tree.map(np.asarray, _JITS[name, path](*args)))
+    return outs
+
+
+def _assert_live_rows(got, want, zeros=True, ordered=True):
+    """Same count, same valid rows (row for row, or as a multiset where a
+    kernel leaves their order to an unstable sort), zeros in every leaf
+    past the count."""
+    n = int(want.count)
+    assert int(got.count) == n
+    assert sorted(got.columns) == sorted(want.columns)
+
+    def rows(b):
+        leaves = [x.reshape(x.shape[0], -1)[:n].tolist()
+                  for k in sorted(b.columns)
+                  for x in jax.tree.leaves(b.columns[k])]
+        out = list(zip(*leaves))
+        return out if ordered else sorted(out)
+
+    assert rows(got) == rows(want)
+    if zeros:
+        for k, col in got.columns.items():
+            for g in jax.tree.leaves(col):
+                assert not g[n:].any(), k
+
+
+def _wide_batch(count, seed=0, cap=_CAP):
+    """``cap`` rows of which ``count`` are valid; the padding rows hold
+    data like any other (nothing may read it)."""
+    from dryad_tpu.data.columnar import Batch, StringColumn
+    rng = np.random.RandomState(seed)
+    lens = rng.randint(1, 3, cap).astype(np.int32)
+    s = rng.randint(97, 100, (cap, 5)).astype(np.uint8)
+    s = np.where(np.arange(5)[None, :] < lens[:, None], s, 0).astype(np.uint8)
+    return Batch({
+        "k": jnp.asarray(rng.randint(0, 4, cap).astype(np.int32)),
+        "f": jnp.asarray(rng.randint(-8, 8, cap).astype(np.float32)),
+        "i": jnp.asarray(rng.randint(-2**30, 2**30, cap).astype(np.int32)),
+        "s": StringColumn(jnp.asarray(s), jnp.asarray(lens)),
+    }, jnp.asarray(count, jnp.int32))
+
+
+def _mask_of(live, seed=3, cap=_CAP):
+    return jnp.asarray(np.random.RandomState(seed).permutation(cap) < live)
+
+
+@pytest.mark.parametrize("live", _LIVES + (_DENSE, _DENSE + 1))
+def test_gather_live_rows_and_zeros(monkeypatch, live):
+    """_gather_live itself: the take in rows [0, live), zeros behind, for
+    a word matrix and for a 1-D source; the tally says what it fetched
+    (whole chunks, or all of it where the whole take ran)."""
+    rng = np.random.RandomState(1)
+    src = jnp.asarray(rng.randint(1, 99, (_CAP, 3)).astype(np.uint32))
+    idx = jnp.asarray(rng.permutation(_CAP).astype(np.int32))
+
+    def fn(src, idx, n):
+        with kernels.gather_tally() as tally:
+            out = kernels._gather_live(src, idx, n)
+            out1 = kernels._gather_live(src[:, 0] > 50, idx, n)
+        return out, out1, sum(f for f, _ in tally), sum(c for _, c in tally)
+
+    monkeypatch.setattr(kernels, "_GATHER_CHUNK", _CHUNK)
+    out, out1, fetched, unbounded = jax.jit(fn)(src, idx, live)
+    want = np.array(src)[np.asarray(idx)]
+    want[live:] = 0
+    np.testing.assert_array_equal(np.asarray(out), want)
+    np.testing.assert_array_equal(np.asarray(out1), want[:, 0] > 50)
+    trips = -(-live // _CHUNK)
+    assert int(fetched) == 2 * (_CAP if live > _DENSE
+                                else min(trips * _CHUNK, _CAP))
+    assert int(unbounded) == 2 * _CAP
+    # no count: the one take, and nothing tallied
+    with kernels.gather_tally() as tally:
+        full = kernels._gather_live(src, idx)
+    np.testing.assert_array_equal(np.asarray(full),
+                                  np.asarray(src)[np.asarray(idx)])
+    assert tally == []
+
+
+def test_gather_tally_leaves_out_a_cond_branch(monkeypatch):
+    """A bounded gather traced inside a ``lax.cond`` branch works and is
+    not tallied (its scalars cannot leave the branch)."""
+    monkeypatch.setattr(kernels, "_GATHER_CHUNK", _CHUNK)
+    src = jnp.arange(_CAP, dtype=jnp.uint32) + 1
+    idx = jnp.arange(_CAP, dtype=jnp.int32)[::-1]
+
+    def fn(src, idx, n):
+        with kernels.gather_tally() as tally:
+            out = jax.lax.cond(
+                n > 3, lambda: kernels._gather_live(src, idx, n),
+                lambda: jnp.zeros_like(src))
+        return out, len(tally)
+
+    out, tallied = jax.jit(fn)(src, idx, 5)
+    assert tallied == 0
+    np.testing.assert_array_equal(
+        np.asarray(out)[:5], np.arange(_CAP, 0, -1, dtype=np.uint32)[:5])
+    assert not np.asarray(out)[5:].any()
+
+
+@pytest.mark.parametrize("live", _LIVES + (_DENSE, _DENSE + 1))
+def test_bounded_compact(monkeypatch, live):
+    b = _wide_batch(_CAP)
+    want, got = _both_paths(monkeypatch, "compact", kernels.compact, b,
+                            _mask_of(live))
+    assert int(want.count) == live
+    _assert_live_rows(got, want)
+
+
+@pytest.mark.parametrize("live", _LIVES)
+@pytest.mark.parametrize("keys", [
+    [("k", False)], [("k", False), ("i", True)], [("s", False)],
+    [("f", True)]], ids=["one", "two", "string", "descending"])
+def test_bounded_sort_by_columns(monkeypatch, keys, live):
+    want, got = _both_paths(
+        monkeypatch, ("sort", str(keys)),
+        lambda b: kernels.sort_by_columns(b, keys), _wide_batch(live))
+    assert int(want.count) == live
+    _assert_live_rows(got, want)
+
+
+_BOUNDARY_AGGS = {
+    "sum-mean": (["k", "s"], {"n": ("count", None), "sf": ("sum", "f"),
+                              "mf": ("mean", "f")}),
+    "min-max": (["k", "s"], {"mn": ("min", "i"), "mx": ("max", "i")}),
+    "min-max-dense-key": (["k"], {"mn": ("min", "f"), "mx": ("max", "f"),
+                                  "n": ("count", None)}),
+    "sum64": (["k", "s"], {"s64": ("sum64", "i"), "si": ("sum", "i")}),
+}
+
+
+@pytest.mark.parametrize("live", _LIVES)
+@pytest.mark.parametrize("where", [False, True], ids=["count", "where"])
+@pytest.mark.parametrize("case", list(_BOUNDARY_AGGS))
+def test_bounded_group_aggregate_boundary(monkeypatch, case, where, live):
+    keys, aggs = _BOUNDARY_AGGS[case]
+    b = _wide_batch(_CAP if where else live)
+    assert kernels._boundary_eligible(b, aggs)[0]
+    assert not kernels._matmul_group_eligible(b, keys, aggs)
+    if where:
+        fn = lambda b, m: kernels.group_aggregate(  # noqa: E731
+            b, keys, aggs, where=m)
+        args = (b, _mask_of(live))
+    else:
+        fn = lambda b: kernels.group_aggregate(b, keys, aggs)  # noqa: E731
+        args = (b,)
+    want, got = _both_paths(monkeypatch, ("group", case, where), fn, *args)
+    assert (int(want.count) > 0) == (live > 0)
+    _assert_live_rows(got, want)
+
+
+@pytest.mark.parametrize("live", _LIVES)
+@pytest.mark.parametrize("keys", [["k", "s"], None], ids=["keys", "rows"])
+def test_bounded_distinct(monkeypatch, keys, live):
+    want, got = _both_paths(monkeypatch, ("distinct", str(keys)),
+                            lambda b: kernels.distinct(b, keys),
+                            _wide_batch(live))
+    assert int(want.count) <= live
+    _assert_live_rows(got, want)
+
+
+def _dimension(seed, str_len, count=6, cap=8):
+    """A right side of ``cap`` rows, ``count`` valid, unique in ``rk`` and
+    in ``rs`` (the keys of _wide_batch's ``k`` and ``s``)."""
+    from dryad_tpu.data.columnar import Batch, StringColumn
+    rng = np.random.RandomState(seed)
+    names = [b"a", b"b", b"c", b"aa", b"ab", b"ca", b"bb", b"cc"]
+    data = np.zeros((cap, str_len), np.uint8)
+    for r, nm in enumerate(names):
+        data[r, :len(nm)] = np.frombuffer(nm, np.uint8)
+    lens = np.array([len(nm) for nm in names], np.int32)
+    return Batch({
+        "rk": jnp.asarray(np.arange(cap, dtype=np.int32)),
+        "rs": StringColumn(jnp.asarray(data), jnp.asarray(lens)),
+        "p": jnp.asarray(rng.randint(1, 1000, cap).astype(np.int32)),
+        "f": jnp.asarray(rng.randint(1, 9, cap).astype(np.float32)),
+    }, jnp.asarray(count, jnp.int32))
+
+
+@pytest.mark.parametrize("live", _LIVES)
+@pytest.mark.parametrize("how", ["inner", "left"])
+@pytest.mark.parametrize("verify", ["bytes", "hash"])
+def test_bounded_lookup_join(monkeypatch, verify, how, live):
+    """_lookup_join: the union sort is bounded by both sides' counts and
+    the closing compaction by the rows kept; ``need`` says an overflow of
+    ``out_capacity`` (24 of the 29 left rows) the same."""
+    if verify == "bytes":           # both keys pack alike: byte-verified
+        lk, rk, right = ["k"], ["rk"], _dimension(2, 5)
+    else:                           # max_len 5 against 7: hash-verified
+        lk, rk, right = ["s"], ["rs"], _dimension(2, 7)
+    left = _wide_batch(live)
+    (want, wneed), (got, gneed) = _both_paths(
+        monkeypatch, ("lookup", verify, how),
+        lambda l, r: kernels._lookup_join(l, r, lk, rk, 24, "_r", how),
+        left, right)
+    assert int(gneed) == int(wneed)
+    if how == "left":
+        assert int(want.count) == min(live, 24)
+        assert (int(wneed) > 0) == (live > 24)
+    # the union's sort is unstable: the order of a key's left rows is
+    # its own, and so is which of them an overflow cuts
+    if int(wneed):
+        assert int(got.count) == int(want.count) == 24
+    else:
+        _assert_live_rows(got, want, ordered=False)
+
+
+@pytest.mark.parametrize("live", _LIVES)
+@pytest.mark.parametrize("how", ["inner", "left", "right", "full"])
+@pytest.mark.parametrize("tier", ["columns", "packed"])
+def test_bounded_hash_join(monkeypatch, tier, how, live):
+    """hash_join's general body: every gather over the output slots is
+    bounded by the candidate pairs, the closing compaction by the
+    verified ones; 24 slots overflow at 29 left rows (``need``).  Both
+    tiers of _packed_gather: a gather a column, and (the TPU's, routed
+    here by force_interpret) one gather of the packed word matrix."""
+    from dryad_tpu.data.columnar import Batch
+    from dryad_tpu.ops import pallas_kernels
+    if tier == "packed":
+        monkeypatch.setattr(pallas_kernels, "_FORCE_INTERPRET", True)
+    right = Batch({"rk": jnp.asarray(np.array([0, 0, 1, 2, 5, 5, 3, 3],
+                                               np.int32)),
+                   "p": jnp.arange(8, dtype=jnp.int32) + 10,
+                   "f": jnp.arange(8, dtype=jnp.float32) + 0.5},
+                  jnp.asarray(6, jnp.int32))
+    (want, wneed), (got, gneed) = _both_paths(
+        monkeypatch, ("hash_join", tier, how),
+        lambda l, r: kernels.hash_join(l, r, ["k"], ["rk"], 24, how=how),
+        _wide_batch(live), right)
+    assert int(gneed) == int(wneed)
+    if live == _CAP:
+        assert int(wneed) > 24
+    # right / full append the unmatched right rows through a concat that
+    # may repeat a row past the count
+    _assert_live_rows(got, want, zeros=how in ("inner", "left"))

@@ -145,6 +145,22 @@ def test_hash_join_compiles(topo, tpu_tier):
              _batch(sh, (), 1024, k=jnp.int32, c=jnp.int32))
 
 
+@pytest.mark.parametrize("words", [4, 26])
+def test_bounded_gather_compiles_at_the_cells_size(topo, words):
+    """The sort fallback's gather branch at 12,000,000 rows (the SQL
+    cells' capacity) bounded by a traced count: a ``while`` whose trip
+    count is the count's, around one chunk's gather (ISSUE 37)."""
+    sh = _one_chip(topo)
+    cap = 12_000_000
+    lane = jax.ShapeDtypeStruct((cap,), jnp.uint32, sharding=sh)
+    text = _compile(
+        lambda ls, o, n: kernels._gather_lanes(list(ls), o, n),
+        (lane,) * words,
+        jax.ShapeDtypeStruct((cap,), jnp.int32, sharding=sh),
+        jax.ShapeDtypeStruct((), jnp.int32, sharding=sh)).as_text()
+    assert f"[{kernels._GATHER_CHUNK},{words}]" in text
+
+
 @pytest.mark.parametrize("kind", ["hash", "range"])
 def test_exchange_compiles_on_four_chips(topo, tpu_tier, kind):
     """hash_exchange / range_exchange under shard_map on a Mesh of the

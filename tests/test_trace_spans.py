@@ -300,3 +300,65 @@ def test_one_store_fetch_a_partition_on_four_devices(tmp_path):
     programs = {s["attrs"]["program"] for s in spans
                 if s["kind"] == "stage"}
     assert "jit_stage_orderby_range_sort" in programs
+
+
+# -- what the bounded gathers fetched rides the stage info vector (ISSUE 37)
+
+
+def _gather_counts(events):
+    done = [e for e in events if e.get("event") == "stage_done"]
+    assert done and all("gather_rows" in e and "gather_rows_cap" in e
+                        for e in done)
+    return (sum(e["gather_rows"] for e in done),
+            sum(e["gather_rows_cap"] for e in done))
+
+
+@pytest.mark.parametrize("query,share", [
+    ("sort", "full"), ("filter", "part"), ("carried", "none")])
+def test_stage_done_says_what_the_bounded_gathers_fetched(monkeypatch,
+                                                          query, share):
+    """``gather_rows`` / ``gather_rows_cap`` on ``stage_done``: a sort of
+    a full batch fetches its capacity, a filter that keeps 10 of 4,096
+    rows one chunk of 64, and a program whose sorts carry their values
+    (no gather) reports 0 of 0."""
+    from dryad_tpu.ops import kernels
+    if share != "none":
+        monkeypatch.setattr(kernels, "_VALOPS_MAX_ELEMS", 0)
+        monkeypatch.setattr(kernels, "_GATHER_CHUNK", 64)
+    events = []
+    ds = _ctx(1, events).from_columns(_columns())
+    if query == "filter":
+        out = ds.where(lambda c: c["k"] < 10).collect()
+        assert sorted(out["k"].tolist()) == list(range(10))
+    else:
+        out = ds.order_by([("k", False)]).collect()
+        assert out["k"].tolist() == list(range(N))
+    fetched, unbounded = _gather_counts(events)
+    # and the benchmark's reader of the two, over the same events
+    from perfbench.layers import gather_live_share
+    read = gather_live_share.read({"queries": [{"i": 0, "events": events}]})
+    if share == "full":
+        assert fetched == unbounded > 0 and read == 1.0
+    elif share == "part":
+        assert (fetched, unbounded) == (64, N) and read == 64 / N
+    else:
+        assert (fetched, unbounded) == (0, 0) and read is None
+
+
+def _done(stage, rows, cap, **more):
+    return {"event": "stage_done", "stage": stage, "gather_rows": rows,
+            "gather_rows_cap": cap, **more}
+
+
+@pytest.mark.parametrize("queries,want", [
+    ([[{"event": "stage_done", "stage": 0}]], None),     # an older program
+    ([[_done(0, 0, 0)]], None),                          # no bounded gather
+    ([[_done(0, 10, 100), _done(1, 40, 100)]], 0.25),    # summed over stages
+    ([[_done(0, 100, 100, overflow=True), _done(0, 50, 200)]], 0.25),
+    ([[_done(0, 10, 100)], [_done(0, 30, 100)], [_done(0, 20, 100)]], 0.2),
+], ids=["no-lanes", "no-gather", "stages", "settled-attempt", "median"])
+def test_gather_live_share_reader(queries, want):
+    from perfbench.layers import gather_live_share
+    run = {"queries": [{"i": i, "events": ev}
+                       for i, ev in enumerate(queries)]}
+    assert gather_live_share.read(run) == want
