@@ -16,6 +16,15 @@ Key idioms:
     tensorized);
   * join = sort the right side by key hash, binary-search candidate ranges,
     expand by prefix-sum offsets, then verify real key equality.
+
+Device scopes: each kernel's body lies in a ``jax.named_scope`` of one
+fixed vocabulary — ``index_sort``, ``row_gather``, ``search``,
+``prefix_sum`` (pallas_kernels), ``group_aggregate``, ``compact``,
+``hash_join``, ``lookup_join``, and the exchange's phases
+``exchange_pack`` / ``exchange_unpack`` (parallel/shuffle.py) — so a
+device profile's ``op_name`` says which kernel an op belongs to
+(perfbench/kernel_scopes.py sums self time by it).  A scope is trace-time
+metadata: it changes no instruction.
 """
 
 from __future__ import annotations
@@ -43,6 +52,7 @@ __all__ = [
 AGG_KINDS = ("sum", "count", "min", "max", "mean", "any", "all", "sum64")
 
 
+@jax.named_scope("search")
 def searchsorted_small(bounds: jax.Array, q: jax.Array,
                        side: str = "left") -> jax.Array:
     """searchsorted against a SMALL sorted array (partition bounds, bucket
@@ -53,6 +63,7 @@ def searchsorted_small(bounds: jax.Array, q: jax.Array,
     return jnp.searchsorted(bounds, q, side=side, method="compare_all")
 
 
+@jax.named_scope("search")
 def searchsorted_big(table: jax.Array, q: jax.Array,
                      side: str = "left") -> jax.Array:
     """searchsorted against a LARGE sorted array (join candidate ranges).
@@ -208,6 +219,7 @@ def _segment_flags(differs: jax.Array, n_valid):
     return is_start, is_end, num_groups
 
 
+@jax.named_scope("index_sort")
 def _sort_segments_carry(hi: jax.Array, lo: jax.Array, valid: jax.Array,
                          n_valid, value_lanes, stable: bool = True,
                          bounded: bool = True):
@@ -238,6 +250,7 @@ def _sort_segments_carry(hi: jax.Array, lo: jax.Array, valid: jax.Array,
     return sorted_vals, is_start, is_end, num_groups
 
 
+@jax.named_scope("index_sort")
 def _sort_segments_dense(key_lane: jax.Array, valid: jax.Array, n_valid,
                          value_lanes):
     """Dense-key segmentation: like _sort_segments_carry but grouping by a
@@ -305,6 +318,7 @@ def gather_tally():
         _TALLY.open = prev
 
 
+@jax.named_scope("row_gather")
 def _gather_live(src: jax.Array, idx: jax.Array, live=None,
                  lanes_first: bool = False) -> jax.Array:
     """``jnp.take(src, idx, axis=0)`` in rows ``[0, live)`` and zeros in
@@ -378,6 +392,7 @@ def _carry_fits(cap: int, n_key_lanes: int, n_val_lanes: int) -> bool:
             and cap * (n_key_lanes + n_val_lanes) <= _VALOPS_MAX_ELEMS)
 
 
+@jax.named_scope("row_gather")
 def _gather_lanes(value_lanes, order: jax.Array, live=None):
     """The lanes' rows in ``order``: ONE packed gather of the stacked
     ``[cap, W]`` word matrix, of the first ``live`` rows alone where
@@ -390,6 +405,7 @@ def _gather_lanes(value_lanes, order: jax.Array, live=None):
     return [out[j] for j in range(len(value_lanes))]
 
 
+@jax.named_scope("index_sort")
 def _sort_carrying(key_lanes, value_lanes, cap: int, stable: bool = True,
                    live=None):
     """Sort by uint32 ``key_lanes`` (stable by default) returning the value
@@ -416,6 +432,7 @@ def _sort_carrying(key_lanes, value_lanes, cap: int, stable: bool = True,
             _gather_lanes(value_lanes, order, live))
 
 
+@jax.named_scope("index_sort")
 def _sort_fused2(lanes: List[jax.Array], packed: List[jax.Array],
                  cap: int, live=None):
     """Runtime key-lane fusion for 2-key-lane sorts (multi-key sort key
@@ -468,6 +485,7 @@ def _sort_fused2(lanes: List[jax.Array], packed: List[jax.Array],
     return jax.lax.cond(ok, fused, general, (inv, la, lb, tuple(packed)))
 
 
+@jax.named_scope("index_sort")
 def permute_by_sort(batch: Batch, key_lanes: Sequence[jax.Array],
                     count=None, stable: bool = True, live=None) -> Batch:
     """Sort the batch's rows by the given uint32 key lanes (most
@@ -487,6 +505,7 @@ def permute_by_sort(batch: Batch, key_lanes: Sequence[jax.Array],
 # filtering / compaction
 
 
+@jax.named_scope("compact")
 def compact(batch: Batch, keep: jax.Array) -> Batch:
     """Move rows where ``keep`` (and valid) to the front, preserving order.
 
@@ -514,6 +533,7 @@ def filter_rows(batch: Batch, predicate) -> Batch:
     return compact(batch, keep)
 
 
+@jax.named_scope("row_gather")
 def take(batch: Batch, n) -> Batch:
     return batch.with_count(jnp.minimum(batch.count, jnp.asarray(n, jnp.int32)))
 
@@ -659,6 +679,7 @@ def _string_lanes_invert(lanes: List[jax.Array], max_len: int,
     return StringColumn(data, lens)
 
 
+@jax.named_scope("index_sort")
 def sort_by_columns(batch: Batch, keys: Sequence[Tuple[str, bool]]) -> Batch:
     """Sort valid rows by the given (column, descending) keys; padding stays
     at the end.  Stable.
@@ -741,6 +762,7 @@ def sort_by_columns(batch: Batch, keys: Sequence[Tuple[str, bool]]) -> Batch:
 # group-by (sort + segment reduce)
 
 
+@jax.named_scope("index_sort")
 def _hash_sort_segments(hi: jax.Array, lo: jax.Array, valid: jax.Array,
                         extra_lanes: Tuple[jax.Array, ...] = ()):
     """Shared segment machinery: sort rows by 64-bit hash (invalid last),
@@ -883,6 +905,7 @@ def _wide_sub(a, b):
     return (a[0] - b[0] - (a[1] < b[1]).astype(jnp.int32), a[1] - b[1])
 
 
+@jax.named_scope("prefix_sum")
 def _wide_prefix(hi: jax.Array, lo: jax.Array):
     """Inclusive prefix sums of 64-bit values held as words, in two
     streamed 32-bit passes: the lower word is the wrapping uint32 prefix;
@@ -961,6 +984,7 @@ def _live_rows(batch: Batch, where):
     return valid, valid.sum(dtype=jnp.int32)
 
 
+@jax.named_scope("group_aggregate")
 def group_aggregate(batch: Batch, key_names: Sequence[str],
                     aggs: Dict[str, Tuple[str, str | None]],
                     where=None) -> Batch:
@@ -1949,6 +1973,7 @@ def distinct(batch: Batch, key_names: Sequence[str] | None = None) -> Batch:
 # whole-batch (scalar) aggregation
 
 
+@jax.named_scope("group_aggregate")
 def scalar_aggregate(batch: Batch,
                      aggs: Dict[str, Tuple[str, str | None]]) -> Dict[str, jax.Array]:
     """Masked full-batch reductions: out_name -> (kind, value_column|None)."""
@@ -2010,6 +2035,7 @@ def _keys_equal(a: Batch, a_idx, a_names, b: Batch, b_idx, b_names,
     return eq
 
 
+@jax.named_scope("row_gather")
 def _packed_gather(cols: Dict[str, Any], idx: jax.Array,
                    live=None) -> Dict[str, Any]:
     """Gather rows of several columns with ONE fused word-matrix gather:
@@ -2192,6 +2218,7 @@ def _lookup_join(left: Batch, right: Batch, left_keys: Sequence[str],
     return Batch(cols, cnt), need
 
 
+@jax.named_scope("hash_join")
 def hash_join(left: Batch, right: Batch, left_keys: Sequence[str],
               right_keys: Sequence[str], out_capacity: int,
               suffix: str = "_r", how: str = "inner",

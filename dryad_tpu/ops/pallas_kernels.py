@@ -271,6 +271,7 @@ def _scan2_kernel_body(R: int):
     return kern
 
 
+@jax.named_scope("prefix_sum")
 def prefix_sum(x: jax.Array) -> jax.Array:
     """Inclusive 1-D prefix sum (f32/i32/u32) — one streamed pass with an
     SMEM carry across sequential grid steps, vs XLA cumsum's log-depth
@@ -301,6 +302,7 @@ def prefix_sum(x: jax.Array) -> jax.Array:
     return y.reshape(-1)[:n]
 
 
+@jax.named_scope("prefix_sum")
 def prefix_sum2(x: jax.Array) -> Tuple[jax.Array, jax.Array]:
     """Compensated f32 inclusive prefix sum: returns an unevaluated
     (hi, lo) pair per prefix (hi + lo = the prefix to ~2x f32 precision).
@@ -357,6 +359,7 @@ def prefix_sum2(x: jax.Array) -> Tuple[jax.Array, jax.Array]:
 # module docstring records why the block-DMA kernels were removed.
 
 
+@jax.named_scope("row_gather")
 def slot_expand(words: jax.Array, offsets: jax.Array, C: int) -> jax.Array:
     """Send-slot expansion: ``words`` is the dest-sorted packed row matrix
     [cap, W] u32; destination d's rows start at ``offsets[d]`` (i32 [D]).
@@ -383,10 +386,12 @@ def slot_compact(words: jax.Array, counts: jax.Array, C: int,
     idx = jnp.arange(S, dtype=jnp.int32)
     rvalid = (idx % C) < jnp.take(counts, idx // C)
     # stable valid-first sort of the row ids, then one packed gather
-    perm = jnp.argsort(~rvalid, stable=True)
-    g = jnp.take(words, perm[:out_rows], axis=0) if S >= out_rows \
-        else jnp.pad(jnp.take(words, perm, axis=0),
-                     ((0, out_rows - S), (0, 0)))
+    with jax.named_scope("index_sort"):
+        perm = jnp.argsort(~rvalid, stable=True)
+    with jax.named_scope("row_gather"):
+        g = jnp.take(words, perm[:out_rows], axis=0) if S >= out_rows \
+            else jnp.pad(jnp.take(words, perm, axis=0),
+                         ((0, out_rows - S), (0, 0)))
     total = rvalid.sum(dtype=jnp.int32)
     gmask = jnp.arange(out_rows, dtype=jnp.int32) < total
     return jnp.where(gmask[:, None], g, 0)

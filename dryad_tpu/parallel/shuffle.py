@@ -97,15 +97,16 @@ def _exchange_one_axis(batch: Batch, dest: jax.Array, axis: str,
     # then slot expansion (pallas_kernels.slot_expand): each
     # destination's run is CONTIGUOUS in the sorted buffer, so the send
     # grid is one gather of the packed [cap, W] matrix.
-    lanes, spec = _pack_columns_u32(dict(batch.columns))
-    counts = hist_buckets(dest, D)                      # full counts [D]
-    offsets = jnp.cumsum(counts) - counts               # exclusive prefix
-    iota = jnp.arange(cap, dtype=jnp.uint32)
-    _, slanes = _sort_carrying([dest.astype(jnp.uint32), iota], lanes,
-                               cap, stable=False)
-    words = jnp.stack(slanes, axis=1)                   # [cap, W] u32
-    send_words = slot_expand(words, offsets.astype(jnp.int32), C)
-    send_counts = jnp.minimum(counts, C)
+    with jax.named_scope("exchange_pack"):
+        lanes, spec = _pack_columns_u32(dict(batch.columns))
+        counts = hist_buckets(dest, D)                      # full counts [D]
+        offsets = jnp.cumsum(counts) - counts               # exclusive prefix
+        iota = jnp.arange(cap, dtype=jnp.uint32)
+        _, slanes = _sort_carrying([dest.astype(jnp.uint32), iota], lanes,
+                                   cap, stable=False)
+        words = jnp.stack(slanes, axis=1)                   # [cap, W] u32
+        send_words = slot_expand(words, offsets.astype(jnp.int32), C)
+        send_counts = jnp.minimum(counts, C)
 
     # ONE all_to_all moves the whole packed matrix (the per-column form
     # issued one collective per column, two per StringColumn)
@@ -115,12 +116,13 @@ def _exchange_one_axis(batch: Batch, dest: jax.Array, axis: str,
     # UNPACK: the valid rows of each received source block are a prefix
     # (pallas_kernels.slot_compact: valid-first sort + one packed
     # gather); every sender clamped its send_counts to C already
-    total = recv_counts.sum(dtype=jnp.int32)
-    out_words = slot_compact(recv_words, recv_counts, C, out_capacity)
-    W = len(slanes)
-    out = Batch(_unpack_columns_u32(
-        [out_words[:, j] for j in range(W)], spec),
-        jnp.minimum(total, out_capacity))
+    with jax.named_scope("exchange_unpack"):
+        total = recv_counts.sum(dtype=jnp.int32)
+        out_words = slot_compact(recv_words, recv_counts, C, out_capacity)
+        W = len(slanes)
+        out = Batch(_unpack_columns_u32(
+            [out_words[:, j] for j in range(W)], spec),
+            jnp.minimum(total, out_capacity))
 
     # measured requirements (pre-truncation, so they are exact even when
     # this run dropped rows): true rows per destination over this axis...

@@ -18,6 +18,15 @@ placed by ``JAX_COMPILATION_CACHE_DIR`` when set and else by
 ``JobConfig.compilation_cache_dir`` (default ``<repo>/.jax_cache``; None
 disables).
 
+The key holds the program's metadata (``op_name`` with its named scopes,
+source file and line): by default JAX hashes a module stripped of it, so a
+program that differs from a cached one only by its scopes loads the
+cached executable and a device profile shows the OLD program's op names.
+The cost: the first run after an edit that moves a traced source line
+compiles again, the same as a program change.  Source files are keyed
+relative to the checkout (``jax_hlo_source_file_canonicalization_regex``,
+unless already set), so the same commit hits from any directory.
+
 :class:`FileCache` is the framework's OWN shared on-disk artifact cache
 (serialized plans, lowered specs — anything bytes) with the same
 concurrency contract the XLA cache relies on, made explicit: commits go
@@ -34,6 +43,7 @@ from __future__ import annotations
 
 import hashlib
 import os
+import re
 import threading
 from typing import Optional
 
@@ -43,9 +53,9 @@ __all__ = ["enable_persistent_cache", "DEFAULT_CACHE_DIR", "FileCache"]
 # cache key's reach (a directory that moves never hits), and a fresh
 # machine has no ~/.cache.  ``JAX_COMPILATION_CACHE_DIR`` places it from
 # outside.
-DEFAULT_CACHE_DIR = os.path.join(
-    os.path.dirname(os.path.dirname(os.path.dirname(
-        os.path.abspath(__file__)))), ".jax_cache")
+_ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__))))
+DEFAULT_CACHE_DIR = os.path.join(_ROOT, ".jax_cache")
 
 _lock = threading.Lock()
 _enabled_dir: Optional[str] = None
@@ -84,6 +94,11 @@ def enable_persistent_cache(path: Optional[str] = DEFAULT_CACHE_DIR) -> Optional
             os.makedirs(resolved, exist_ok=True)
             jax.config.update("jax_compilation_cache_dir", resolved)
         jax.config.update("jax_enable_compilation_cache", True)
+        jax.config.update("jax_compilation_cache_include_metadata_in_key",
+                          True)
+        if not jax.config.jax_hlo_source_file_canonicalization_regex:
+            jax.config.update("jax_hlo_source_file_canonicalization_regex",
+                              "^" + re.escape(_ROOT + os.sep))
         # cache every compile: stage programs are small but numerous, and
         # even a 0.3 s compile is worth skipping across worker processes
         jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)

@@ -1,0 +1,14 @@
+"""Exchange (``parallel/shuffle.py``: ``_exchange_one_axis`` after the
+``all_to_all`` — ``slot_compact`` and the unpacking of the columns):
+device milliseconds a query of every op under the phase scope
+``exchange_unpack``, at any depth — self time of the ``jit_stage_*``
+programs on the busiest device, summed over the traced queries ÷ their
+number (``perfbench/kernel_scopes.py``).  ``None`` off a real device, on
+a program without the scope, or where no exchange ran.  Source: device
+trace."""
+
+from perfbench import kernel_scopes
+
+
+def read(run):
+    return kernel_scopes.ms_per_query(run, "exchange_unpack")

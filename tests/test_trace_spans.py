@@ -283,6 +283,133 @@ def test_compiled_module_carries_the_name():
     assert "jit_per_shard" not in text
 
 
+# -- the kernel scopes a device profile reads (ISSUE 38) ----------------------
+
+_HEAVY_OP = re.compile(r"\s(sort|gather|scatter)\(")
+_OP_NAME = re.compile(r'op_name="([^"]*)"')
+
+
+def _heavy_op_scopes(text):
+    """Every scope of the vocabulary on the op_name of the sort, gather
+    and scatter instructions of a compiled program; and those of the
+    instructions that lie under none."""
+    from perfbench.kernel_scopes import scopes_of
+    seen, bare = set(), []
+    for line in text.splitlines():
+        if not _HEAVY_OP.search(line):
+            continue
+        m = _OP_NAME.search(line)
+        sc = scopes_of(m.group(1) if m else None)
+        seen.update(sc)
+        if not sc:
+            bare.append(line.strip()[:120])
+    return seen, bare
+
+
+def _stage_texts(ctx, ds):
+    """Each stage program of the query compiled, its inputs' shapes from
+    the stages before it run abstractly."""
+    graph = plan_query(ds.node, ctx.nparts, hosts=ctx.hosts,
+                       levels=ctx.levels, config=ctx.config)
+    results, texts = {}, []
+    for st in graph.stages:
+        args = [results[leg.src] if isinstance(leg.src, int)
+                else leg.src[1].batch for leg in st.legs]
+        fn = ctx.executor._build_stage_fn(
+            st, 1, ctx.config.initial_send_slack, len(args), False)
+        results[st.id] = jax.eval_shape(fn, *args)[0]
+        texts.append(fn.lower(*args).compile().as_text())
+    return "\n".join(texts)
+
+
+def _q3_shaped(ctx):
+    rng = np.random.default_rng(5)
+    n = 256
+    cat = sql.Catalog()
+    cat.register_columns("c", {
+        "c_key": np.arange(n // 8, dtype=np.int32),
+        "c_seg": rng.integers(0, 3, n // 8).astype(np.int32)})
+    cat.register_columns("o", {
+        "o_key": np.arange(n // 2, dtype=np.int32),
+        "o_cust": rng.integers(0, n // 8, n // 2).astype(np.int32),
+        "o_date": rng.integers(0, 100, n // 2).astype(np.int32)})
+    cat.register_columns("l", {
+        "l_order": rng.integers(0, n // 2, n).astype(np.int32),
+        "l_price": rng.uniform(1, 9, n).astype(np.float32),
+        "l_ship": rng.integers(0, 100, n).astype(np.int32)})
+    return sql.query(ctx, cat, """
+        select l_order, sum(l_price) as rev, o_date from c, o, l
+        where c_seg = 1 and c_key = o_cust and l_order = o_key
+              and o_date < 50 and l_ship > 50
+        group by l_order, o_date order by rev desc limit 10""")
+
+
+@pytest.mark.parametrize("query,want", [
+    ("sort", {"index_sort", "row_gather"}),
+    ("group_by", {"group_aggregate", "index_sort"}),
+    ("two_joins", {"hash_join", "search", "index_sort", "row_gather",
+                   "compact", "group_aggregate"}),
+])
+def test_stage_programs_name_their_kernels(monkeypatch, query, want):
+    """A one-partition sort (index sort + packed gather), a group-by and a
+    Q3-shaped query of two hash joins: the scopes of the kernels they run
+    are path components of their sorts', gathers' and scatters' op names,
+    and no sort, gather or scatter lies under none."""
+    from dryad_tpu.ops import kernels
+    monkeypatch.setattr(kernels, "_VALOPS_MAX_ELEMS", 0)
+    monkeypatch.setattr(kernels, "_GATHER_CHUNK", 64)
+    ctx = _ctx(1, None)
+    if query == "two_joins":
+        ds = _q3_shaped(ctx)
+    else:
+        ds = (_sorted if query == "sort" else _grouped)(
+            ctx.from_columns(_columns()))
+    seen, bare = _heavy_op_scopes(_stage_texts(ctx, ds))
+    assert want <= seen, (want - seen, seen)
+    assert not bare, bare
+
+
+def test_the_exchange_names_its_pack_and_unpack():
+    """A range exchange over four devices on the pack path: the dest sort
+    and ``slot_expand`` under ``exchange_pack``, ``slot_compact`` under
+    ``exchange_unpack``, each with its kernel scope inside; the
+    ``all-to-all`` under neither."""
+    from jax.sharding import Mesh, PartitionSpec as P
+    from dryad_tpu.data.columnar import Batch
+    from dryad_tpu.ops.pallas_kernels import force_interpret
+    from dryad_tpu.parallel import shuffle
+    mesh = Mesh(np.asarray(jax.devices()[:4]), ("dp",))
+    cap = 256
+    rng = np.random.default_rng(0)
+    batch = Batch({"k": rng.integers(0, 1 << 20, (4, cap)).astype(np.int32),
+                   "v": rng.uniform(size=(4, cap)).astype(np.float32)},
+                  np.full((4,), cap, np.int32))
+    bounds = np.sort(rng.integers(0, 1 << 20, 3)).astype(np.uint32)
+    bounds = np.stack([bounds ^ np.uint32(1 << 31),
+                       np.zeros(3, np.uint32)], axis=1)
+
+    def per_shard(b, bnd):
+        b = jax.tree.map(lambda x: x[0], b)
+        out, *_ = shuffle.range_exchange(b, [("k", False)], bnd, cap)
+        return jax.tree.map(lambda x: x[None], out)
+
+    fn = jax.jit(jax.shard_map(per_shard, mesh=mesh,
+                               in_specs=(P("dp"), P()), out_specs=P("dp"),
+                               check_vma=False))
+    with force_interpret():
+        text = fn.lower(batch, bounds).compile().as_text()
+    pack = [ln for ln in text.splitlines() if "/exchange_pack/" in ln]
+    unpack = [ln for ln in text.splitlines() if "/exchange_unpack/" in ln]
+    assert any(_HEAVY_OP.search(ln) and "/index_sort/" in ln for ln in pack)
+    assert any(_HEAVY_OP.search(ln) and "/row_gather/" in ln for ln in pack)
+    assert any(_HEAVY_OP.search(ln) and "/index_sort/" in ln
+               for ln in unpack)
+    assert any(_HEAVY_OP.search(ln) and "/row_gather/" in ln
+               for ln in unpack)
+    a2a = [ln for ln in text.splitlines() if " all-to-all(" in ln]
+    assert a2a and not any("/exchange_" in ln for ln in a2a)
+
+
 def test_one_store_fetch_a_partition_on_four_devices(tmp_path):
     src = _input_store(tmp_path, 4)
     events = []
