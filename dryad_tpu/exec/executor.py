@@ -683,8 +683,10 @@ class Executor:
         """``facts`` (optional) is filled while the program is traced
         with what only its shapes tell: ``join_in_bytes``, the bytes of
         a join's two inputs as the program holds them (every leg's ops
-        and exchange applied), capacity x row bytes over all shards, and
-        ``build_rows``, the rows of capacity of its right input."""
+        and exchange applied), capacity x row bytes over all shards,
+        ``build_rows``, the rows of capacity of its right input, and, where
+        the program holds hash_join's general body, ``search_sort_rows``,
+        the elements its search phase sorts (kernels.search_sort_rows)."""
         def per_shard(*args):
             with kernels.gather_tally() as gathers:
                 return ops_and_info(gathers, *args)
@@ -760,6 +762,10 @@ class Executor:
                             for x in jax.tree.leaves(b.columns))
                         facts["build_rows"] = \
                             self.nparts * rest[0].capacity
+                        if _join_kernel(op.params) != "lookup":
+                            facts["search_sort_rows"] = \
+                                self.nparts * kernels.search_sort_rows(
+                                    cur.capacity, rest[0].capacity)
                     cur, nd = _apply_op(cur, op, scale, rest,
                                         self.axes, slack)
                     rest = []

@@ -14,8 +14,9 @@ Key idioms:
   * group-by = 64-bit key hash -> lexsort -> segment boundaries -> segment
     reductions (sort-based, like the reference's hash/merge GroupBy but
     tensorized);
-  * join = sort the right side by key hash, binary-search candidate ranges,
-    expand by prefix-sum offsets, then verify real key equality.
+  * join = sort the right side by key hash, find each left row's candidate
+    range with one merge sort of both sides' hashes, hand each output slot
+    its left row with a running max, then verify real key equality.
 
 Device scopes: each kernel's body lies in a ``jax.named_scope`` of one
 fixed vocabulary — ``index_sort``, ``row_gather``, ``search``,
@@ -61,17 +62,6 @@ def searchsorted_small(bounds: jax.Array, q: jax.Array,
     — while 'compare_all' fuses into |bounds| vectorized compares
     (~free for |bounds| <= a few thousand)."""
     return jnp.searchsorted(bounds, q, side=side, method="compare_all")
-
-
-@jax.named_scope("search")
-def searchsorted_big(table: jax.Array, q: jax.Array,
-                     side: str = "left") -> jax.Array:
-    """searchsorted against a LARGE sorted array (join candidate ranges).
-    'sort' method = one variadic device sort of (table ++ queries) —
-    O((n+m) log^2) vectorized passes instead of the scan method's
-    log(n) rounds of random gathers (TPU random gathers run ~9 ns/row;
-    sorts ride the vector units)."""
-    return jnp.searchsorted(table, q, side=side, method="sort")
 
 
 # ---------------------------------------------------------------------------
@@ -2218,6 +2208,73 @@ def _lookup_join(left: Batch, right: Batch, left_keys: Sequence[str],
     return Batch(cols, cnt), need
 
 
+def search_sort_rows(n_left: int, n_right: int) -> int:
+    """Elements the search phase of ``hash_join``'s general body sorts for
+    ``n_left`` left and ``n_right`` right rows of capacity: the merge of
+    both sides' hashes, then the sort back to left-row order — once,
+    carrying both bounds, or past the operand budget
+    (``_VALOPS_MAX_ELEMS``) once a bound."""
+    n = n_left + n_right
+    return n * (2 if _carry_fits(n, 1, 2) else 3)
+
+
+@jax.named_scope("search")
+def _candidate_ranges(rkey: jax.Array, lh: jax.Array
+                      ) -> Tuple[jax.Array, jax.Array]:
+    """``start[i]`` = #{k : rkey[k] < lh[i]} and ``stop[i]`` = #{k :
+    rkey[k] <= lh[i]} against the SORTED ``rkey`` (``np.searchsorted``'s
+    left and right sides), with one merge sort of both sides and no
+    scatter.
+
+    The union ``rkey ++ lh`` is sorted on (hash, position): a right row's
+    position is below every left row's, so in a run of equal hashes the
+    right rows come first.  In that order ``stop`` is the running count of
+    right rows, and ``start`` that count where the hash's run began — a
+    running max of the counts at run starts, since the counts never fall.
+    A sort keyed on the left rows' own positions brings both back."""
+    from dryad_tpu.ops.pallas_kernels import prefix_max, prefix_sum
+    n, m = rkey.shape[0], lh.shape[0]
+    pos = jnp.arange(n + m, dtype=jnp.int32)
+    skey, spos = jax.lax.sort((jnp.concatenate([rkey, lh]), pos),
+                              num_keys=2, is_stable=False)
+    is_right = (spos < n).astype(jnp.int32)
+    stop = prefix_sum(is_right)
+    run_start = (pos == 0) | (skey != jnp.roll(skey, 1))
+    start = prefix_max(jnp.where(run_start, stop - is_right, 0))
+    # left rows first, at their own positions; right rows past them
+    back = jnp.where(spos < n, spos + m, spos - n)
+    if _carry_fits(n + m, 1, 2):
+        _, start, stop = jax.lax.sort((back, start, stop), num_keys=1,
+                                      is_stable=False)
+    else:
+        _, start = jax.lax.sort((back, start), num_keys=1, is_stable=False)
+        _, stop = jax.lax.sort((back, stop), num_keys=1, is_stable=False)
+    return start[:m], stop[:m]
+
+
+@jax.named_scope("search")
+def _slot_owners(cum: jax.Array, mult: jax.Array,
+                 out_capacity: int) -> jax.Array:
+    """``lid[t]`` = #{i : cum[i] <= t}, the left row that owns output slot
+    ``t``, for every slot ``t < cum[-1]``; ``cum`` is the inclusive prefix
+    sum of the non-negative ``mult``.  Row ``i`` owns the slots ``[cum[i]
+    - mult[i], cum[i])``: its index is written at the first of them and a
+    running max over the slots hands it to the rest — one scatter of a
+    mark a row and one pass over the slots, whatever share of them is
+    live.  Slots at or past ``cum[-1]`` hold some row's index; every
+    reader masks them or reads the first ``live`` alone."""
+    from dryad_tpu.ops.pallas_kernels import prefix_max
+    rows = jnp.arange(cum.shape[0], dtype=jnp.int32)
+    first = cum - mult
+    # a row whose first slot is not in range gets an index of its own past
+    # the end: no two indices are equal, and those marks are dropped
+    at = jnp.where((mult > 0) & (first < out_capacity), first,
+                   out_capacity + rows)
+    marks = jnp.zeros((out_capacity,), jnp.int32).at[at].set(
+        rows, mode="drop", unique_indices=True)
+    return prefix_max(marks)
+
+
 @jax.named_scope("hash_join")
 def hash_join(left: Batch, right: Batch, left_keys: Sequence[str],
               right_keys: Sequence[str], out_capacity: int,
@@ -2303,13 +2360,14 @@ def hash_join(left: Batch, right: Batch, left_keys: Sequence[str],
          jnp.arange(right.capacity, dtype=jnp.int32)),
         num_keys=3, is_stable=False)
     rkey = jnp.take(rh, order)
-    # mark invalid rows with sentinel max keys so searchsorted excludes them;
-    # valid rows hashing to the sentinel just become extra candidates.
+    # invalid rows take the sentinel max key, so they sort last; a left
+    # row hashing to the sentinel counts them as candidates, and
+    # ``rid < right.count`` below drops them
     pos = jnp.arange(right.capacity)
     rkey = jnp.where(pos < right.count, rkey, jnp.uint32(0xFFFFFFFF))
 
-    start = searchsorted_big(rkey, lh, side="left")
-    stop = searchsorted_big(rkey, lh, side="right")
+    # each left row's candidates are the sorted right rows [start, stop)
+    start, stop = _candidate_ranges(rkey, lh)
     mult = jnp.where(lvalid, stop - start, 0)
     if how not in ("inner", "left", "right", "full"):
         raise ValueError(f"unknown join how={how!r}")
@@ -2323,8 +2381,7 @@ def hash_join(left: Batch, right: Batch, left_keys: Sequence[str],
     cum = jnp.cumsum(mult)
     total = cum[-1]
     t = jnp.arange(out_capacity, dtype=jnp.int32)
-    lid = searchsorted_big(cum, t, side="right").astype(jnp.int32)
-    lid_c = jnp.minimum(lid, left.capacity - 1)
+    lid_c = _slot_owners(cum, mult, out_capacity)
     # slots at or past ``total`` hold nothing (slot_valid): every gather
     # over the slots fetches the first ``live`` alone and leaves zeros
     # behind them, which index row 0 and are masked like what was there

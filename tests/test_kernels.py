@@ -617,3 +617,141 @@ def test_bounded_hash_join(monkeypatch, tier, how, live):
     # right / full append the unmatched right rows through a concat that
     # may repeat a row past the count
     _assert_live_rows(got, want, zeros=how in ("inner", "left"))
+
+
+# -- hash_join's search phase: merge-sort ranges, slot owners --------------
+
+_SENT = 0xFFFFFFFF
+
+
+def _search_case(case, rng):
+    """(sorted right hashes with the sentinel past the valid rows, left
+    hashes, left validity, how="left" synthetic rows?, out_capacity)."""
+    n, m = 24, 40
+    nval = {"empty_right": 0}.get(case, 18)
+    rh = np.sort(rng.integers(0, 6, nval).astype(np.uint32))
+    lh = rng.integers(0, 8, m).astype(np.uint32)
+    lvalid = np.arange(m) < {"empty_left": 0}.get(case, 33)
+    if case == "sentinel":      # valid rows of both sides hash to it
+        rh[-2:] = _SENT
+        lh[[3, 7]] = _SENT
+    if case == "total_zero":
+        lh = rng.integers(100, 200, m).astype(np.uint32)
+    rkey = np.concatenate([rh, np.full(n - nval, _SENT, np.uint32)])
+    total = np.where(lvalid, np.searchsorted(rkey, lh, "right")
+                     - np.searchsorted(rkey, lh, "left"), 0).sum()
+    oc = max(int(total) // 2, 1) if case == "overflow" else 96
+    return rkey, lh, lvalid, case == "left_synth", oc
+
+
+@pytest.mark.parametrize("budget", ["one_sort_back", "a_sort_a_bound"])
+@pytest.mark.parametrize("case", ["duplicates", "sentinel", "empty_left",
+                                  "empty_right", "total_zero", "overflow",
+                                  "left_synth"])
+def test_candidate_ranges_and_slot_owners(monkeypatch, case, budget):
+    """The search phase against np.searchsorted: ``start`` / ``stop`` are
+    its left / right sides over the sorted right hashes (sentinel
+    padding included, as the old three-search phase had them), and the
+    slot owners are ``searchsorted(cum, t, "right")`` at every slot below
+    ``min(total, out_capacity)`` — the slots anything reads."""
+    if budget == "a_sort_a_bound":
+        monkeypatch.setattr(kernels, "_VALOPS_MAX_ELEMS", 0)
+    rkey, lh, lvalid, synth, oc = _search_case(
+        case, np.random.default_rng(len(case)))
+    start, stop = jax.jit(kernels._candidate_ranges)(jnp.asarray(rkey),
+                                                     jnp.asarray(lh))
+    np.testing.assert_array_equal(start, np.searchsorted(rkey, lh, "left"))
+    np.testing.assert_array_equal(stop, np.searchsorted(rkey, lh, "right"))
+    mult = np.where(lvalid, np.asarray(stop) - np.asarray(start), 0)
+    if synth:
+        mult = np.where(lvalid & (mult == 0), 1, mult)
+    cum = np.cumsum(mult).astype(np.int32)
+    lid = jax.jit(kernels._slot_owners, static_argnums=2)(
+        jnp.asarray(cum), jnp.asarray(mult.astype(np.int32)), oc)
+    live = min(int(cum[-1]), oc)
+    want = np.searchsorted(cum, np.arange(oc), "right")
+    np.testing.assert_array_equal(np.asarray(lid)[:live], want[:live])
+    assert {"empty_left": live == 0, "empty_right": live == 0,
+            "total_zero": live == 0,
+            "overflow": int(cum[-1]) > oc}.get(case, live > 0)
+
+
+def _np_hash_join(left, right, how, oc):
+    """hash_join's output by hand: slots in left-row order, each row's
+    candidates in (hash, right row) order, a row of the left side with
+    none of them one synthetic slot (left / full), the first ``oc`` slots
+    kept where the keys are equal, then (right / full) the right rows no
+    kept slot matched; and ``need``."""
+    def h(b, keys):
+        hi, lo = (np.asarray(x) for x in hash_batch_keys(b, keys))
+        return hi ^ ((lo.astype(np.uint64) * 0x9E3779B9)
+                     & 0xFFFFFFFF).astype(np.uint32)
+    lh, rh = h(left, ["k"]), h(right, ["rk"])
+    lk, la = np.asarray(left["k"]), np.asarray(left["a"])
+    rk, rp = np.asarray(right["rk"]), np.asarray(right["p"])
+    nl, nr = int(left.count), int(right.count)
+    order = sorted(range(nr), key=lambda j: (rh[j], j))
+    rkey = [rh[j] for j in order] + [_SENT] * (right.capacity - nr)
+    slots = []
+    for i in range(nl):
+        cand = [s for s, key in enumerate(rkey) if key == lh[i]]
+        if not cand and how in ("left", "full"):
+            slots.append((i, None))
+        slots += [(i, s) for s in cand]
+    total = len(slots)
+    rows, matched = [], set()
+    for i, s in slots[:oc]:
+        if s is None:
+            rows.append((lk[i], la[i], 0))
+        elif s < nr and lk[i] == rk[order[s]]:
+            rows.append((lk[i], la[i], rp[order[s]]))
+            matched.add(order[s])
+    need = total if total > oc else 0
+    if how in ("right", "full"):
+        extra = [(rk[j], 0, rp[j]) for j in range(nr) if j not in matched]
+        rows += extra
+        need = total + len(extra) if total + len(extra) > oc else need
+    return rows[:oc], need
+
+
+@pytest.mark.parametrize("oc", [200, 30], ids=["fits", "overflows"])
+@pytest.mark.parametrize("budget", ["one_sort_back", "a_sort_a_bound"])
+@pytest.mark.parametrize("how", ["inner", "left", "right", "full"])
+def test_hash_join_matches_numpy(monkeypatch, how, budget, oc):
+    """hash_join's general body gives the numpy reference's rows, in its
+    order, and its ``need``, whichever way the search phase sorts back."""
+    if budget == "a_sort_a_bound":
+        monkeypatch.setattr(kernels, "_VALOPS_MAX_ELEMS", 0)
+    rng = np.random.default_rng(11)
+    left = batch_from_numpy({"k": rng.integers(0, 10, 40).astype(np.int32),
+                             "a": np.arange(40, dtype=np.int32) + 1},
+                            capacity=48)
+    right = batch_from_numpy({"rk": rng.integers(0, 8, 12).astype(np.int32),
+                              "p": np.arange(12, dtype=np.int32) + 100},
+                             capacity=16)
+    out, need = jax.jit(lambda l, r: kernels.hash_join(
+        l, r, ["k"], ["rk"], oc, how=how))(left, right)
+    rows, want_need = _np_hash_join(left, right, how, oc)
+    got = batch_to_numpy(out)
+    assert int(out.count) == len(rows)
+    assert list(zip(got["k"].tolist(), got["a"].tolist(),
+                    got["p"].tolist())) == [tuple(map(int, r)) for r in rows]
+    assert int(need) == want_need
+    assert (want_need > 0) == (oc == 30)
+
+
+def test_hash_join_search_phase_scatters_once():
+    """An inner general hash_join's program holds two sorts and ONE
+    scatter under the ``search`` scope (the slot owners' marks): no rank
+    scatter of a sort-based searchsorted is left."""
+    import re
+    left = batch_from_numpy({"k": np.arange(40, dtype=np.int32) % 7,
+                             "a": np.arange(40, dtype=np.int32)}, capacity=64)
+    right = batch_from_numpy({"rk": np.arange(20, dtype=np.int32) % 5,
+                              "p": np.arange(20, dtype=np.int32)}, capacity=32)
+    text = jax.jit(lambda l, r: kernels.hash_join(
+        l, r, ["k"], ["rk"], 128)).lower(left, right).compile().as_text()
+    ops = [m.group(1) for ln in text.splitlines()
+           if "/search/" in ln and (m := re.search(r"\s(scatter|sort)\(", ln))]
+    assert sorted(ops) == ["scatter", "sort", "sort"]
+    assert kernels.search_sort_rows(64, 32) == 2 * (64 + 32)

@@ -9,8 +9,9 @@ import pytest
 import jax.numpy as jnp
 
 from dryad_tpu.ops.pallas_kernels import (force_interpret, hist_buckets,
-                                          pallas_active, prefix_sum,
-                                          slot_compact, slot_expand)
+                                          pallas_active, prefix_max,
+                                          prefix_sum, slot_compact,
+                                          slot_expand)
 
 
 def _modes():
@@ -87,6 +88,18 @@ def test_prefix_sum_unpadded_sizes(mode):
         x = jnp.ones((n,), jnp.int32)
         y = np.asarray(_run(mode, lambda: prefix_sum(x)))
         assert (y == np.arange(1, n + 1)).all(), n
+
+
+@pytest.mark.parametrize("mode", _modes())
+@pytest.mark.parametrize("n", [1, 128, 32768, 32769, 70_000])
+def test_prefix_max(mode, n):
+    """The running max across tiles (the SMEM carry) and within one (the
+    lanes, then the rows), negatives and the pad path included."""
+    rng = np.random.RandomState(n)
+    x = rng.randint(-(1 << 30), 1 << 30, n).astype(np.int32)
+    x[n // 3:] = np.minimum(x[n // 3:], 0)      # a max the carry holds
+    y = np.asarray(_run(mode, lambda: prefix_max(jnp.asarray(x))))
+    assert (y == np.maximum.accumulate(x)).all()
 
 
 def test_boundary_group_path_used_and_matches_scan():

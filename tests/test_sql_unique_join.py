@@ -203,10 +203,11 @@ def test_the_marked_stage_holds_no_cond_and_no_second_join(devices8):
         lookup, general = _stage_text(ctx, ms, ma), _stage_text(ctx, ps, pa)
         assert "conditional(" not in lookup + general
         # every op of the kernel's body lies in its scope; hash_join's
-        # general body (three more sorts: the build side's, and two
-        # binary searches done as sorts) is not in the marked program
+        # general body (the build side's sort, its search phase's merge of
+        # both sides and the sort back) is not in the marked program
         assert "/lookup_join/" in lookup and "/lookup_join/" not in general
-        assert general.count(" sort(") >= lookup.count(" sort(") + 3
+        assert "/search/" in general and "/search/" not in lookup
+        assert general.count(" sort(") > lookup.count(" sort(")
 
     # asked for blindly the program holds both kernels and a cond
     two = [Batch({"k": jnp.zeros(64, jnp.int32), n: jnp.zeros(64, jnp.int32)},
@@ -242,6 +243,12 @@ def test_stage_done_says_which_kernel_ran(devices8):
             [k == "lookup" for k in kernels_want]
         assert all(e["build_rows"] > 0 and e["join_in_bytes"] > 0
                    for e in joins)
+        # hash_join's general body says what its search phase sorts: at
+        # least both inputs' rows of capacity, twice
+        assert all(("search_sort_rows" in e) == (e["join_kernel"] != "lookup")
+                   for e in joins)
+        assert all(e["search_sort_rows"] > 2 * e["build_rows"]
+                   for e in joins if e["join_kernel"] != "lookup")
         # the stage with the group-by says how many sums ran in 64 bits
         assert sum(e.get("int64_sums", 0) for e in done) >= 1
         assert all("int64_sums" not in e for e in joins)
@@ -250,6 +257,8 @@ def test_stage_done_says_which_kernel_ran(devices8):
                  and "join_kernel" in (e.get("attrs") or {})]
         assert {s["attrs"]["join_kernel"] for s in spans} == \
             set(kernels_want)
+        assert all(("search_sort_rows" in s["attrs"])
+                   == (s["attrs"]["join_kernel"] != "lookup") for s in spans)
 
 
 def test_dataset_join_right_unique_true_keeps_the_checked_form(devices8):

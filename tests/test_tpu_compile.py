@@ -96,6 +96,15 @@ def test_prefix_sum2_compiles(topo, tpu_tier):
     assert c.as_text().count("tpu_custom_call") == 1
 
 
+def test_prefix_max_compiles(topo, tpu_tier):
+    """The running max of hash_join's search phase, at the 15 M rows of
+    Q3's merge of ``lineitem`` and ``orders``."""
+    x = jax.ShapeDtypeStruct((15_000_000,), jnp.int32,
+                             sharding=_one_chip(topo))
+    c = _compile(pallas_kernels.prefix_max, x)
+    assert c.as_text().count("tpu_custom_call") == 1
+
+
 def test_sort_by_columns_compiles(topo, tpu_tier):
     b = _batch(_one_chip(topo), (), 4096, **_TERASORT)
     _compile(lambda x: kernels.sort_by_columns(x, [("key", False)]), b)
