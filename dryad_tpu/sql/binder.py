@@ -56,11 +56,18 @@ class BoundJoin:
     # the build side — the larger input probes, so a key/foreign-key
     # join fits the stage's first out_capacity
     swap: bool = False
-    # the build side is ONE base table and the join's columns cover the
-    # key its catalog entry carries (verified where its rows were
-    # written): at most one build row a probe row, so the stage runs
-    # the lookup join alone (_Binder._mark_unique)
-    unique: bool = False
+    # set where the build side is unique on the join's columns — at most
+    # one build row a probe row, so the stage runs the lookup join alone
+    # (_Binder._mark_unique) — and says why: "table", the build side is
+    # ONE base table and the columns cover the key its catalog entry
+    # carries (verified where its rows were written); "inherited", the
+    # build side is what is joined so far, and the columns cover a key
+    # it kept through the joins before
+    unique_by: Optional[str] = None
+
+    @property
+    def unique(self) -> bool:
+        return self.unique_by is not None
 
 
 @dataclasses.dataclass
@@ -359,24 +366,35 @@ class _Binder:
 
     def _mark_unique(self, joins: List[BoundJoin],
                      base_renames: Dict[str, str]) -> None:
-        """Mark the inner and left joins whose build (right) side is one
-        base table — its own filter and projection may lie between: a
-        subset of a key's rows is still unique — and whose join columns
-        cover the key that table's catalog entry carries.  The build
-        side is the joined table, or under ``swap`` what is joined so
-        far, which is one base table at the first join only."""
-        for i, j in enumerate(joins):
-            if j.how not in ("inner", "left"):
-                continue
-            if not j.swap:
-                table, cols = j.table, {j.renames[k] for k in j.right_keys}
-            elif i == 0:
-                table = self.stmt.table.name
-                cols = {base_renames.get(k) for k in j.left_keys}
-            else:
-                continue
+        """Mark the inner and left joins whose build (right) side is
+        unique on the join's columns.  The build side is the joined
+        table, or under ``swap`` what is joined so far.  A base table is
+        unique on the key its catalog entry carries — its own filter and
+        projection may lie between: a subset of a key's rows is still
+        unique.  What is joined so far starts as the base table; after a
+        marked join it keeps the key of that join's probe side (each
+        probe row met at most one build row), after any other join it has
+        none."""
+        def key_of(table: str, renames: Dict[str, str]):
+            """The physical names of the table's key, or None."""
             key = self.catalog.get(table).unique
-            j.unique = bool(key) and set(key) <= cols
+            if not key or not set(key) <= set(renames.values()):
+                return None
+            return {p for p, col in renames.items() if col in key}
+
+        kept = key_of(self.stmt.table.name, base_renames)
+        for i, j in enumerate(joins):
+            own = key_of(j.table, j.renames)
+            if j.how in ("inner", "left"):
+                if not j.swap:
+                    if own is not None and own <= set(j.right_keys):
+                        j.unique_by = "table"
+                elif kept is not None and kept <= set(j.left_keys):
+                    j.unique_by = "table" if i == 0 else "inherited"
+            if not j.unique:
+                kept = None
+            elif j.swap:
+                kept = own
 
     # -- expressions -------------------------------------------------------
 

@@ -16,8 +16,9 @@ Shape of the lowered chain::
     WHERE, one table's     .where(Predicate) on that table's scan, below
                            its join; a column only it reads goes after
     JOIN / FROM-list keys  .join(...), the larger input on the left; a
-                           build side that is one base table joined on
-                           the key its store carries: right_unique=
+                           build side joined on a key — the key its
+                           store carries, or one what is joined so far
+                           kept through the joins before: right_unique=
                            "verified", the lookup kernel alone
     WHERE, the residual    .where(Predicate) above the joins
     GROUP BY + aggregates  pre-Projector (keys + agg-input exprs)
@@ -142,7 +143,8 @@ def lower(ctx, catalog: Catalog, bound: BoundSelect, loader=None,
         other = root(j.table, j.alias, j.renames, j.span)
         lks = [live(k) for k in j.left_keys]
         # a key the catalog carries was verified where the rows were
-        # written: no run-time check, no second kernel in the program
+        # written, and one kept through marked joins holds by their
+        # kernel: no run-time check, no second kernel in the program
         ru = "verified" if j.unique else False
         cur = _stamp(other.join(cur, j.right_keys, lks, how=j.how,
                                 right_unique=ru)
@@ -154,7 +156,9 @@ def lower(ctx, catalog: Catalog, bound: BoundSelect, loader=None,
                          else zip(j.right_keys, lks))
     if span is not None:
         span.set(columns_kept=kept, columns_stored=stored,
-                 unique_joins=sum(j.unique for j in bound.joins))
+                 unique_joins=sum(j.unique for j in bound.joins),
+                 inherited_unique_joins=sum(j.unique_by == "inherited"
+                                            for j in bound.joins))
     if bound.residual is not None:
         cur = _stamp(cur.where(Predicate(above(bound.residual)),
                                label="sql-where"),

@@ -2,7 +2,8 @@
 
 ``sql/binder`` marks exactly the inner and left joins whose build side is
 one base table (its own filter and projection may lie between) joined on
-columns that cover the key its catalog entry carries; ``sql/lower`` hands
+columns that cover the key its catalog entry carries, or what is joined
+so far joined on a key it kept through marked joins; ``sql/lower`` hands
 them to ``Dataset.join(right_unique="verified")``; the stage's program is
 then ``kernels._lookup_join`` and nothing else — no duplicate check, no
 ``cond``, no general hash-join body — and says so on its ``stage_done``
@@ -146,6 +147,84 @@ def test_the_build_side_must_be_one_base_table(devices8):
     q_left = ("select f_rev, p_brand from fact left join part "
               "on f_part = p_key")
     assert [j.unique for j in _bound(_catalog(t), q_left).joins] == [True]
+
+
+# -- a key kept through the joins before ---------------------------------------
+
+def _chain(keys=("c", "o", "d")):
+    """Schemas alone: ``c`` (100 rows, key c_k), ``o`` (1,000, key o_k,
+    foreign key o_c), ``d`` (60, key d_k, foreign key d_o), ``b`` (50, no
+    key), ``g`` (5,000, no key, foreign key g_o) and ``h`` (20,000, no
+    key, foreign key h_o): a larger table probes what is joined so far."""
+    def num(*cols):
+        return {c: {"kind": "num", "dtype": "int32"} for c in cols}
+    tables = {"c": (num("c_k", "c_x"), 100, ["c_k"]),
+              "o": (num("o_k", "o_c", "o_x"), 1000, ["o_k"]),
+              "d": (num("d_k", "d_o", "d_x"), 60, ["d_k"]),
+              "b": (num("b_x", "b_y"), 50, None),
+              "g": (num("g_o", "g_y"), 5000, None),
+              "h": (num("h_o", "h_y"), 20000, None)}
+    cat = sql.Catalog()
+    for name, (schema, rows, key) in tables.items():
+        cat.register_schema(name, schema, rows=rows,
+                            unique=key if name in keys else None)
+    return cat
+
+
+def _marks(cat, text):
+    return [(j.table, j.swap, j.unique_by) for j in _bound(cat, text).joins]
+
+
+@pytest.mark.parametrize("text,want", [
+    # o probes the keyed c: o_k is still a key of what is joined, and
+    # the larger g probes it on o_k
+    ("select g_y from c, o, g where o_c = c_k and g_o = o_k",
+     [("o", True, "table"), ("g", True, "inherited")]),
+    # a join to a table on its key (unswapped) keeps the probe's key
+    ("select g_y from c join o on o_c = c_k join d on d_k = o_k "
+     "join g on g_o = o_k",
+     [("o", True, "table"), ("d", False, "table"), ("g", True, "inherited")]),
+    ("select g_y from c join o on o_c = c_k left join d on d_k = o_k "
+     "join g on g_o = o_k",
+     [("o", True, "table"), ("d", False, "table"), ("g", True, "inherited")]),
+], ids=["from-list", "inner-key-join-between", "left-key-join-between"])
+def test_a_key_is_kept_through_a_marked_join(text, want):
+    assert _marks(_chain(), text) == want
+
+
+@pytest.mark.parametrize("text,want", [
+    # general hash join between: an o row may meet many b rows
+    ("select g_y from o, b, g where o_x = b_x and g_o = o_k",
+     [("b", False, None), ("g", True, None)]),
+    # the same join without b: o is the build side, and one base table
+    ("select g_y from o, g where g_o = o_k", [("g", True, "table")]),
+    # a right or a full join between: the result is no longer o's rows
+    ("select g_y from c join o on o_c = c_k right join d on d_k = o_k "
+     "join g on g_o = o_k",
+     [("o", True, "table"), ("d", False, None), ("g", True, None)]),
+    ("select g_y from c join o on o_c = c_k full join d on d_k = o_k "
+     "join g on g_o = o_k",
+     [("o", True, "table"), ("d", False, None), ("g", True, None)]),
+    # the keyed o was the build side: what is joined so far carries the
+    # key of its probe side, g, which has none: o_k repeats
+    ("select h_y from g, o, h where g_o = o_k and h_o = o_k",
+     [("o", False, "table"), ("h", True, None)]),
+    ("select g_y from o, d, g where d_o = o_k and g_o = o_k",
+     [("d", False, None), ("g", True, None)]),
+], ids=["hash-join", "control", "right", "full", "keyed-build-side",
+        "unkeyed-probe"])
+def test_no_key_survives_a_join_that_may_repeat_rows(text, want):
+    assert _marks(_chain(), text) == want
+
+
+def test_an_inherited_key_needs_its_base_key():
+    """Without c's key nothing is marked; without o's, the first join is
+    still marked but nothing is inherited: o's rows have no key to keep."""
+    q = "select g_y from c, o, g where o_c = c_k and g_o = o_k"
+    assert _marks(_chain(keys=("o",)), q) == [("o", True, None),
+                                               ("g", True, None)]
+    assert _marks(_chain(keys=("c",)), q) == [("o", True, "table"),
+                                               ("g", True, None)]
 
 
 def test_lower_hands_the_mark_to_the_plan_and_counts_it(devices8):
