@@ -52,6 +52,9 @@ _INFO_TIE = 4 + _SLOT_FEEDBACK_LEGS
 # the two lanes after it: the rows the program's bounded gathers fetched,
 # and the rows they would have fetched unbounded (kernels.gather_tally)
 _INFO_GATHER = _INFO_TIE + 1
+# and the lane after those: the group-bys whose one-hot branch ran
+# (kernels.onehot_tally)
+_INFO_ONEHOT = _INFO_GATHER + 2
 # a program's name with jit's own "jit_" before it stays within 64
 _PROGRAM_NAME_MAX = 60
 
@@ -546,13 +549,15 @@ def _wide_sum_count(stage: Stage) -> Dict[str, int]:
                               if isinstance(spec, tuple))}
 
 
-def gather_info_attrs(info) -> Dict[str, int]:
-    """``gather_rows`` / ``gather_rows_cap`` of a fetched stage info
-    ``[P, lanes]``: the rows the program's bounded gathers fetched, over
-    all shards, and the rows the same gathers would have fetched
-    unbounded (their capacities)."""
+def stage_info_attrs(info) -> Dict[str, int]:
+    """``gather_rows`` / ``gather_rows_cap`` / ``onehot_groups`` of a
+    fetched stage info ``[P, lanes]``: the rows the program's bounded
+    gathers fetched, over all shards, the rows the same gathers would
+    have fetched unbounded (their capacities), and the group-bys whose
+    one-hot branch ran (the most on any one shard)."""
     return {"gather_rows": int(info[:, _INFO_GATHER].sum()),
-            "gather_rows_cap": int(info[:, _INFO_GATHER + 1].sum())}
+            "gather_rows_cap": int(info[:, _INFO_GATHER + 1].sum()),
+            "onehot_groups": int(info[:, _INFO_ONEHOT].max())}
 
 
 def _join_kernel(params) -> str:
@@ -688,10 +693,11 @@ class Executor:
         the program holds hash_join's general body, ``search_sort_rows``,
         the elements its search phase sorts (kernels.search_sort_rows)."""
         def per_shard(*args):
-            with kernels.gather_tally() as gathers:
-                return ops_and_info(gathers, *args)
+            with kernels.gather_tally() as gathers, \
+                    kernels.onehot_tally() as onehots:
+                return ops_and_info(gathers, onehots, *args)
 
-        def ops_and_info(gathers, *args):
+        def ops_and_info(gathers, onehots, *args):
             leg_batches = [
                 _squeeze(b) for b in args[:n_legs]]
             bounds = args[n_legs] if has_bounds else None
@@ -775,20 +781,23 @@ class Executor:
                 needs = jnp.maximum(needs, nd)
             # ONE small per-shard info vector [need_scale, need_slack,
             # exchange_need_scale, out_count, slot_used x 4 legs,
-            # tie_rows, gather_rows, gather_rows_cap]: the
-            # executor host-fetches exactly one array per stage — a
+            # tie_rows, gather_rows, gather_rows_cap, onehot_groups]:
+            # the executor host-fetches exactly one array per stage — a
             # second fetch per stage costs a full link round trip, which
             # dominates iterative jobs on high-latency links.  The slot
             # lanes are the exchanges' own measured send-slot feedback
             # (free: they ride the fetch that happens anyway), and so do
-            # the range exchange's tie counter (_INFO_TIE) and what the
-            # program's bounded gathers fetched (_INFO_GATHER).
+            # the range exchange's tie counter (_INFO_TIE), what the
+            # program's bounded gathers fetched (_INFO_GATHER) and its
+            # group-bys' one-hot branches (_INFO_ONEHOT).
             fetched = sum((f for f, _ in gathers), jnp.zeros((), jnp.int32))
             unbounded = min(sum(c for _, c in gathers), 2**31 - 1)
+            onehot = sum(onehots, jnp.zeros((), jnp.int32))
             info = jnp.concatenate([needs, exch_need[None],
                                     cur.count.astype(jnp.int32)[None],
                                     slots, ties[None], fetched[None],
-                                    jnp.full((1,), unbounded, jnp.int32)])
+                                    jnp.full((1,), unbounded, jnp.int32),
+                                    onehot[None]])
             return _expand(cur), info[None]
 
         per_shard.__name__ = per_shard.__qualname__ = \
@@ -1220,8 +1229,8 @@ class Executor:
             if range_attrs:
                 range_attrs["tie_rows"] = int(info[:, _INFO_TIE].sum())
                 span.set(**range_attrs)
-            gather_attrs = gather_info_attrs(info)
-            span.set(**gather_attrs)
+            info_attrs = stage_info_attrs(info)
+            span.set(**info_attrs)
             out_bytes = int(sum(
                 x.size * x.dtype.itemsize
                 for x in jax.tree.leaves(out_batch)))
@@ -1241,7 +1250,7 @@ class Executor:
                 "cache_hit": cache_hit,
                 "dispatches": 2,   # program launch + info fetch
                 "wall_s": round(wall, 4), **join_attrs, **range_attrs,
-                **filter_attrs, **gather_attrs})
+                **filter_attrs, **info_attrs})
             decision = self._decide_needs(stage, scale, slack, salted,
                                           need_scale, need_slack,
                                           need_exch)

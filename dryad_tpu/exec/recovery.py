@@ -275,7 +275,7 @@ class Run:
             if range_attrs:
                 from dryad_tpu.exec.executor import _INFO_TIE
                 range_attrs["tie_rows"] = int(info[:, _INFO_TIE].sum())
-            from dryad_tpu.exec.executor import gather_info_attrs
+            from dryad_tpu.exec.executor import stage_info_attrs
             self._event({
                 "event": "stage_done", "stage": stage.id,
                 "label": stage.label, "attempt": 0,
@@ -290,7 +290,7 @@ class Run:
                 "dispatches": 1,   # program launch only; fetch amortized
                 "wall_s": rec["enqueue_s"], **rec.get("join", {}),
                 **range_attrs, **rec.get("filters", {}),
-                **gather_info_attrs(info)})
+                **stage_info_attrs(info)})
             if not of:
                 # settled clean at the planned shapes: cross-check the
                 # measured rows/bytes against the static cost prediction

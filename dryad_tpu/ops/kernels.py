@@ -287,11 +287,31 @@ _VALOPS_MAX_ELEMS = 48 << 20
 # count up by at most a millisecond's rows
 _GATHER_CHUNK = 1 << 16
 
-# open bounded-gather tally of this thread (gather_tally), or nothing
+# open tallies of this thread by kind (gather_tally, onehot_tally): the
+# trace they were opened under and their entries, or nothing
 _TALLY = threading.local()
 
 
 @contextlib.contextmanager
+def _open_tally(kind: str):
+    prev = getattr(_TALLY, kind, None)
+    entries: List[Any] = []
+    setattr(_TALLY, kind, (jax.core.get_opaque_trace_state(), entries))
+    try:
+        yield entries
+    finally:
+        setattr(_TALLY, kind, prev)
+
+
+def _tally(kind: str, entry) -> None:
+    """Add ``entry`` to the open tally of ``kind`` if it was opened under
+    the trace being traced now: a value traced under another trace — a
+    ``lax.cond`` branch, a loop body — cannot leave it."""
+    tally = getattr(_TALLY, kind, None)
+    if tally is not None and tally[0] == jax.core.get_opaque_trace_state():
+        tally[1].append(entry)
+
+
 def gather_tally():
     """Collect what the bounded gathers traced inside the block fetch:
     a list of ``(rows fetched: traced int32 scalar, rows the unbounded
@@ -299,13 +319,16 @@ def gather_tally():
     program sums them into its info vector (exec/executor).  A gather
     traced under another trace than the block's own — a ``lax.cond``
     branch, a loop body — is left out: its scalars cannot leave it."""
-    prev = getattr(_TALLY, "open", None)
-    entries: List[Tuple[jax.Array, int]] = []
-    _TALLY.open = (jax.core.get_opaque_trace_state(), entries)
-    try:
-        yield entries
-    finally:
-        _TALLY.open = prev
+    return _open_tally("gather")
+
+
+def onehot_tally():
+    """Collect the run-time predicates of the group-bys traced inside
+    the block that can take the one-hot lowering
+    (``_group_aggregate_smallkey``): a list of traced bool scalars, True
+    where the one-hot branch runs.  Left out as ``gather_tally`` leaves
+    a gather out."""
+    return _open_tally("onehot")
 
 
 @jax.named_scope("row_gather")
@@ -371,9 +394,7 @@ def _gather_live(src: jax.Array, idx: jax.Array, live=None,
         dense = live > cap - cap // 4
         out = jax.lax.cond(dense, whole_masked, chunks)
         fetched = jnp.where(dense, cap, jnp.minimum(trips * chunk, cap))
-    tally = getattr(_TALLY, "open", None)
-    if tally is not None and tally[0] == jax.core.get_opaque_trace_state():
-        tally[1].append((fetched, cap))
+    _tally("gather", (fetched, cap))
     return out
 
 
@@ -1010,7 +1031,8 @@ def group_aggregate(batch: Batch, key_names: Sequence[str],
     count/mean-parts) merges partial aggregates — that is how the distributed
     GroupBy works (planner splits it into local combine -> shuffle -> merge).
 
-    Lowering: small-span integer keys take the one-hot MXU path (a
+    Lowering: a small-span integer key, or short keys (``_short_key``)
+    whose spans multiply to a small number, take the one-hot MXU path (a
     runtime span check, _group_aggregate_smallkey); then the
     boundary-carry path when the agg set allows it; else the
     segmented-scan path (_group_aggregate_scan).
@@ -1041,15 +1063,30 @@ _SMALLKEY_CHUNK = 16384    # rows per accumulation step (bounds the
                            # materialized [chunk, slots] one-hot to 32 MB)
 
 
-def _matmul_group_eligible(batch: Batch, key_names, aggs) -> bool:
-    """Static half of the MXU group gate: single integer dense key,
-    sums/means over float columns only (f32 accumulation is exact for
-    counts below 2^24 but not for wide integers), partition small enough
-    that counts stay exact."""
-    if not _dense_fast_key(batch, key_names):
+def _short_key(col) -> bool:
+    """A grouping key whose values span at most 2^16 whatever they are: a
+    bool, an integer of at most 16 bits, a string of at most one byte
+    (SQL's ``CHAR(1)``).  Keys that are all short can share one
+    mixed-radix slot of the one-hot lowering."""
+    if isinstance(col, StringColumn):
+        return col.max_len == 1
+    if isinstance(col, Int64Column) or col.ndim != 1:
         return False
-    kd = batch.columns[key_names[0]].dtype
-    if not jnp.issubdtype(kd, jnp.integer):
+    return col.dtype == jnp.bool_ or (
+        jnp.issubdtype(col.dtype, jnp.integer) and col.dtype.itemsize <= 2)
+
+
+def _matmul_group_eligible(batch: Batch, key_names, aggs) -> bool:
+    """Static half of the MXU group gate: one integer dense key of any
+    width, or keys that are all short (``_short_key``); sums/means over
+    4-byte float columns only (a float accumulator is not exact for wide
+    integers), a partition below 2^24 rows (a mean divides by its count
+    in float32).  From shapes and dtypes alone: a key set that fails it
+    traces no span check and no second branch."""
+    cols = [batch.columns[k] for k in key_names]
+    one_int = (_dense_fast_key(batch, key_names)
+               and jnp.issubdtype(cols[0].dtype, jnp.integer))
+    if not (one_int or (cols and all(_short_key(c) for c in cols))):
         return False
     if batch.capacity >= (1 << 24):
         return False
@@ -1066,33 +1103,81 @@ def _matmul_group_eligible(batch: Batch, key_names, aggs) -> bool:
     return True
 
 
+def _slot_digit(col) -> jax.Array:
+    """A key's digit of the one-hot slot, in the key's own order: a
+    one-byte string is 0 when empty, else its byte + 1 (the empty string
+    first, as _string_sort_lanes orders it); a bool or a short integer
+    its value as int32; a 32-bit integer key its value."""
+    if isinstance(col, StringColumn):
+        return jnp.where(col.lengths > 0,
+                         col.data[:, 0].astype(jnp.int32) + 1, 0)
+    if col.dtype.itemsize < 4:
+        return col.astype(jnp.int32)
+    return col
+
+
+def _digit_column(d: jax.Array, like):
+    """The key column of digits ``d`` (_slot_digit's inverse)."""
+    if isinstance(like, StringColumn):
+        return StringColumn(
+            jnp.maximum(d - 1, 0).astype(jnp.uint8)[:, None],
+            (d > 0).astype(like.lengths.dtype))
+    return d.astype(like.dtype)
+
+
 def _group_aggregate_smallkey(batch: Batch, key_names: Sequence[str],
                               aggs: Dict[str, Tuple[str, str | None]],
                               fallback, where=None) -> Batch:
-    """One-hot MXU group aggregation for small-span integer keys.
+    """One-hot MXU group aggregation for keys of a small span.
 
     The sort-based lowerings pay ~log^2(n) compare-exchange stages per
     row; when the key span fits ``_SMALLKEY_SLOTS``, per-group sums are
     ONE matmul against the one-hot slot matrix — the systolic array does
     the scatter-add the chip has no scatter unit for (k-means recenter,
     reference role: the broadcast/aggregation ML loops of BASELINE
-    config 5).  The span is a runtime property, so the choice is a
-    lax.cond against the sort fallback: wide-key batches pay one extra
-    min/max reduction, nothing else.
+    config 5).  Several keys share one mixed-radix slot, the first key
+    most significant: ``sum((digit_i - min_i) * prod(span_j, j > i))``
+    over their live rows' digits (_slot_digit), so the groups come out in
+    the keys' lexicographic order and each key is rebuilt from its
+    digit.  The span is a runtime property, so the choice is a lax.cond
+    against the sort fallback: a batch whose spans multiply past the
+    slots pays one min/max reduction a key, nothing else.
+
+    Sums accumulate chunk by chunk in a compensated (hi, lo) pair
+    (pallas_kernels._dd_add): 733 chunk sums of a 12 M-row batch added
+    into one float32 would lose up to ~733 roundings of the group's sum;
+    the pair keeps the error near one.  Counts accumulate in int32.
     """
-    kcol = batch.columns[key_names[0]]
+    from dryad_tpu.ops.pallas_kernels import _dd_add
+    keys = [batch.columns[k] for k in key_names]
     cap = batch.capacity
     valid, n_valid = _live_rows(batch, where)
     S = _SMALLKEY_SLOTS
-    kmin = jnp.min(jnp.where(valid, kcol, jnp.iinfo(kcol.dtype).max))
-    kmax = jnp.max(jnp.where(valid, kcol, jnp.iinfo(kcol.dtype).min))
-    # i32 wraparound on huge true spans lands negative -> fallback
-    span = kmax - kmin + 1
-    use = (n_valid > 0) & (kmax >= kmin) & (span > 0) & (span <= S)
+    use = n_valid > 0
+    mins, spans = [], []
+    for d in map(_slot_digit, keys):
+        dmin = jnp.min(jnp.where(valid, d, jnp.iinfo(d.dtype).max))
+        dmax = jnp.max(jnp.where(valid, d, jnp.iinfo(d.dtype).min))
+        # i32 wraparound on huge true spans lands negative -> fallback
+        span = dmax - dmin + 1
+        use = use & (dmax >= dmin) & (span > 0) & (span <= S)
+        mins.append(dmin)
+        spans.append(span)
+    if len(keys) > 1:
+        # short keys: each span <= 2^16 + 1, the running product is
+        # clamped past the slots, so nothing here wraps
+        slots = jnp.ones((), jnp.int32)
+        for span in spans:
+            slots = jnp.minimum(slots * jnp.clip(span, 0, S + 1), S + 1)
+        use = use & (slots <= S)
+    _tally("onehot", use)
 
     def mm_branch(b: Batch) -> Batch:
-        k = b.columns[key_names[0]]
-        slot = jnp.clip((k - kmin).astype(jnp.int32), 0, S - 1)
+        slot = None
+        for k, dmin, span in zip(key_names, mins, spans):
+            off = (_slot_digit(b.columns[k]) - dmin).astype(jnp.int32)
+            slot = off if slot is None else slot * span + off
+        slot = jnp.clip(slot, 0, S - 1)
         slot = jnp.where(valid, slot, S)          # padding matches nothing
         vals: Dict[str, jax.Array] = {}
         shapes: Dict[str, Tuple] = {}
@@ -1117,22 +1202,23 @@ def _group_aggregate_smallkey(batch: Batch, key_names: Sequence[str],
                 .reshape(nb, _SMALLKEY_CHUNK, m_tot)
 
         def step(acc, xs):
-            cnt_acc, sum_acc = acc
+            cnt_acc, sum_hi, sum_lo = acc
             sl = xs[0]
-            oh = (sl[:, None] ==
-                  jnp.arange(S, dtype=jnp.int32)[None, :]) \
-                .astype(jnp.float32)                      # [chunk, S]
-            cnt_acc = cnt_acc + jnp.sum(oh, axis=0)
+            hit = sl[:, None] == jnp.arange(S, dtype=jnp.int32)[None, :]
+            cnt_acc = cnt_acc + jnp.sum(hit, axis=0, dtype=jnp.int32)
             if names:
-                sum_acc = sum_acc + jax.lax.dot_general(
-                    oh, xs[1], (((0,), (0,)), ((), ())),
+                part = jax.lax.dot_general(
+                    hit.astype(jnp.float32), xs[1], (((0,), (0,)), ((), ())),
                     precision=jax.lax.Precision.HIGHEST)  # [S, m]
-            return (cnt_acc, sum_acc), None
+                sum_hi, sum_lo = _dd_add(sum_hi, sum_lo, part,
+                                         jnp.zeros_like(part))
+            return (cnt_acc, sum_hi, sum_lo), None
 
-        init = (jnp.zeros((S,), jnp.float32),
-                jnp.zeros((S, max(m_tot, 1)), jnp.float32))
-        (cnts, sums), _ = jax.lax.scan(
-            step, init, (slot_p, vcat) if names else (slot_p,))
+        zeros = jnp.zeros((S, max(m_tot, 1)), jnp.float32)
+        (cnts, sum_hi, sum_lo), _ = jax.lax.scan(
+            step, (jnp.zeros((S,), jnp.int32), zeros, zeros),
+            (slot_p, vcat) if names else (slot_p,))
+        sums = sum_hi + sum_lo
         nonempty = cnts > 0
         num_groups = nonempty.sum(dtype=jnp.int32)
         order = jnp.argsort(~nonempty, stable=True)       # [S], tiny
@@ -1148,10 +1234,19 @@ def _group_aggregate_smallkey(batch: Batch, key_names: Sequence[str],
                 return jnp.pad(g, padw)
             return g[:cap]
 
+        # each slot's digits: mixed radix, the last key least significant
+        if len(keys) == 1:
+            digits = [mins[0] + rank]
+        else:
+            digits, r = [], rank
+            for dmin, span in zip(mins[::-1], spans[::-1]):
+                span = jnp.maximum(span, 1)
+                digits.insert(0, dmin + r % span)
+                r = r // span
         out_cols: Dict[str, Any] = {}
-        out_cols[key_names[0]] = place(
-            (kmin + rank).astype(kcol.dtype))
-        cnt_g = place(cnts).astype(jnp.int32)
+        for k, col, d in zip(key_names, keys, digits):
+            out_cols[k] = jax.tree.map(place, _digit_column(d, col))
+        cnt_g = place(cnts)
         off = 0
         col_sums: Dict[str, jax.Array] = {}
         for n in names:

@@ -211,9 +211,10 @@ def _scan_kernel_body(R: int, dt):
 
 def _dd_add(hi1, lo1, hi2, lo2):
     """Double-single (compensated) f32 add via Knuth TwoSum + Dekker
-    renormalization — the ONE implementation both the pallas kernel body
-    and the XLA fallback scan use (drift here silently changes error
-    bounds)."""
+    renormalization — the ONE implementation the pallas kernel body, the
+    XLA fallback scan and the one-hot group-by's chunk accumulator
+    (ops/kernels._group_aggregate_smallkey) use (drift here silently
+    changes error bounds)."""
     s = hi1 + hi2
     bb = s - hi1
     err = (hi1 - (s - bb)) + (hi2 - bb)

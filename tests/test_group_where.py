@@ -30,9 +30,10 @@ _AGGS = {
              "lo": ("min", "x"), "hi": ("max", "y")},
 }
 _KEYS = {"dense": ["k"], "strings": ["f", "g"], "const": ["c"]}
-# the one-hot path takes a single integer key; two strings go to the
-# boundary path under the same aggregates (that is Q1)
+# the one-hot path takes a single integer key, or short keys such as two
+# one-byte strings (that is Q1); min/max send them to the sort paths
 _CASES = [("smallkey", "dense"), ("smallkey", "const"),
+          ("smallkey", "strings"),
           ("boundary", "dense"), ("boundary", "strings"),
           ("boundary", "const"),
           ("scan", "dense"), ("scan", "strings"), ("scan", "const")]
@@ -76,14 +77,24 @@ def _batch(keys, wide, mask, poison):
         t["y"] = np.where(dead & (np.arange(CAP) % 2 == 0),
                           np.float32(-np.inf), t["y"])
         t["k"] = np.where(dead, np.int32(2**31 - 1), t["k"])
+        # a dropped string key far from the kept ones: a span taken over
+        # all rows would no longer fit the one-hot slots
+        for name in ("f", "g"):
+            t[name] = [b"~" if d else v for d, v in zip(dead, t[name])]
     return batch_from_numpy(t, capacity=CAP, str_max_len=1).with_count(N), t
 
 
 @functools.lru_cache(maxsize=None)
 def _fns(lowering, key):
     keys, aggs = _KEYS[key], _AGGS[lowering]
-    masked = jax.jit(lambda b, m: kernels.group_aggregate(b, keys, aggs,
-                                                          where=m))
+
+    @jax.jit
+    def masked(b, m):
+        # and how many group-bys took the one-hot branch
+        with kernels.onehot_tally() as onehot:
+            out = kernels.group_aggregate(b, keys, aggs, where=m)
+        return out, sum(onehot, jnp.zeros((), jnp.int32))
+
     compacted = jax.jit(lambda b, m: kernels.group_aggregate(
         kernels.compact(b, m), keys, aggs))
     return masked, compacted
@@ -115,13 +126,16 @@ def test_masked_group_equals_compact_then_group(lowering, key, mask_kind,
     mask = _mask(mask_kind)
     batch, table = _batch(keys, wide=lowering != "smallkey", mask=mask,
                           poison=poison)
-    # two string keys under the one-hot aggregates are Q1: boundary path
+    # two string keys under the one-hot aggregates are Q1: one-hot path
     assert _route(batch, keys, aggs) == lowering
     masked, compacted = _fns(lowering, key)
-    got = _groups(batch_to_numpy(masked(batch, jnp.asarray(mask))), keys)
+    out, onehot = masked(batch, jnp.asarray(mask))
+    got = _groups(batch_to_numpy(out), keys)
     ref = _groups(batch_to_numpy(compacted(batch, jnp.asarray(mask))), keys)
 
     kept = np.nonzero(mask & (np.arange(CAP) < N))[0]
+    # the kept rows' span fits the slots whatever the dropped rows hold
+    assert int(onehot) == (lowering == "smallkey" and len(kept) > 0)
     host = {k: ([v[i] for i in kept] if isinstance(v, list) else v[kept])
             for k, v in table.items()}
     want = {}

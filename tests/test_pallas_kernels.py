@@ -255,6 +255,165 @@ def test_smallkey_matmul_nan_padding():
     assert float(np.asarray(out.columns["s"])[0]) == 15.0
 
 
+def _short_keys_table(case, n, rng):
+    """Key columns of one short-key set (the rows' first few hold each
+    key's extremes, so the live spans are exactly the ones named) and
+    two float columns."""
+    def spread(lo, hi, dtype):
+        v = rng.randint(lo, hi + 1, n).astype(dtype)
+        v[:2] = (lo, hi)
+        return v
+
+    def chars(values):
+        out = [values[i] for i in rng.randint(0, len(values), n)]
+        out[:len(values)] = values
+        return out
+
+    if case == "two-strings":      # Q1's CHAR(1) pair, one key empty
+        t = {"f": chars([b"", b"A", b"N"]), "g": chars([b"N", b"O"])}
+    elif case == "bool-int8":
+        t = {"b": spread(0, 1, np.bool_), "i": spread(-5, 5, np.int8)}
+    elif case == "slots-512":      # spans 2 x 16 x 16
+        t = {"b": spread(0, 1, np.bool_), "h": spread(-8, 7, np.int16),
+             "c": chars([bytes([c]) for c in range(ord("A"), ord("Q"))])}
+    else:                          # "slots-513": spans 3 x 9 x 19
+        t = {"i": spread(-1, 1, np.int8), "c": chars([b"", b"\x07"]),
+             "u": spread(5, 23, np.uint8)}
+    keys = list(t)
+    t["x"] = rng.uniform(1, 100, n).astype(np.float32)
+    t["y"] = rng.uniform(0.5, 2, n).astype(np.float32)
+    return t, keys
+
+
+def _host_rows(table, keys):
+    cols = [table[k] for k in keys]
+    return [tuple(c[i] if isinstance(c[i], bytes) else int(c[i])
+                  for c in cols) for i in range(len(cols[0]))]
+
+
+@pytest.mark.parametrize("case,one_hot", [
+    ("two-strings", True), ("bool-int8", True), ("slots-512", True),
+    ("slots-513", False)])
+def test_smallkey_short_keys_match_the_boundary_path(case, one_hot):
+    """Short keys (bools, integers of at most 16 bits, one-byte strings)
+    share one mixed-radix slot: the groups come out in the keys'
+    lexicographic order, each equal to the boundary lowering's group of
+    the same key (whose order is its key hash's) — keys and counts
+    exact, sums within 1e-6.  Spans that multiply past the slots run the
+    boundary lowering itself, row for row."""
+    import jax
+    from dryad_tpu.data import batch_from_numpy, batch_to_numpy
+    from dryad_tpu.ops import kernels as k
+
+    cap, n = 4096, 4000
+    table, keys = _short_keys_table(case, cap, np.random.RandomState(9))
+    b = batch_from_numpy(table, capacity=cap, str_max_len=1).with_count(n)
+    aggs = {"n": ("count", None), "s": ("sum", "x"), "m": ("mean", "y")}
+    assert k._matmul_group_eligible(b, keys, aggs)
+
+    @jax.jit
+    def both(b):
+        with k.onehot_tally() as ran:
+            got = k.group_aggregate(b, keys, aggs)
+        return got, k._group_aggregate_boundary(b, keys, aggs, None), ran[0]
+
+    got, ref, ran = both(b)
+    assert bool(ran) == one_hot
+    got, ref = batch_to_numpy(got), batch_to_numpy(ref)
+    gk, rk = _host_rows(got, keys), _host_rows(ref, keys)
+    if one_hot:
+        assert gk == sorted(gk)
+        at = sorted(range(len(rk)), key=rk.__getitem__)
+        ref = {c: [v[i] for i in at] if isinstance(v, list) else v[at]
+               for c, v in ref.items()}
+        rk = [rk[i] for i in at]
+    assert gk == rk
+    # every live row is in a group, and the groups are the host's
+    want = {}
+    for i, key in enumerate(_host_rows(table, keys)[:n]):
+        want[key] = want.get(key, 0) + 1
+    assert dict(zip(gk, got["n"].tolist())) == want
+    np.testing.assert_array_equal(got["n"], ref["n"])
+    assert got["n"].dtype == np.int32
+    for c in ("s", "m"):
+        np.testing.assert_allclose(got[c], ref[c], rtol=1e-6)
+
+
+def test_wide_keys_trace_the_boundary_program():
+    """Three 32-bit keys (TPC-H Q3's group) fail the one-hot gate from
+    their dtypes alone: no span check, no ``cond``, the boundary
+    lowering's program whatever the data."""
+    import jax
+    from dryad_tpu.data.columnar import Batch
+    from dryad_tpu.ops import kernels as k
+
+    keys = ["a", "b", "c"]
+    aggs = {"n": ("count", None), "s": ("sum", "x")}
+
+    def batch(seed):
+        rng = np.random.RandomState(seed)
+        cols = {c: jnp.asarray(rng.randint(0, 3, 512).astype(np.int32))
+                for c in keys}
+        cols["x"] = jnp.asarray(rng.rand(512).astype(np.float32))
+        return Batch(cols, jnp.asarray(500, jnp.int32))
+
+    def prims(jaxpr):
+        for eqn in jaxpr.eqns:
+            yield eqn.primitive.name
+            for v in eqn.params.values():
+                for sub in (v if isinstance(v, (list, tuple)) else [v]):
+                    inner = getattr(sub, "jaxpr", sub)
+                    if hasattr(inner, "eqns"):
+                        yield from prims(inner)
+
+    assert not k._matmul_group_eligible(batch(0), keys, aggs)
+    traced = [jax.make_jaxpr(lambda b: k.group_aggregate(b, keys, aggs))(
+        batch(s)) for s in (0, 1)]
+    assert "cond" not in set(prims(traced[0].jaxpr))
+    boundary = jax.make_jaxpr(lambda b: k._group_aggregate_boundary(
+        b, keys, aggs, None))(batch(2))
+    assert str(traced[0]) == str(traced[1]) == str(boundary)
+
+
+def test_smallkey_sums_are_compensated(monkeypatch):
+    """The chunk sums of a one-hot group-by accumulate in a compensated
+    pair: over 1,024 chunks, where one plain ``float32`` accumulator (the
+    lowering's before) is off by more than 1e-6 — 5.8e-6 here: every
+    line of the column has one price, so every chunk adds the same sum
+    and its roundings all lean one way — the group sums stay within 1e-7
+    of float64; counts are exact ``int32``."""
+    import jax
+    from dryad_tpu.data.columnar import Batch
+    from dryad_tpu.ops import kernels as k
+
+    chunk, nb = 64, 1024
+    monkeypatch.setattr(k, "_SMALLKEY_CHUNK", chunk)
+    n = chunk * nb
+    key = (np.arange(n) % 4).astype(np.int32)
+    x = np.full(n, 38_000.3, np.float32)
+    b = Batch({"k": jnp.asarray(key), "x": jnp.asarray(x)},
+              jnp.asarray(n, jnp.int32))
+    out = jax.jit(lambda b: k.group_aggregate(
+        b, ["k"], {"s": ("sum", "x"), "n": ("count", None)}))(b)
+    assert int(out.count) == 4
+    got_k = np.asarray(out.columns["k"])[:4]
+    got_s = np.asarray(out.columns["s"])[:4]
+    got_n = np.asarray(out.columns["n"])[:4]
+    assert got_n.dtype == np.int32
+    for g, s, c in zip(got_k, got_s, got_n):
+        rows = x[key == g]
+        assert c == len(rows)
+        exact = rows.astype(np.float64).sum()
+        # the plain accumulator: each chunk's sum (in float64, then
+        # rounded, as the chunk's matmul gives it) added into one float32
+        plain = np.float32(0)
+        for part in np.where(key == g, x, 0).reshape(nb, chunk) \
+                .astype(np.float64).sum(axis=1).astype(np.float32):
+            plain = np.float32(plain + part)
+        assert abs(float(plain) - exact) > 1e-6 * exact, (g, plain, exact)
+        assert abs(float(s) - exact) <= 1e-7 * exact, (g, s, exact)
+
+
 def test_tokenize_group_count_matches_unfused():
     """The fused SelectMany+GroupBy+Count equals split_tokens + lower +
     group_aggregate on real text, including the NEED channel."""

@@ -135,8 +135,9 @@ def test_group_aggregate_compiles(topo, tpu_tier):
     ids=["one-hot", "boundary"])
 def test_masked_group_aggregate_compiles(topo, tpu_tier, keys, cols):
     """A filter's mask into the group-by (``where=``): Q6's shape (one
-    constant key, the one-hot matmul with the sort fallback beside it)
-    and Q1's (two 1-byte strings, the boundary path)."""
+    constant key) and Q1's (two 1-byte strings in one mixed-radix slot),
+    each the one-hot matmul with the boundary path beside it as the sort
+    fallback."""
     sh = _one_chip(topo)
     b = _batch(sh, (), 4096, v=jnp.float32, **cols)
     m = jax.ShapeDtypeStruct((4096,), jnp.bool_, sharding=sh)

@@ -489,3 +489,38 @@ def test_gather_live_share_reader(queries, want):
     run = {"queries": [{"i": i, "events": ev}
                        for i, ev in enumerate(queries)]}
     assert gather_live_share.read(run) == want
+
+
+# -- the group-bys that took the one-hot branch ride it too ------------------
+
+
+@pytest.mark.parametrize("keys,want", [
+    (["g"], 1),             # one integer key, span 3
+    (["k"], 0),             # one integer key, span 4,096: the sort fallback
+    (["k", "g"], 0),        # two 32-bit keys: never the one-hot lowering
+], ids=["short", "wide-span", "wide-keys"])
+def test_stage_done_says_which_group_bys_ran_one_hot(keys, want):
+    events = []
+    out = _ctx(1, events).from_columns(_columns()).group_by(
+        keys, {"s": ("sum", "v"), "n": ("count", None)}).collect()
+    assert int(out["n"].sum()) == N
+    done = [e for e in events if e.get("event") == "stage_done"]
+    assert done and sum(e["onehot_groups"] for e in done) == want
+    from perfbench.layers import onehot_groups
+    assert onehot_groups.read(
+        {"queries": [{"i": 0, "events": events}]}) == want
+
+
+@pytest.mark.parametrize("queries,want", [
+    ([[{"event": "stage_done", "stage": 0}]], None),     # an older program
+    ([[_done(0, 0, 0, onehot_groups=1), _done(1, 0, 0, onehot_groups=0)]],
+     1.0),                                               # summed over stages
+    ([[_done(0, 0, 0, onehot_groups=0, overflow=True),
+       _done(0, 0, 0, onehot_groups=1)]], 1.0),          # the settled attempt
+    ([[_done(0, 0, 0, onehot_groups=n)] for n in (0, 2, 1)], 1.0),
+], ids=["no-lane", "stages", "settled-attempt", "median"])
+def test_onehot_groups_reader(queries, want):
+    from perfbench.layers import onehot_groups
+    run = {"queries": [{"i": i, "events": ev}
+                       for i, ev in enumerate(queries)]}
+    assert onehot_groups.read(run) == want
